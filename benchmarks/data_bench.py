@@ -254,7 +254,8 @@ def main():
     ap.add_argument("--imagenet-records", type=int, default=2048)
     ap.add_argument("--r18-samples-per-sec", type=float, default=29793.0,
                     help="the chip-side demand to compare ingest against "
-                         "(BENCH_r01 ResNet-18 throughput)")
+                         "(ResNet-18 throughput of the first chip record, "
+                         "2026-07-30)")
     ap.add_argument("--r50-samples-per-sec", type=float, default=2315.0,
                     help="ResNet-50 PER-CHIP step demand for the ImageNet "
                          "ingest comparison (measured, bench_history)")

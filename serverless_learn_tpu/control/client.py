@@ -1,10 +1,13 @@
-"""Python bindings to the native control/data plane.
+"""Python clients of the control/data plane.
 
-The transport is ``native/bin/libslt.so`` via ctypes — the per-chunk fetch
-loop and framing run in C++ (see ``native/slt_client.cc``); Python only
-constructs/parses the small protobuf control messages with the generated
-``slt_pb2``. A pure-Python socket fallback covers environments where the
-shared library isn't built.
+The transport is a plain socket speaking the daemons' length-prefixed
+framing (``native/framing.h``), with per-RPC deadlines, connection
+poisoning and a per-peer circuit breaker; messages are the generated
+``slt_pb2`` protobufs. The constructors still accept ``prefer_native``
+because ``control/gossip.py`` passes it through; it selects nothing (the
+ctypes binding to ``native/bin/libslt.so`` had no deadline inside
+``slt_call``, so every RPC takes the socket path — ROADMAP D9 owns the
+rest of the native plane).
 
 Successor of the reference's per-binary stub classes (SURVEY.md §2.5):
 ``CoordinatorClient`` ≈ MasterStub (``src/worker.cc:107-133``),
@@ -16,7 +19,6 @@ lease renewals.
 
 from __future__ import annotations
 
-import ctypes
 import os
 import random
 import socket
@@ -197,48 +199,8 @@ def breaker_for(peer: str, fail_threshold: int = 5,
         return b
 
 
-class _NativeLib:
-    _instance = None
-
-    def __init__(self):
-        path = os.path.join(_BIN, "libslt.so")
-        self.lib = ctypes.CDLL(path)
-        self.lib.slt_connect.restype = ctypes.c_void_p
-        self.lib.slt_connect.argtypes = [ctypes.c_char_p]
-        self.lib.slt_disconnect.argtypes = [ctypes.c_void_p]
-        self.lib.slt_call.restype = ctypes.c_longlong
-        self.lib.slt_call.argtypes = [
-            ctypes.c_void_p, ctypes.c_ubyte, ctypes.c_char_p, ctypes.c_size_t,
-            ctypes.c_void_p, ctypes.c_size_t, ctypes.POINTER(ctypes.c_ubyte),
-            ctypes.c_int]
-        self.lib.slt_fetch_into.restype = ctypes.c_longlong
-        self.lib.slt_fetch_into.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_ulonglong,
-            ctypes.c_ulonglong, ctypes.c_void_p, ctypes.c_size_t]
-        self.lib.slt_set_flow.restype = None
-        self.lib.slt_set_flow.argtypes = [ctypes.c_void_p, ctypes.c_int]
-        self.lib.slt_put.restype = ctypes.c_int
-        self.lib.slt_put.argtypes = [
-            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t]
-
-    @classmethod
-    def get(cls) -> Optional["_NativeLib"]:
-        if cls._instance is None:
-            if not ensure_native_built():
-                return None
-            try:
-                cls._instance = cls()
-            except (OSError, AttributeError):
-                # AttributeError: a stale libslt.so predating a newly
-                # required symbol (e.g. slt_set_flow). Degrade to the
-                # pure-python transport instead of crashing every client.
-                return None
-        return cls._instance
-
-
 class Transport:
-    """One persistent connection; native (ctypes) when available, else pure
-    Python sockets with the same framing."""
+    """One persistent socket connection speaking the daemons' framing."""
 
     def __init__(self, addr: str, prefer_native: bool = True,
                  rpc_timeout_s: float = 30.0, max_attempts: int = 3,
@@ -252,10 +214,8 @@ class Transport:
         self.breaker = breaker if breaker is not None else breaker_for(addr)
         self._rng = random.Random()
         self._lock = threading.Lock()
-        self._native = _NativeLib.get() if prefer_native else None
-        self._handle = None
         self._sock: Optional[socket.socket] = None
-        self._flow: Optional[int] = None  # pure-python sticky backpressure
+        self._flow: Optional[int] = None  # sticky backpressure report
         from serverless_learn_tpu.telemetry import get_registry
 
         reg = get_registry()
@@ -265,13 +225,7 @@ class Transport:
         self._m_timeouts = reg.counter(
             "slt_rpc_timeouts_total",
             "RPCs that hit their deadline (connection poisoned + redialed)")
-        if self._native is not None:
-            self._handle = self._native.lib.slt_connect(addr.encode())
-            if not self._handle:
-                self.breaker.record_failure()
-                raise ConnectionError(f"cannot connect to {addr}")
-        else:
-            self._dial()
+        self._dial()
 
     def set_flow(self, flow: Optional[int]):
         """Sticky input-backpressure for this connection's future fetches:
@@ -280,11 +234,7 @@ class Transport:
         flight. None clears the report."""
         with self._lock:
             self._flow = flow
-            if self._native is not None:
-                self._native.lib.slt_set_flow(
-                    self._handle, -1 if flow is None else int(flow))
 
-    # ---- pure-python path ----
     def _dial(self):
         host, port = self.addr.rsplit(":", 1)
         self._sock = socket.create_connection(
@@ -325,8 +275,7 @@ class Transport:
         return mtype, self._recv_exact(length) if length else b""
 
     # ---- public API ----
-    def call(self, req_type: int, payload: bytes, cap: int = 1 << 20,
-             idempotent: bool = True):
+    def call(self, req_type: int, payload: bytes, idempotent: bool = True):
         """Unary call; returns (resp_type, resp_bytes). Reconnect+resend on a
         broken connection happens only for ``idempotent`` requests — resending
         e.g. a Register that was already applied would create a ghost worker.
@@ -339,24 +288,6 @@ class Transport:
         if not self.breaker.allow():
             raise ConnectionError(
                 f"circuit open to {self.addr} (failing fast)")
-        if self._native is not None:
-            with self._lock:
-                buf = ctypes.create_string_buffer(cap)
-                rt = ctypes.c_ubyte(0)
-                n = self._native.lib.slt_call(
-                    self._handle, req_type, payload, len(payload),
-                    buf, cap, ctypes.byref(rt), 1 if idempotent else 0)
-                if n < 0:
-                    self.breaker.record_failure()
-                    raise ConnectionError(f"call to {self.addr} failed")
-                if n > cap:
-                    # Truncated responses parse as valid-but-shorter protobufs
-                    # (e.g. a manifest silently missing keys) — refuse.
-                    raise ValueError(
-                        f"response ({n} B) exceeds buffer ({cap} B); "
-                        f"retry with cap>={n}")
-                self.breaker.record_success()
-                return rt.value, buf.raw[:n]
         attempts = self.max_attempts if idempotent else 1
         for attempt in range(attempts):
             try:
@@ -399,18 +330,6 @@ class Transport:
             raise ConnectionError(
                 f"circuit open to {self.addr} (failing fast)")
         with self._lock:
-            if self._native is not None:
-                n = self._native.lib.slt_fetch_into(
-                    self._handle, key.encode(), offset, length,
-                    dst.ctypes.data_as(ctypes.c_void_p), dst.nbytes)
-                if n == -3:
-                    raise IOError(
-                        f"fetch {key!r}: crc mismatch (corrupted in transit)")
-                if n < 0:
-                    self.breaker.record_failure()
-                    raise IOError(f"fetch {key!r} failed")
-                self.breaker.record_success()
-                return int(n)
             try:
                 out = self._fetch_into_locked(key, dst, offset, length)
             except socket.timeout:
@@ -468,14 +387,6 @@ class Transport:
             raise ConnectionError(
                 f"circuit open to {self.addr} (failing fast)")
         with self._lock:
-            if self._native is not None:
-                rc = self._native.lib.slt_put(
-                    self._handle, key.encode(), data, len(data))
-                if rc != 0:
-                    self.breaker.record_failure()
-                    raise IOError(f"put {key!r} failed")
-                self.breaker.record_success()
-                return
             try:
                 self._put_locked(key, data)
             except socket.timeout:
@@ -515,13 +426,9 @@ class Transport:
 
     def close(self):
         # Under _lock like every other connection-state transition: a
-        # close racing an in-flight call() must not disconnect a handle
-        # the call is mid-exchange on, and two closers must not both
-        # pass the `if self._handle` check (double slt_disconnect).
+        # close racing an in-flight call() must not drop a socket the
+        # call is mid-exchange on.
         with self._lock:
-            if self._native is not None and self._handle:
-                self._native.lib.slt_disconnect(self._handle)
-                self._handle = None
             if self._sock is not None:
                 self._sock.close()
                 self._sock = None
@@ -598,8 +505,7 @@ class ShardClient:
         with ttrace.client_span("rpc/manifest", peer=self.t.addr):
             req = self.pb.ManifestRequest(dataset=dataset)
             ttrace.attach_context(req)
-            _, raw = self.t.call(MSG_MANIFEST_REQ, req.SerializeToString(),
-                                 cap=16 << 20)
+            _, raw = self.t.call(MSG_MANIFEST_REQ, req.SerializeToString())
         rep = self.pb.ManifestReply()
         rep.ParseFromString(raw)
         if not rep.ok:
@@ -645,10 +551,6 @@ class ShardClient:
 
     def fetch_into(self, key: str, dst: np.ndarray, offset: int = 0,
                    length: int = 0) -> int:
-        # The client-side span covers both transports; the `trace` field
-        # rides only the pure-python frames (the native libslt.so path
-        # serializes FetchRequest in C++), so server-side chaining is
-        # best-effort there — the client span still lands in the timeline.
         with ttrace.client_span("rpc/fetch", peer=self.t.addr, key=key):
             return self.t.fetch_into(key, dst, offset, length)
 

@@ -99,10 +99,10 @@ def dot_product_attention(
     too, for the impls that don't read lengths."""
     if impl == "auto":
         # On an sp>1 mesh the sequence dim is sharded and ring attention is
-        # the only impl that keeps it that way (flash would fall back to
-        # dense XLA and materialize the [T, T] scores). Otherwise flash
-        # above the measured threshold; flash itself falls back to xla for
-        # unsupported mask forms, untileable shapes, non-TPU/CPU backends.
+        # the only impl that keeps it that way. Otherwise flash above the
+        # measured threshold, when the kernels can run this call — the
+        # kernel's own test decides, so the choice made here is the one
+        # that runs.
         from serverless_learn_tpu.parallel.compat import in_manual_region
         from serverless_learn_tpu.parallel.ring_attention import (
             get_active_mesh)
@@ -113,14 +113,20 @@ def dot_product_attention(
                 and (mask is None or kv_lengths is not None)
                 and k.shape[1] % mesh.shape["sp"] == 0):
             # Suffix padding (kv_lengths) rides the ring's per-hop "len"
-            # masking; only a GENERAL mask (no lengths form) forces the
-            # dense fallback on an sp mesh.
+            # masking; only a GENERAL mask (no lengths form) takes dense
+            # attention on an sp mesh.
             impl = "ring"
-        elif kv_lengths is not None:
-            impl = ("flash" if q.shape[1] >= AUTO_FLASH_MIN_SEQ_LENGTHS
-                    else "xla")
         else:
-            impl = "flash" if q.shape[1] >= AUTO_FLASH_MIN_SEQ else "xla"
+            min_seq = (AUTO_FLASH_MIN_SEQ_LENGTHS if kv_lengths is not None
+                       else AUTO_FLASH_MIN_SEQ)
+            impl = "xla"
+            if q.shape[1] >= min_seq:
+                from serverless_learn_tpu.ops.pallas.flash_attention import (
+                    untileable_reason)
+
+                if untileable_reason(q, k, mask=mask,
+                                     kv_lengths=kv_lengths) is None:
+                    impl = "flash"
     if impl == "xla":
         if kv_lengths is not None and mask is None:
             # Honor the lengths contract on this path too: a caller that

@@ -173,19 +173,18 @@ def fused_cross_entropy_with_integer_labels(
     ``optax.softmax_cross_entropy_with_integer_labels``, streaming the vocab
     axis through VMEM instead of materializing fp32 probabilities in HBM.
 
-    Shapes the kernel can't tile (vocab not a multiple of ``block_v``) fall
-    back to optax; rows are padded up to ``block_n``.
+    A vocab the kernel can't tile (not a multiple of ``block_v``) raises —
+    the caller asked for the fused kernel, so optax must not run under its
+    name; rows are padded up to ``block_n``.
     """
-    import optax
-
     V = logits.shape[-1]
     lead = logits.shape[:-1]
-    backend = jax.default_backend()
-    if V % block_v or backend not in ("cpu", "tpu"):
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits.astype(jnp.float32), labels)
+    if V % block_v:
+        raise ValueError(
+            f"fused cross-entropy cannot tile vocab {V}: not a multiple of "
+            f"block_v={block_v}")
     if interpret is None:
-        interpret = backend == "cpu"
+        interpret = jax.default_backend() == "cpu"
 
     def local(x, lab):
         """Kernel over this shard's rows ([..., V] -> [...])."""

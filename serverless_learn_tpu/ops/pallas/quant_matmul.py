@@ -87,10 +87,11 @@ def _pick_tiles(R: int, I: int, O: int):
 
 def quant_matmul(x: jax.Array, wq: jax.Array, scale: jax.Array,
                  out_dtype=None) -> jax.Array:
-    """``x [..., I] @ wq [I, O] * scale [O]`` with in-kernel dequant.
-
-    Falls back to the XLA form (convert-then-dot) off TPU/CPU or for
-    untileable shapes — same math, the measured materialization cost."""
+    """``x [..., I] @ wq [I, O] * scale [O]``: the XLA convert-then-dot
+    form by default (see the module docstring for why it MEASURED faster
+    than the kernel on v5e), the in-kernel dequant under
+    ``SLT_QUANT_PALLAS=1`` — where a shape the kernel can't tile raises
+    instead of quietly taking the XLA form."""
     import os
 
     out_dtype = out_dtype or x.dtype
@@ -99,17 +100,15 @@ def quant_matmul(x: jax.Array, wq: jax.Array, scale: jax.Array,
     R = 1
     for d in lead:
         R *= d
-    x2 = x.reshape(R, I)
-    backend = jax.default_backend()
-    tiles = _pick_tiles(max(R, 8), I, O)
-    use_pallas = (os.environ.get("SLT_QUANT_PALLAS")
-                  and backend in ("tpu", "cpu")
-                  and tiles is not None and R <= 4096)
-    if not use_pallas:
-        # Default: the XLA convert-then-dot form. See the module docstring
-        # for why this MEASURED faster than the custom kernel on v5e.
+    if not os.environ.get("SLT_QUANT_PALLAS"):
         y = jnp.tensordot(x, wq.astype(x.dtype), axes=1)
         return (y * scale.astype(x.dtype)).astype(out_dtype)
+    tiles = _pick_tiles(max(R, 8), I, O)
+    if tiles is None or R > 4096:
+        raise ValueError(
+            f"SLT_QUANT_PALLAS=1 but the dequant kernel cannot tile "
+            f"x [{R}, {I}] @ wq [{I}, {O}]")
+    x2 = x.reshape(R, I)
     bi, bo = tiles
     # Pad rows to the 8-sublane tile (decode calls are R=batch, often < 8).
     Rp = max(8, -(-R // 8) * 8)
@@ -127,6 +126,6 @@ def quant_matmul(x: jax.Array, wq: jax.Array, scale: jax.Array,
         out_specs=pl.BlockSpec((Rp, bo), lambda o, i: (0, o)),
         out_shape=jax.ShapeDtypeStruct((Rp, O), out_dtype),
         scratch_shapes=[pltpu.VMEM((Rp, bo), jnp.float32)],
-        interpret=backend == "cpu",
+        interpret=jax.default_backend() == "cpu",
     )(x2, wq, scale.reshape(1, O))
     return out[:R].reshape(*lead, O)

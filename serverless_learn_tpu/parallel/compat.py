@@ -1,42 +1,14 @@
-"""JAX version-compat shims shared by every shard_map user in the tree.
-
-One place for the import-location and kwarg-rename drift (0.6 moved
-shard_map out of experimental and renamed check_rep -> check_vma); three
-modules previously carried private copies and two of them diverged.
-"""
+"""shard_map helpers shared by every shard_map user in the tree."""
 
 from __future__ import annotations
 
-try:  # JAX >= 0.6 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map
-
-    _NO_CHECK = {"check_vma": False}
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _NO_CHECK = {"check_rep": False}  # the kwarg's pre-0.6 name
-
-
-# The resolved shard_map, for callers that keep replication checking on.
-shard_map = _shard_map
-
-
-def axis_size(axis_name: str):
-    """``jax.lax.axis_size`` on JAX versions that have it; the classic
-    ``psum(1, axis)`` identity (constant-folded under jit) elsewhere —
-    0.4.x has ``axis_index`` but not ``axis_size``."""
-    import jax
-
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+from jax import shard_map
 
 
 def shard_map_no_check(fn, *, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, on any supported JAX."""
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **_NO_CHECK)
+    """shard_map with replication checking off."""
+    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=False)
 
 
 # -- manual-region tracking -------------------------------------------------

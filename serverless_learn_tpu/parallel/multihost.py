@@ -44,22 +44,11 @@ def initialize(coordinator_address: str, num_processes: int,
     """
     import jax
 
-    if timeout_s is None:
-        jax.distributed.initialize(coordinator_address=coordinator_address,
-                                   num_processes=num_processes,
-                                   process_id=process_id)
-        return
-    try:
-        jax.distributed.initialize(coordinator_address=coordinator_address,
-                                   num_processes=num_processes,
-                                   process_id=process_id,
-                                   initialization_timeout=int(timeout_s))
-    except TypeError as e:
-        if "initialization_timeout" not in str(e):
-            raise  # a real argument bug, not a missing-kwarg jax version
-        jax.distributed.initialize(coordinator_address=coordinator_address,
-                                   num_processes=num_processes,
-                                   process_id=process_id)
+    kw = {} if timeout_s is None else {
+        "initialization_timeout": int(timeout_s)}
+    jax.distributed.initialize(coordinator_address=coordinator_address,
+                               num_processes=num_processes,
+                               process_id=process_id, **kw)
 
 
 def free_port(host: str = "127.0.0.1") -> int:

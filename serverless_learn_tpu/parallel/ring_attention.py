@@ -43,7 +43,6 @@ same model code runs sp=1 (no-op) or sp=N by changing the mesh shape.
 
 from __future__ import annotations
 
-import os
 from functools import partial
 from typing import Optional
 
@@ -51,7 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from serverless_learn_tpu.parallel import compat
 from serverless_learn_tpu.parallel.compat import (
     shard_map_no_check as _shard_map)
 
@@ -162,7 +160,7 @@ def _ring_attention_local(q, k, v, kv_lengths, *, axis_name: str,
     unexpanded. ``kv_lengths`` [B] are GLOBAL suffix lengths; each hop
     slices them to its resident block. Causal hidden hops still compute
     (gated in the merge) — the zigzag layout removes that waste."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     T_loc = q.shape[1]
@@ -252,7 +250,7 @@ def _ring_attention_zigzag(q, k, v, kv_lengths, *, axis_name: str, hop_fn):
     layout: the relayout (two half-block ppermutes in, two out) is
     amortized against (n-1) hops of halved compute.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     B, T_loc = q.shape[:2]
@@ -329,8 +327,7 @@ def _ring_attention_zigzag(q, k, v, kv_lengths, *, axis_name: str, hop_fn):
     return out.astype(q.dtype)
 
 
-def _auto_zigzag(causal: bool, n: int, t_loc: int, flash_ok: bool = True
-                 ) -> bool:
+def _auto_zigzag(causal: bool, n: int, t_loc: int) -> bool:
     """The "auto" layout policy. Zigzag halves the causal hop compute —
     but only adopt it when its half-blocks still hit the flash kernel (or
     flash is out of reach at full blocks too): trading the blocked kernel
@@ -340,8 +337,7 @@ def _auto_zigzag(causal: bool, n: int, t_loc: int, flash_ok: bool = True
 
     if not (causal and n > 1 and t_loc % 2 == 0):
         return False
-    return (not flash_ok or _pick_block(t_loc // 2) is not None
-            or _pick_block(t_loc) is None)
+    return _pick_block(t_loc // 2) is not None or _pick_block(t_loc) is None
 
 
 def _local_ring_fn(T_loc: int, n: int, causal: bool, layout: str,
@@ -353,15 +349,11 @@ def _local_ring_fn(T_loc: int, n: int, causal: bool, layout: str,
     e.g. pipeline stages)."""
     from serverless_learn_tpu.ops.pallas.flash_attention import _pick_block
 
-    backend = jax.default_backend()
-    flash_ok = (backend in ("cpu", "tpu")
-                or bool(os.environ.get("SLT_FORCE_PALLAS")))
-
     def make_hop(span):
         block = _pick_block(span)
-        if block is not None and flash_ok:
+        if block is not None:
             return partial(_flash_hop, block=block,
-                           interpret=backend == "cpu")
+                           interpret=jax.default_backend() == "cpu")
         return partial(_dense_hop, scale=scale)
 
     zig_ok = causal and n > 1 and T_loc % 2 == 0
@@ -373,7 +365,7 @@ def _local_ring_fn(T_loc: int, n: int, causal: bool, layout: str,
                 f"T_loc={T_loc})")
         zigzag = True
     elif layout == "auto":
-        zigzag = _auto_zigzag(causal, n, T_loc, flash_ok)
+        zigzag = _auto_zigzag(causal, n, T_loc)
     else:
         zigzag = False
     if zigzag:
@@ -393,7 +385,7 @@ def ring_attention_manual(q, k, v, *, axis_name: str = "sp",
     GLOBAL suffix lengths (each hop slices its resident block's span).
     Same math and hop kernels as the public ``ring_attention``; only the
     shard_map wrapper is omitted."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     local = _local_ring_fn(q.shape[1], n, causal, layout,
                            q.shape[-1] ** -0.5)
     lens = None if kv_lengths is None else kv_lengths.astype(jnp.int32)

@@ -102,27 +102,37 @@ def installed() -> bool:
     return _installed
 
 
-def _device_memory() -> Optional[list]:
-    """Per-device memory stats, only if jax is ALREADY imported (a crash
-    handler must not pay a cold jax import) and the backend reports them
-    (CPU returns None/raises; TPU/GPU give bytes_in_use etc.)."""
-    if "jax" not in sys.modules:
+def live_jax():
+    """The jax module if this process has already initialised a backend,
+    else None. "Imported" is not enough: a supervisor imports jax through
+    ``training.checkpoint`` without touching a device, and asking it for
+    ``local_devices()`` would take the chip its child trainers need (a
+    chip belongs to one process at a time)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
         return None
-    try:
-        import jax
+    from jax._src import xla_bridge
 
-        out = []
-        for d in jax.local_devices():
-            try:
-                stats = d.memory_stats()
-            except Exception:
-                stats = None
-            if stats:
-                out.append({"device": str(d), **{k: v for k, v in
-                                                 stats.items()}})
-        return out or None
-    except Exception:
+    return jax if xla_bridge.backends_are_initialized() else None
+
+
+def device_memory() -> Optional[list]:
+    """Per-device memory stats, only if this process already holds its
+    devices (see ``live_jax``; a crash handler must neither pay a cold
+    import nor grab a chip) and the backend reports them (CPU returns
+    None/raises; TPU gives bytes_in_use etc.)."""
+    jax = live_jax()
+    if jax is None:
         return None
+    out = []
+    for d in jax.local_devices():
+        try:
+            stats = d.memory_stats()
+        except Exception:
+            stats = None
+        if stats:
+            out.append({"device": str(d), **dict(stats)})
+    return out or None
 
 
 def dump(reason: str, dir: Optional[str] = None) -> Optional[str]:
@@ -172,7 +182,7 @@ def dump(reason: str, dir: Optional[str] = None) -> Optional[str]:
                     payload[pname] = val
             except Exception:
                 pass
-        mem = _device_memory()
+        mem = device_memory()
         if mem is not None:
             payload["device_memory"] = mem
         tmp = path + ".tmp"

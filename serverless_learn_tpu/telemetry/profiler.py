@@ -28,11 +28,12 @@ from __future__ import annotations
 
 import json
 import os
-import sys
 import threading
 import time
 from contextlib import contextmanager
 from typing import Callable, Optional
+
+from serverless_learn_tpu.telemetry import flight
 
 MAX_PROFILE_SECONDS = 60.0
 DEFAULT_ALERT_CAPTURE_S = 3.0
@@ -64,38 +65,11 @@ def armed() -> bool:
     return profile_dir() is not None
 
 
-def _device_memory() -> Optional[list]:
-    """Per-device memory watermarks, only if jax is already imported —
-    same discipline as the flight recorder's snapshot."""
-    if "jax" not in sys.modules:
-        return None
-    try:
-        import jax
-
-        out = []
-        for d in jax.local_devices():
-            try:
-                stats = d.memory_stats()
-            except Exception:
-                stats = None
-            if stats:
-                out.append({"device": str(d), **dict(stats)})
-        return out or None
-    except Exception:
-        return None
-
-
 def _device_kind() -> Optional[str]:
-    """device_kind of the first local device, only if jax is already
-    imported (deviceless callers must not pay the import)."""
-    if "jax" not in sys.modules:
-        return None
-    try:
-        import jax
-
-        return jax.local_devices()[0].device_kind
-    except Exception:
-        return None
+    """device_kind of the first local device, only if this process already
+    holds its devices (``flight.live_jax``)."""
+    jax = flight.live_jax()
+    return jax.local_devices()[0].device_kind if jax is not None else None
 
 
 def _write_meta(out_dir: str, meta: dict):
@@ -131,7 +105,7 @@ def capture(seconds: float, out_dir: Optional[str] = None,
                 "seconds": seconds,
                 "started_unix_s": round(time.time(), 6),
                 "ledger_at_trigger": goodput.get_ledger().report(),
-                "device_memory_start": _device_memory(),
+                "device_memory_start": flight.device_memory(),
                 "device_kind": _device_kind(),
                 "mesh_axes": xray.mesh_axes()}
         import jax.profiler
@@ -141,7 +115,7 @@ def capture(seconds: float, out_dir: Optional[str] = None,
             sleep(seconds)
         finally:
             jax.profiler.stop_trace()
-        meta["device_memory_stop"] = _device_memory()
+        meta["device_memory_stop"] = flight.device_memory()
         _write_meta(out_dir, meta)
         # Round 16: every capture gets an xray summary stamped into its
         # meta — the trace explains itself ("step is 31% exposed

@@ -58,7 +58,7 @@ from serverless_learn_tpu.models.registry import get_model
 from serverless_learn_tpu.parallel.mesh import make_mesh
 from serverless_learn_tpu.training.optimizer import make_optimizer
 
-from serverless_learn_tpu.parallel.compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 import flax.struct
 

@@ -13,8 +13,10 @@ matmuls on the MXU. MFU is always quoted AGAINST THE bf16 PEAK — the
 framework's training dtype policy is bf16 compute on TPU, and fp32 MXU
 peaks are not published per generation, so a quoted-vs-fp32 number would
 be invented. A deliberately-fp32 run therefore reads as low MFU, which is
-truthful about the hardware left on the table. Unknown device kinds yield
-None and MFU is simply omitted — never guessed.
+truthful about the hardware left on the table. The tables are keyed by
+the exact ``device_kind`` string: a CPU has no peak (None, MFU omitted),
+and a device on the ``tpu`` platform that is not in the table is an error,
+never a neighbour's number.
 """
 
 from __future__ import annotations
@@ -41,48 +43,48 @@ PEAK_HBM_GBPS = {
 }
 
 
-def _lookup_kind(table: dict, kind: str) -> Optional[float]:
-    for name, v in table.items():
-        if kind.startswith(name):
-            return v
-    return None
-
-
 def peak_flops_for_kind(kind: str) -> Optional[float]:
-    """Peak bf16 FLOP/s for a device_kind string (no jax import — the
-    xray analyzer runs on deviceless nodes against recorded captures)."""
-    tf = _lookup_kind(PEAK_TFLOPS_BF16, kind)
+    """Peak bf16 FLOP/s for an exact device_kind string, or None if it is
+    not in the table (no jax import — the xray analyzer runs on deviceless
+    nodes against recorded captures)."""
+    tf = PEAK_TFLOPS_BF16.get(kind)
     return tf * 1e12 if tf else None
 
 
 def peak_hbm_bytes_per_s_for_kind(kind: str) -> Optional[float]:
-    """Peak HBM bytes/s for a device_kind string, or None if unknown."""
-    gb = _lookup_kind(PEAK_HBM_GBPS, kind)
+    """Peak HBM bytes/s for an exact device_kind string, or None."""
+    gb = PEAK_HBM_GBPS.get(kind)
     return gb * 1e9 if gb else None
 
 
-def peak_flops_per_chip(device=None) -> Optional[float]:
-    """Peak bf16 FLOP/s for one chip, or None if unknown."""
+def _peak_of_device(for_kind, device) -> Optional[float]:
     import jax
 
-    kind = (device or jax.devices()[0]).device_kind
-    return peak_flops_for_kind(kind)
+    device = device or jax.devices()[0]
+    peak = for_kind(device.device_kind)
+    if peak is None and device.platform == "tpu":
+        raise KeyError(
+            f"no published peak for TPU device_kind {device.device_kind!r}; "
+            f"add it to the tables in utils/flops.py with its source")
+    return peak
+
+
+def peak_flops_per_chip(device=None) -> Optional[float]:
+    """Peak bf16 FLOP/s for one chip; None off the ``tpu`` platform."""
+    return _peak_of_device(peak_flops_for_kind, device)
 
 
 def peak_hbm_bytes_per_s(device=None) -> Optional[float]:
-    """Peak HBM bytes/s for one chip, or None if unknown."""
-    import jax
-
-    kind = (device or jax.devices()[0]).device_kind
-    return peak_hbm_bytes_per_s_for_kind(kind)
+    """Peak HBM bytes/s for one chip; None off the ``tpu`` platform."""
+    return _peak_of_device(peak_hbm_bytes_per_s_for_kind, device)
 
 
 def compiled_step_cost(step_fn, *args, n_devices: int = 1
                        ) -> Optional[dict]:
     """XLA's own compiled cost model for one call of ``step_fn(*args)``:
     ``{"flops": F, "bytes_accessed": B}`` across the whole mesh (either
-    value may be absent when the backend doesn't report it). None when no
-    cost analysis is exposed at all.
+    value may be absent when the backend doesn't report it). None when the
+    backend reports neither; a step that cannot lower or compile raises.
 
     ``n_devices`` MUST be the mesh size the function is jitted over: under
     SPMD, ``cost_analysis()`` reports the per-shard partitioned module's
@@ -90,23 +92,14 @@ def compiled_step_cost(step_fn, *args, n_devices: int = 1
     global FLOPs), so the global count is per-shard x devices."""
     import jax
 
-    try:
-        # Already-jitted callables expose .lower — reuse their cache instead
-        # of wrapping in a second jit (which would recompile from scratch).
-        if hasattr(step_fn, "lower"):
-            lowered = step_fn.lower(*args)
-        else:
-            lowered = jax.jit(step_fn).lower(*args)
-        analysis = lowered.compile().cost_analysis()
-    except Exception:
-        return None
+    # Already-jitted callables expose .lower — reuse their cache instead
+    # of wrapping in a second jit (which would recompile from scratch).
+    if hasattr(step_fn, "lower"):
+        lowered = step_fn.lower(*args)
+    else:
+        lowered = jax.jit(step_fn).lower(*args)
+    analysis = lowered.compile().cost_analysis()
     if not analysis:
-        return None
-    # jax used to return one dict; newer versions return a one-element
-    # list of per-computation dicts. Accept both.
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
-    if not isinstance(analysis, dict):
         return None
     out = {}
     flops = analysis.get("flops")

@@ -9,7 +9,8 @@ import socket
 
 import pytest
 
-from serverless_learn_tpu.cli import _config_from_args, build_parser, main
+from serverless_learn_tpu.cli import (_config_from_args, _trainer_config,
+                                      build_parser, main)
 
 
 def _parse(argv):
@@ -60,8 +61,12 @@ def test_config_file_set_and_flag_precedence(tmp_path):
 def test_default_mesh_uses_all_devices():
     import jax
 
-    cfg = _config_from_args(_parse(["train", "--model", "mlp_mnist"]))
-    assert cfg.mesh.size == len(jax.devices())
+    args = _parse(["train", "--model", "mlp_mnist"])
+    assert _trainer_config(args).mesh.size == len(jax.devices())
+    # Argument parsing alone names no devices (supervisors rely on it).
+    assert _config_from_args(args).mesh.size == 1
+    named = _parse(["train", "--model", "mlp_mnist", "--mesh", "dp=1"])
+    assert _trainer_config(named).mesh.size == 1
 
 
 def test_bad_set_syntax():

@@ -1,6 +1,6 @@
 """Pallas flash attention vs dense reference (forward + gradients), run in
-interpreter mode on CPU; the same kernel compiles for TPU (exercised by
-bench.py on the real chip)."""
+interpreter mode on CPU; ``chip_smoke.py``'s flash leg compiles the same
+kernels on the chip."""
 
 import jax
 import jax.numpy as jnp
@@ -54,11 +54,24 @@ def test_flash_grads_match_dense(causal):
                                    rtol=1e-3, atol=1e-3)
 
 
-def test_flash_fallback_on_untileable_shapes():
-    # seq 100 isn't a multiple of the block size: silently uses dense path
+def test_explicit_flash_raises_and_auto_takes_xla_on_untileable_shapes():
+    """seq 100 (and 4100) is no multiple of a block size: an explicit flash
+    request raises, and ``auto`` (above its threshold) asks the kernel's
+    own tileability test and runs dense attention instead."""
+    from serverless_learn_tpu.ops.attention import dot_product_attention
+
     q, k, v = _qkv(3, 1, 100, 2, 16)
-    out = flash_attention(q, k, v, causal=True)
-    ref = xla_attention(q, k, v, causal=True)
+    with pytest.raises(ValueError, match="not multiples of a block size"):
+        flash_attention(q, k, v, causal=True)
+    with pytest.raises(ValueError, match="not multiples of a block size"):
+        dot_product_attention(q, k, v, causal=True, impl="flash")
+    general = jnp.ones((1, 1, 128, 128), jnp.int32)  # not a key-padding row
+    q2, k2, v2 = _qkv(4, 1, 128, 2, 16)
+    with pytest.raises(ValueError, match="key-padding row"):
+        flash_attention(q2, k2, v2, mask=general)
+    ql, kl, vl = _qkv(5, 1, 4100, 1, 8)
+    out = dot_product_attention(ql, kl, vl, causal=True, impl="auto")
+    ref = xla_attention(ql, kl, vl, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 

@@ -4,9 +4,11 @@ FLOPs, peak lookup by device kind, and the ThroughputMeter wiring."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from serverless_learn_tpu.utils.flops import (
-    PEAK_TFLOPS_BF16, compiled_step_flops, mfu, peak_flops_per_chip)
+    PEAK_TFLOPS_BF16, compiled_step_cost, compiled_step_flops, mfu,
+    peak_flops_for_kind, peak_flops_per_chip, peak_hbm_bytes_per_s)
 from serverless_learn_tpu.utils.metrics import ThroughputMeter
 
 
@@ -21,16 +23,47 @@ def test_compiled_flops_matches_analytic_matmul():
     assert abs(flops - 2 * n ** 3) / (2 * n ** 3) < 0.05, flops
 
 
-def test_peak_lookup_unknown_device_is_none():
+def test_peak_lookup_off_tpu_is_none():
     class Fake:
+        platform = "cpu"
         device_kind = "abacus"
 
     assert peak_flops_per_chip(Fake()) is None
     assert mfu(1e12, 1.0, device=Fake()) is None
 
 
+def test_peak_lookup_is_exact_and_unknown_tpu_kind_raises():
+    """A prefix match once handed every unknown "TPU v5..." string v5p's
+    459 TFLOP/s; the tables match ``device_kind`` exactly, and a device on
+    the tpu platform that is not in them is an error."""
+    assert peak_flops_for_kind("TPU v5 lite") == 197e12
+    assert peak_flops_for_kind("TPU v5") == 459e12
+    assert peak_flops_for_kind("TPU v5e") is None
+    assert peak_flops_for_kind("TPU v5 litepod") is None
+
+    class Unknown:
+        platform = "tpu"
+        device_kind = "TPU v5e"
+
+    with pytest.raises(KeyError, match="TPU v5e"):
+        peak_flops_per_chip(Unknown())
+    with pytest.raises(KeyError, match="TPU v5e"):
+        peak_hbm_bytes_per_s(Unknown())
+    with pytest.raises(KeyError):
+        mfu(1e12, 1.0, device=Unknown())
+
+
+def test_step_that_cannot_lower_raises():
+    def bad(a):
+        return a @ jnp.ones((3, 3))  # shape mismatch at trace time
+
+    with pytest.raises(TypeError):
+        compiled_step_cost(bad, jnp.ones((2, 2)))
+
+
 def test_mfu_math():
     class V5e:
+        platform = "tpu"
         device_kind = "TPU v5 lite"
 
     peak = PEAK_TFLOPS_BF16["TPU v5 lite"] * 1e12

@@ -61,11 +61,10 @@ def _contended(addr, probe_flow, other_flow, n_others=3):
     return out["probe"][0], [out[f"o{i}"][0] for i in range(n_others)]
 
 
-@pytest.mark.parametrize("prefer_native", [True, False])
-def test_fetch_carries_flow(shard_server, prefer_native):
-    """Both transports mark their fetches; the server's stats prove the
+def test_fetch_carries_flow(shard_server):
+    """The transport marks its fetches; the server's stats prove the
     starved stream was recognized."""
-    c = ShardClient(shard_server, prefer_native=prefer_native)
+    c = ShardClient(shard_server)
     try:
         c.set_flow(0)
         assert len(c.fetch("synthetic:1000000")) == 1000000

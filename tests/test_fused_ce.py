@@ -1,5 +1,5 @@
 """Fused (Pallas) softmax cross-entropy: must match optax exactly in value
-and gradient, fall back off-tile, and compose with the sharded train step."""
+and gradient, refuse an untileable vocab, and compose with the sharded train step."""
 
 import jax
 import jax.numpy as jnp
@@ -46,13 +46,13 @@ def test_bf16_logits(devices):
     assert g.dtype == jnp.bfloat16
 
 
-def test_untiled_vocab_falls_back(devices):
+def test_untiled_vocab_raises(devices):
+    """fused_ce=True is an explicit request: optax must not answer it."""
     key = jax.random.PRNGKey(2)
     logits = jax.random.normal(key, (4, 100), jnp.float32)
     labels = jax.random.randint(key, (4,), 0, 100)
-    got = fused_cross_entropy_with_integer_labels(logits, labels)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(_ref(logits, labels)),
-                               rtol=1e-6)
+    with pytest.raises(ValueError, match="cannot tile vocab 100"):
+        fused_cross_entropy_with_integer_labels(logits, labels)
 
 
 def test_fused_train_step_matches_unfused(devices):

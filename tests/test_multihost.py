@@ -83,7 +83,6 @@ import os, sys, json
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.pop("XLA_FLAGS", None)  # 1 device per process
 import jax
-jax.config.update("jax_platforms", "cpu")
 from serverless_learn_tpu.parallel.multihost import bootstrap_via_coordinator
 world = bootstrap_via_coordinator(sys.argv[1], world_size=2,
                                   name=f"proc{os.getpid()}", timeout_s=60)
