@@ -1,0 +1,18 @@
+"""Model-FLOPs utilisation of the training window: the FLOPs a LoRA step
+requires per token (``chipbench/flops.py``: no frozen-weight gradient, no
+recomputation) times the window's tokens per second, over the chip's
+published peak. The whole step's share; bounds every kernel's roofline."""
+
+from chipbench import flops
+
+
+def read(run, entry):
+    rate = run["end_to_end"].get("train_tokens_per_s")
+    if rate is None:
+        return None
+    cell = run["cell"]
+    per_token = flops.lora_train_flops_per_token(
+        cell.sizes, cell.traffic["tokens_per_sequence"])
+    peak = flops.peaks(run["device"]["kind"])
+    return 100.0 * per_token * rate / (peak["flops_per_s"]
+                                       * run["device"]["count"])
