@@ -100,6 +100,7 @@ from serverless_learn_tpu.telemetry import flight, goodput
 from serverless_learn_tpu.telemetry.tracing import node_name
 from serverless_learn_tpu.telemetry.waterfall import (BoundaryEvents,
                                                       RequestWaterfall)
+from serverless_learn_tpu.utils.tracing import annotate
 
 
 @jitcheck.bucket
@@ -248,10 +249,16 @@ class ContinuousBatchingEngine:
             self._chunk_jit = self._build_chunk()
         self._admit_jits: Dict[tuple, object] = {}
         self.chunks_run = 0
-        self.requests_admitted = 0
         self.requests_finished = 0
         self.requests_cancelled = 0
         self.prefill_chunks_run = 0
+        # Exact running totals the ``sched_iter`` record takes its
+        # per-iteration differences of (see ``_sched_record``): prompt
+        # tokens sent to prefill programs, output tokens appended at
+        # harvest, seconds blocked in harvest's device_get.
+        self.prefill_tokens_total = 0
+        self.tokens_out_total = 0
+        self.harvest_wait_s_total = 0.0
         # Decode row accounting: ``decoded_rows_total`` counts rows that
         # still owed tokens at dispatch; ``dispatched_rows_total`` counts
         # rows of compute actually paid (paged: the compacted nb bucket;
@@ -735,7 +742,6 @@ class ContinuousBatchingEngine:
                 self._m_qwait.observe(wait)
 
     def _post_admit_stats(self, n: int):
-        self.requests_admitted += n
         self._m_admit_sz.observe(n)
         live = self.max_slots - len(self._free_slots())
         self._m_slots.set(live)
@@ -1095,6 +1101,7 @@ class ContinuousBatchingEngine:
                                         self._slot_pages[sid][:n_full])
             snapshot.append((sid, r, bool(fin[i]), r.gen))
         self.prefill_chunks_run += len(batch)
+        self.prefill_tokens_total += sum(tk for _, _, tk in batch)
         self._m_prefill_chunks.inc(len(batch))
         try:
             tok0.copy_to_host_async()
@@ -1176,6 +1183,31 @@ class ContinuousBatchingEngine:
             pass
         return ("pchunk", toks, snapshot)
 
+    def _decode_step(self) -> tuple:
+        """Monolithic decode chunk (legacy baseline): always pays
+        ``max_slots`` rows of compute, live or not."""
+        with goodput.phase("compile" if self.chunks_run == 0
+                           else "decode"):
+            self._state, toks = self._chunk_jit(self.params, self._state)
+        self.chunks_run += 1
+        self._m_chunks.inc()
+        self.decoded_rows_total += sum(
+            1 for r in self._slots if r is not None and not r.finished)
+        self.dispatched_rows_total += self.max_slots
+        # Start the D2H transfer NOW, behind the enqueued compute:
+        # serial per-chunk fetches put a host round trip between chunks
+        # (measured 0.38x of the static engine before this). With the
+        # copy launched at dispatch, harvest's np.asarray finds the
+        # bytes already en route / landed and the transfer overlaps the
+        # in-flight chunks' compute.
+        try:
+            toks.copy_to_host_async()
+        except (AttributeError, RuntimeError):
+            pass  # platform without async D2H: harvest blocks
+        return ("chunk", toks,
+                [(i, r) for i, r in enumerate(self._slots)
+                 if r is not None])
+
     # -- harvest -----------------------------------------------------------
 
     def _harvest(self, fut) -> None:
@@ -1183,6 +1215,7 @@ class ContinuousBatchingEngine:
         t_h0 = time.perf_counter()
         arr = np.asarray(jax.device_get(toks))  # blocks; overlaps in-flight
         t_now = time.perf_counter()
+        self.harvest_wait_s_total += t_now - t_h0
         if t_now - t_h0 > 1e-4:
             # The dispatcher sat blocked in this device_get: tokens of
             # LATER in-flight futures stall behind it (harvest drain).
@@ -1250,6 +1283,7 @@ class ContinuousBatchingEngine:
                 first = r.tokens.index(r.eos_id)
                 r.tokens = r.tokens[:first + 1]
                 r.tokens += [r.eos_id] * (r.max_new - len(r.tokens))
+            self.tokens_out_total += len(r.tokens) - n_before
             if len(r.tokens) >= r.max_new:
                 r.finished = True
                 r.result = {"new_tokens": r.tokens[:r.max_new],
@@ -1289,82 +1323,161 @@ class ContinuousBatchingEngine:
                 r.done.set()
         self._m_slots.set(self.max_slots - len(self._free_slots()))
 
+    # -- the scheduler's own account ---------------------------------------
+
+    def _slot_census(self) -> tuple:
+        """(decoding, prefilling, free, other) over the slot table; the
+        four sum to ``max_slots``. ``other``: cancelled by a timed-out
+        submitter, or finished, and not yet retired."""
+        dec = pre = free = 0
+        for r in self._slots:
+            if r is None:
+                free += 1
+            elif r.finished or r.cancelled:
+                continue
+            elif r.prefilling:
+                pre += 1
+            else:
+                dec += 1
+        return dec, pre, free, self.max_slots - dec - pre - free
+
+    def _sched_counts(self) -> tuple:
+        """The running totals ``_sched_record`` differences, in its
+        order. The prefix trie's skipped tokens are the registry's
+        ``slt_kv_prefix_tokens_total``: exact for one engine per
+        registry, as ``serve`` and the tests have it."""
+        return (self.prefill_chunks_run, self.prefill_tokens_total,
+                int(self._m_kv_hit_tokens.value), self.decoded_rows_total,
+                self.chunks_run, self.tokens_out_total,
+                self.requests_finished, self.harvest_wait_s_total)
+
+    def _sched_record(self, seq: int, ts: list, idle: bool, c0: tuple,
+                      census: tuple, queued: int, sent: list) -> dict:
+        """The ``sched_iter`` record of one working iteration of the
+        dispatch loop: what it found in the slots, what it sent to the
+        device, what came back, and where the host's time went. Built
+        only when an event sink is set.
+
+        ``ts``: ``perf_counter`` at the iteration's start and after the
+        queue drain, admission, the prefill step, the decode chunk and
+        the harvests (the clock of the request spans' marks). ``c0``:
+        ``_sched_counts()`` at its start. ``census``/``queued``: taken
+        after admission, before the prefill step. ``sent``: the futures
+        it dispatched. No ``marks_s`` or ``waterfall`` key: readers pick
+        request spans out of the same sink by those."""
+        (pre_rows, pre_toks, hit_toks, dec_rows, chunks, toks_out,
+         finished, wait_s) = (
+            b - a for a, b in zip(c0, self._sched_counts()))
+        dec, pre, free, other = census
+        ids = dict.fromkeys(
+            e[1].span.span_id for f in sent for e in f[2]
+            if e[1].span is not None)
+        return {
+            "event": "sched_iter", "engine": "continuous",
+            "node": node_name(), "seq": seq, "t0_s": ts[0], "dur_s": ts[5] - ts[0],
+            "phases_s": {
+                "queue_idle": ts[1] - ts[0] if idle else 0.0,
+                "admit": ts[2] - ts[1], "prefill": ts[3] - ts[2],
+                "decode": ts[4] - ts[3], "harvest_wait": wait_s,
+                "harvest": ts[5] - ts[4] - wait_s},
+            "max_slots": self.max_slots, "slots_decoding": dec,
+            "slots_prefilling": pre, "slots_free": free,
+            "slots_other": other, "queued": queued,
+            "prefill_rows": pre_rows, "prefill_tokens": pre_toks,
+            "prefill_hit_tokens": hit_toks, "decode_rows": dec_rows,
+            "decode_steps": chunks * self.chunk_size,
+            "tokens_out": toks_out, "requests_finished": finished,
+            "requests": list(ids)}
+
     def _dispatch_loop(self):
         futures: deque = deque()
         staged: List[_Request] = []
+        seq = 0
         while not self._stop.is_set():
-            # Drain the queue; block briefly only when fully idle.
-            idle = (not futures and not staged
-                    and all(r is None for r in self._slots))
-            try:
-                if idle:
-                    # A fully idle engine's blocking wait is "idle" on
-                    # the goodput ledger — the busy/admit/compile split
-                    # below is what the badput breakdown reports.
-                    with goodput.phase("idle"):
-                        staged.append(self._q.get(timeout=0.05))
-                else:
-                    staged.append(self._q.get(timeout=0.0))
-                while True:
-                    staged.append(self._q.get_nowait())
-            except queue.Empty:
-                pass
-            try:
+            seq += 1
+            # ``sched.*``: the iteration and its phases on the profiler's
+            # clock, beside the jit_pre / jit_chunk programs they launch
+            # in any captured trace.
+            with annotate("sched.iter"):
+                self._iterate(seq, futures, staged)
+
+    def _iterate(self, seq: int, futures: deque, staged: List[_Request]):
+        """One scheduler iteration: drain the queue, admit, one prefill
+        step, one decode chunk, harvest down to ``pipeline_depth``."""
+        sink = self.event_log   # read once: its owner may swap it
+        on = sink is not None
+        if on:
+            ts = [time.perf_counter()]
+            c0 = self._sched_counts()
+        # Drain the queue; block briefly only when fully idle.
+        idle = (not futures and not staged
+                and all(r is None for r in self._slots))
+        try:
+            if idle:
+                # A fully idle engine's blocking wait is "idle" on
+                # the goodput ledger — the busy/admit/compile split
+                # below is what the badput breakdown reports.
+                with goodput.phase("idle"):
+                    staged.append(self._q.get(timeout=0.05))
+            else:
+                staged.append(self._q.get(timeout=0.0))
+            while True:
+                staged.append(self._q.get_nowait())
+        except queue.Empty:
+            pass
+        if on:
+            ts.append(time.perf_counter())
+        sent: list = []   # futures dispatched by this iteration
+        admitted = False
+        harvested = 0
+        try:
+            with annotate("sched.admit"):
                 if staged:
                     if self._paged:
                         # Paged admission only allocates pages + a slot;
                         # the compute happens in the prefill step below.
                         with goodput.phase("admit"):
-                            if self._admit_paged(staged):
-                                self._m_activity.set(time.time())
+                            admitted = self._admit_paged(staged)
                     else:
                         fut = self._admit(staged)
                         if fut is not None:
                             futures.append(fut)
-                            self._m_activity.set(time.time())
-                if self._paged:
+                            sent.append(fut)
+            if on:
+                ts.append(time.perf_counter())
+                census = self._slot_census()
+                queued = len(staged) + self._q.qsize()
+            if self._paged:
+                with annotate("sched.prefill"):
                     fut = self._prefill_step(staged)
                     if fut is not None:
                         futures.append(fut)
-                        self._m_activity.set(time.time())
+                        sent.append(fut)
+                if on:
+                    ts.append(time.perf_counter())
+                with annotate("sched.decode"):
                     fut = self._decode_step_paged(staged)
                     if fut is not None:
                         futures.append(fut)
-                        self._m_activity.set(time.time())
-                    self._m_kv_in_use.set(self._pool.used_blocks)
-                    self._maybe_resolve_kv_alert()
-                elif any(r is not None and not r.finished
-                         for r in self._slots):
-                    with goodput.phase("compile" if self.chunks_run == 0
-                                       else "decode"):
-                        self._state, toks = self._chunk_jit(self.params,
-                                                            self._state)
-                    self.chunks_run += 1
-                    self._m_chunks.inc()
-                    # Row accounting: the monolithic chunk always pays
-                    # max_slots rows of compute, live or not.
-                    self.decoded_rows_total += sum(
-                        1 for r in self._slots
-                        if r is not None and not r.finished)
-                    self.dispatched_rows_total += self.max_slots
-                    self._m_activity.set(time.time())
-                    # Start the D2H transfer NOW, behind the enqueued
-                    # compute: serial per-chunk fetches put a host round
-                    # trip between chunks (measured 0.38x of the static
-                    # engine before this). With the copy launched at
-                    # dispatch, harvest's np.asarray finds the bytes
-                    # already en route / landed and the transfer overlaps
-                    # the in-flight chunks' compute.
-                    try:
-                        toks.copy_to_host_async()
-                    except (AttributeError, RuntimeError):
-                        pass  # platform without async D2H: harvest blocks
-                    futures.append(
-                        ("chunk", toks,
-                         [(i, r) for i, r in enumerate(self._slots)
-                          if r is not None]))
-                # Keep <= pipeline_depth chunks in flight; drain fully
-                # when nothing is active (nobody else will harvest).
+                        sent.append(fut)
+                self._m_kv_in_use.set(self._pool.used_blocks)
+                self._maybe_resolve_kv_alert()
+            else:
+                if on:
+                    ts.append(ts[-1])  # its admit is its prefill
+                if any(r is not None and not r.finished
+                       for r in self._slots):
+                    with annotate("sched.decode"):
+                        fut = self._decode_step()
+                    futures.append(fut)
+                    sent.append(fut)
+            if on:
+                ts.append(time.perf_counter())
+            if sent or admitted:
+                self._m_activity.set(time.time())
+            # Keep <= pipeline_depth chunks in flight; drain fully
+            # when nothing is active (nobody else will harvest).
+            with annotate("sched.harvest"):
                 while futures and (len(futures) > self.pipeline_depth
                                    or not any(r is not None
                                               for r in self._slots)):
@@ -1372,38 +1485,46 @@ class ContinuousBatchingEngine:
                     # work actually drains: productive "decode" time.
                     with goodput.phase("decode"):
                         self._harvest(futures.popleft())
-            except Exception as ex:
-                # Fail every in-flight and staged request; a poisoned
-                # device state must not wedge the dispatcher silently.
-                err = {"error": f"{type(ex).__name__}: {ex}"}
-                for _, _, snapshot in futures:
-                    for entry in snapshot:
-                        r = entry[1]
-                        if not r.finished:
-                            r.finished, r.result = True, dict(err)
-                            r.done.set()
-                futures.clear()
-                for r in staged:
-                    r.finished, r.result = True, dict(err)
-                    r.done.set()
-                staged.clear()
-                for i, r in enumerate(self._slots):
-                    if r is not None and not r.finished:
+                    harvested += 1
+            if on and (sent or admitted or harvested):
+                ts.append(time.perf_counter())
+                # Straight to the sink: the flight ring's 2,048 slots
+                # of crash forensics are for request spans and lifecycle
+                # events, not for several scheduler records a second.
+                sink.emit(self._sched_record(
+                    seq, ts, idle, c0, census, queued, sent))
+        except Exception as ex:
+            # Fail every in-flight and staged request; a poisoned
+            # device state must not wedge the dispatcher silently.
+            err = {"error": f"{type(ex).__name__}: {ex}"}
+            for _, _, snapshot in futures:
+                for entry in snapshot:
+                    r = entry[1]
+                    if not r.finished:
                         r.finished, r.result = True, dict(err)
                         r.done.set()
-                    self._slots[i] = None
-                if self._paged:
-                    # Rebuild the allocator with the device state: a
-                    # poisoned pool's tables point at freed pages.
-                    self._pool = BlockPool(self._pool.num_blocks, self._ps)
-                    if self._trie is not None:
-                        self._trie = PrefixTrie(
-                            self._pool, max_blocks=self._trie.max_blocks,
-                            hit_window=self.kv.prefix_hit_window)
-                    self._tbl[:] = self._pool.sentinel
-                    self._slot_pages = [[] for _ in range(self.max_slots)]
-                    self._pending_cow.clear()
-                self._state = self._init_state()
+            futures.clear()
+            for r in staged:
+                r.finished, r.result = True, dict(err)
+                r.done.set()
+            staged.clear()
+            for i, r in enumerate(self._slots):
+                if r is not None and not r.finished:
+                    r.finished, r.result = True, dict(err)
+                    r.done.set()
+                self._slots[i] = None
+            if self._paged:
+                # Rebuild the allocator with the device state: a
+                # poisoned pool's tables point at freed pages.
+                self._pool = BlockPool(self._pool.num_blocks, self._ps)
+                if self._trie is not None:
+                    self._trie = PrefixTrie(
+                        self._pool, max_blocks=self._trie.max_blocks,
+                        hit_window=self.kv.prefix_hit_window)
+                self._tbl[:] = self._pool.sentinel
+                self._slot_pages = [[] for _ in range(self.max_slots)]
+                self._pending_cow.clear()
+            self._state = self._init_state()
 
     # -- stats / warm / stop ----------------------------------------------
 

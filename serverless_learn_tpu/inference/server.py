@@ -45,7 +45,7 @@ class GenerationServer:
                  registry=None, metrics_port: Optional[int] = None,
                  event_log_path: Optional[str] = None,
                  profile_dir: Optional[str] = None, kv=None,
-                 waterfall=None):
+                 waterfall=None, event_sink=None):
         from serverless_learn_tpu.config import KVCacheConfig
         from serverless_learn_tpu.telemetry import (JsonlEventLog,
                                                     get_registry)
@@ -60,8 +60,14 @@ class GenerationServer:
         self.params = params
         self.conn_timeout_s = conn_timeout_s
         self.registry = registry or get_registry()
-        self.event_log = (JsonlEventLog(event_log_path)
-                          if event_log_path else None)
+        # Where the engine's request spans, scheduler records and
+        # lifecycle events go: ``event_sink`` is any object with
+        # ``emit(dict)`` (an embedding process's in-memory list, a
+        # benchmark's); else the JSONL file at ``event_log_path``.
+        if event_sink is not None and event_log_path:
+            raise ValueError("give event_sink or event_log_path, not both")
+        self.event_log = event_sink if event_sink is not None else (
+            JsonlEventLog(event_log_path) if event_log_path else None)
         if not isinstance(engine, str):
             # A pre-built engine object (anything with submit()/stop()):
             # the fleet layer's stub replicas and embedding tests inject

@@ -50,6 +50,15 @@ _NEG = -1e30
 # variance of a few ms between runs is normal; treat 14-16 ms as the band.
 _BLOCK_CANDIDATES = (512, 256, 128)
 
+# The kernels' names are an interface: ``pallas_call(name=)`` puts them
+# into the HLO instruction's name (``flash_fwd.<n>``; without it all
+# three read ``attn.<n>``, the calling flax module's scope), which is how
+# a device trace and the benchmark's ``device_ops`` tell forward, dq and
+# dk/dv apart. ``tests/test_program_names.py`` pins them.
+FWD_KERNEL = "flash_fwd"
+BWD_DQ_KERNEL = "flash_bwd_dq"
+BWD_DKV_KERNEL = "flash_bwd_dkv"
+
 
 def _pick_block(n: int):
     for c in _BLOCK_CANDIDATES:
@@ -222,6 +231,7 @@ def _flash_fwd_bhsd(q, k, v, mask_arg, mask_mode, *, causal: bool,
     args = af + [q, k, v] + ab
     out, lse = pl.pallas_call(
         kernel,
+        name=FWD_KERNEL,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -403,6 +413,7 @@ def _flash_bwd_bhsd(q, k, v, mask_arg, mask_mode, lse, g, out, *,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           mask_mode=mask_mode),
+        name=BWD_DQ_KERNEL,
         grid=(B, H, T // block_q, S // block_k),
         in_specs=in_specs,
         out_specs=qspec,
@@ -427,6 +438,7 @@ def _flash_bwd_bhsd(q, k, v, mask_arg, mask_mode, lse, g, out, *,
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           mask_mode=mask_mode),
+        name=BWD_DKV_KERNEL,
         grid=(B, H, S // block_k, T // block_q),
         in_specs=in_specs2,
         out_specs=[dkspec, dkspec],

@@ -143,14 +143,26 @@ def get_tracer() -> Tracer:
     return _global_tracer
 
 
-def annotate(name: str):
-    """Named device-trace annotation; no-op if the profiler is unavailable."""
+def _resolve_annotation():
     try:
         import jax.profiler
 
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation
     except Exception:
-        return contextlib.nullcontext()
+        return lambda name: contextlib.nullcontext()
+
+
+_annotation = None  # resolved on first use: importing this module stays jax-free
+
+
+def annotate(name: str):
+    """Named device-trace annotation; no-op if the profiler is unavailable.
+    The serving scheduler opens five per iteration whether or not a trace
+    is running, so the import is resolved once, not per call."""
+    global _annotation
+    if _annotation is None:
+        _annotation = _resolve_annotation()
+    return _annotation(name)
 
 
 def step_annotation(step: int):
