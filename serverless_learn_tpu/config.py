@@ -425,7 +425,8 @@ class KVCacheConfig:
     prompt prefixes map to refcounted read-only blocks, copy-on-write at
     the first divergent block) and chunked prefill (``prefill_chunk``:
     long prompts split into chunks the scheduler interleaves between
-    decode steps, budgeted per boundary by ``prefill_budget``).
+    decode chunks; how many an iteration dispatches the engine derives
+    from its own slots unless ``prefill_budget`` caps it).
     """
 
     paged: bool = True            # False = legacy monolithic KV rows
@@ -436,9 +437,14 @@ class KVCacheConfig:
     # backpressure + preemption keep it correct).
     num_blocks: int = 0
     prefill_chunk: int = 32       # prompt tokens per prefill chunk (0 = whole)
-    # Max prompt tokens dispatched per scheduler boundary across all
-    # prefilling slots — bounds how long a decode boundary can stall.
-    prefill_budget: int = 64
+    # Bounds how long the rows that are decoding wait for their next
+    # chunk while prompts prefill. 0 = derived: every scheduler iteration
+    # feeds every prefilling slot, in at most chunk_size // 2 prefill
+    # programs while a slot decodes (about a third of the iteration) and
+    # unbounded while none does. > 0 = cap on the prompt tokens one
+    # iteration dispatches across all prefilling slots (an interactive
+    # deployment that wants a tighter stall).
+    prefill_budget: int = 0
     prefix_cache: bool = True     # shared-prefix block reuse (trie)
     # Max blocks the prefix trie may pin after their owners retire
     # (0 = auto: num_blocks // 4). LRU-evicted under pool pressure.

@@ -446,8 +446,7 @@ def run_kv_smoke(seed: int = 0, rate_rps: float = 10.0,
         registry = MetricsRegistry()
         ledger = goodput_mod.PhaseLedger(emit=False)
         prev = goodput_mod.set_ledger(ledger)
-        kv = KVCacheConfig(paged=paged, block_size=16, prefill_chunk=32,
-                           prefill_budget=64)
+        kv = KVCacheConfig(paged=paged, block_size=16, prefill_chunk=32)
         srv = GenerationServer(module, params, engine="continuous",
                                max_batch=4, chunk_size=8,
                                registry=registry, kv=kv).start()
@@ -670,8 +669,7 @@ def run_waterfall_smoke(seed: int = 0, events_path: Optional[str] = None,
     #   count outgrows the warmed width — while their token gaps are
     #   being traced.
     kv = KVCacheConfig(paged=True, block_size=16, num_blocks=18,
-                       prefix_cache=False, prefill_chunk=32,
-                       prefill_budget=64)
+                       prefix_cache=False, prefill_chunk=32)
     eng = ContinuousBatchingEngine(module, params, max_slots=4,
                                    chunk_size=8, registry=registry,
                                    event_log=log, kv=kv,

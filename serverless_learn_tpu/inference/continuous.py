@@ -27,9 +27,13 @@ Round 13 replaced the slots' ONE monolithic resident KV allocation
   divergent block. Sound because K/V depend only on token values and
   absolute RoPE positions.
 * **Chunked prefill** (``prefill_chunk``): long prompts admit in chunks
-  the scheduler interleaves between decode boundaries (budgeted by
-  ``prefill_budget``), so a 4k-token prompt no longer stalls the decode
-  batch for one giant admit. Admission under pool pressure is TYPED
+  the scheduler interleaves between decode boundaries, so a 4k-token
+  prompt no longer stalls the decode batch for one giant admit. Every
+  iteration feeds EVERY mid-prefill slot, program after program, up to a
+  quota the engine derives from its own slots (``_prefill_steps``:
+  ``chunk_size // 2`` programs while a slot decodes, no bound while none
+  does; an explicit ``prefill_budget`` caps the iteration's prompt
+  tokens instead). Admission under pool pressure is TYPED
   backpressure (the request stays queued, ``slt_kv_admit_blocked_total``
   counts, a ``kv.blocks_exhausted`` alert event fires for `slt doctor`);
   decode-time pressure first evicts cached prefixes, then deterministically
@@ -76,6 +80,7 @@ this surface is judged against the matching-or-beating bar alone.
 
 from __future__ import annotations
 
+import math
 import queue
 import threading
 import time
@@ -231,8 +236,11 @@ class ContinuousBatchingEngine:
                 if kv.prefix_cache else None)
             self._pmod = kvcache.paged_module(module, ps, num_blocks)
             self.prefill_chunk = kv.prefill_chunk or max_seq
-            self.prefill_budget = max(kv.prefill_budget,
-                                      self.prefill_chunk)
+            # 0 = derived per iteration (``_prefill_steps``). An explicit
+            # cap is at least one chunk: the oldest row always advances.
+            self.prefill_budget = (max(kv.prefill_budget,
+                                       self.prefill_chunk)
+                                   if kv.prefill_budget > 0 else 0)
             # Host-owned block tables: [max_slots, max_pages] page ids,
             # sentinel (== num_blocks) marking unallocated entries.
             self._tbl = np.full((max_slots, self._max_pages),
@@ -989,31 +997,62 @@ class ContinuousBatchingEngine:
             self._post_admit_stats(admitted)
         return admitted > 0
 
-    def _prefill_step(self, staged: List[_Request]) -> Optional[tuple]:
-        """Advance mid-prefill slots by up to ``prefill_chunk`` tokens
-        each, bounded by ``prefill_budget`` per boundary — the policy
-        that keeps a long prompt from stalling the decode batch."""
+    def _prefill_steps(self) -> List[tuple]:
+        """This iteration's prefill programs: every mid-prefill slot is
+        fed, oldest admitted first, program after program, until no slot
+        is mid-prefill or the iteration's quota is spent.
+
+        The quota bounds how long the rows that are decoding wait for
+        their next chunk, in units the engine has: a prefill program
+        costs about one stream of the weights and a decode chunk
+        ``chunk_size`` of them, so with a slot decoding an iteration
+        dispatches at most ``chunk_size // 2`` programs (prefill is then
+        at most about a third of the iteration); with no slot decoding
+        there is nothing to stall and no bound. An explicit
+        ``prefill_budget`` caps the iteration's prompt tokens instead."""
+        budget = self.prefill_budget or math.inf
+        steps = math.inf
+        if not self.prefill_budget and self._slot_census()[0]:
+            steps = max(1, self.chunk_size // 2)
+        t0 = self.prefill_tokens_total
+        refused: set = set()  # slots the pool refused pages this iteration
+        futs: List[tuple] = []
+        while len(futs) < steps:
+            fut = self._prefill_step(
+                budget - (self.prefill_tokens_total - t0), refused)
+            if fut is None:
+                break
+            futs.append(fut)
+        return futs
+
+    def _prefill_step(self, budget: float,
+                      refused: set) -> Optional[tuple]:
+        """One prefill program: every mid-prefill slot advances by up to
+        ``prefill_chunk`` tokens, oldest admitted first; the rows stop at
+        the first that does not fit the token ``budget`` (``inf``: none).
+        A slot the pool refuses pages joins ``refused`` and sits out the
+        rest of the iteration (nothing frees pages before the next
+        harvest)."""
         rows = []
         for sid, r in enumerate(self._slots):
-            if r is None or not r.prefilling or r.finished:
+            if r is None or not r.prefilling or r.finished \
+                    or sid in refused:
                 continue
             if r.cancelled:
                 self._cancel(r)
                 self._retire_slot(sid)
                 continue
             rows.append((sid, r))
-        if not rows:
-            return None
-        rows.sort(key=lambda sr: sr[1].admit_seq)  # FIFO budget
-        budget = self.prefill_budget
+        rows.sort(key=lambda sr: sr[1].admit_seq)  # FIFO
         batch = []
         for sid, r in rows:
             rem = len(r.prompt) - r.prefill_pos
             tk = min(rem, self.prefill_chunk)
-            if batch and tk > budget:
+            if tk > budget:
                 break
             if not self._ensure_pages(sid, r.prefill_pos + tk):
                 self._note_kv_blocked()
+                refused.add(sid)
                 continue
             budget -= tk
             batch.append((sid, r, tk))
@@ -1346,7 +1385,7 @@ class ContinuousBatchingEngine:
         order. The prefix trie's skipped tokens are the registry's
         ``slt_kv_prefix_tokens_total``: exact for one engine per
         registry, as ``serve`` and the tests have it."""
-        return (self.prefill_chunks_run, self.prefill_tokens_total,
+        return (self.prefill_tokens_total,
                 int(self._m_kv_hit_tokens.value), self.decoded_rows_total,
                 self.chunks_run, self.tokens_out_total,
                 self.requests_finished, self.harvest_wait_s_total)
@@ -1359,16 +1398,21 @@ class ContinuousBatchingEngine:
         only when an event sink is set.
 
         ``ts``: ``perf_counter`` at the iteration's start and after the
-        queue drain, admission, the prefill step, the decode chunk and
+        queue drain, admission, the prefill programs, the decode chunk and
         the harvests (the clock of the request spans' marks). ``c0``:
         ``_sched_counts()`` at its start. ``census``/``queued``: taken
-        after admission, before the prefill step. ``sent``: the futures
+        after admission, before the prefill programs. ``sent``: the futures
         it dispatched. No ``marks_s`` or ``waterfall`` key: readers pick
         request spans out of the same sink by those."""
-        (pre_rows, pre_toks, hit_toks, dec_rows, chunks, toks_out,
+        (pre_toks, hit_toks, dec_rows, chunks, toks_out,
          finished, wait_s) = (
             b - a for a, b in zip(c0, self._sched_counts()))
         dec, pre, free, other = census
+        # ``prefill_steps``: the prefill programs it dispatched;
+        # ``prefill_rows``: the distinct slots they fed (a slot that got
+        # a chunk in each of three programs counts once).
+        pre_futs = [f for f in sent if f[0] == "prefill"]
+        pre_rows = len({e[0] for f in pre_futs for e in f[2]})
         ids = dict.fromkeys(
             e[1].span.span_id for f in sent for e in f[2]
             if e[1].span is not None)
@@ -1383,7 +1427,8 @@ class ContinuousBatchingEngine:
             "max_slots": self.max_slots, "slots_decoding": dec,
             "slots_prefilling": pre, "slots_free": free,
             "slots_other": other, "queued": queued,
-            "prefill_rows": pre_rows, "prefill_tokens": pre_toks,
+            "prefill_steps": len(pre_futs), "prefill_rows": pre_rows,
+            "prefill_tokens": pre_toks,
             "prefill_hit_tokens": hit_toks, "decode_rows": dec_rows,
             "decode_steps": chunks * self.chunk_size,
             "tokens_out": toks_out, "requests_finished": finished,
@@ -1402,8 +1447,9 @@ class ContinuousBatchingEngine:
                 self._iterate(seq, futures, staged)
 
     def _iterate(self, seq: int, futures: deque, staged: List[_Request]):
-        """One scheduler iteration: drain the queue, admit, one prefill
-        step, one decode chunk, harvest down to ``pipeline_depth``."""
+        """One scheduler iteration: drain the queue, admit, the prefill
+        programs its quota allows (``_prefill_steps``), one decode chunk,
+        harvest down to ``pipeline_depth`` futures in flight."""
         sink = self.event_log   # read once: its owner may swap it
         on = sink is not None
         if on:
@@ -1449,10 +1495,9 @@ class ContinuousBatchingEngine:
                 queued = len(staged) + self._q.qsize()
             if self._paged:
                 with annotate("sched.prefill"):
-                    fut = self._prefill_step(staged)
-                    if fut is not None:
-                        futures.append(fut)
-                        sent.append(fut)
+                    pre = self._prefill_steps()
+                    futures.extend(pre)
+                    sent.extend(pre)
                 if on:
                     ts.append(time.perf_counter())
                 with annotate("sched.decode"):

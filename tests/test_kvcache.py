@@ -226,6 +226,108 @@ def test_paged_engine_greedy_exact_with_chunked_prefill(model):
         eng.stop()
 
 
+def test_paged_engine_greedy_exact_with_every_slot_fed(model):
+    """The derived prefill quota (``prefill_budget`` left at 0): eight
+    concurrent unequal prompts, each longer than one chunk, go through
+    programs of up to eight rows, several an iteration, and stay
+    byte-identical to solo generate."""
+    module, params = model
+    eng = _paged_engine(module, params, max_slots=8,
+                        kv=KVCacheConfig(block_size=4, prefill_chunk=4))
+    assert eng.prefill_budget == 0
+    try:
+        prompts = [[(7 * i + 3 * j) % 97 + 1 for j in range(5 + 3 * i)]
+                   for i in range(8)]   # 5, 8, ... 26 tokens: 2-7 chunks
+        results = [None] * len(prompts)
+
+        def client(i):
+            results[i] = eng.submit(prompts[i], 5 + i, temperature=0.0,
+                                    top_k=0, eos_id=None, seed=0)
+
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        for i, p in enumerate(prompts):
+            assert "error" not in results[i], results[i]
+            assert results[i]["new_tokens"] == _solo(module, params, p,
+                                                     5 + i), \
+                f"request {i} diverged under the derived prefill quota"
+        assert eng.prefill_chunks_run == sum(
+            pages_for(len(p), 4) for p in prompts)
+        st = eng.kv_stats()
+        assert (st["blocks_total"] - st["blocks_free"]
+                == st["prefix_blocks_cached"])
+    finally:
+        eng.stop()
+
+
+def _stopped_engine_with_prefilling(module, params, prompts, seqs, kv,
+                                    max_slots=4):
+    """A paged engine whose dispatcher has stopped, with ``prompts``
+    placed mid-prefill in slots 0.. and admitted in the order ``seqs``:
+    the scheduler's prefill functions can then be called by hand."""
+    from serverless_learn_tpu.inference.continuous import _Request
+
+    eng = ContinuousBatchingEngine(module, params, max_slots=max_slots,
+                                   chunk_size=4, kv=kv,
+                                   registry=MetricsRegistry())
+    eng.stop()
+    for sid, (prompt, seq) in enumerate(zip(prompts, seqs)):
+        eng._slots[sid] = _Request(
+            prompt=np.asarray(prompt, np.int32), max_new=4,
+            temperature=0.0, top_k=0, eos_id=None, seed=0, admitted=True,
+            prefilling=True, admit_seq=seq)
+    return eng
+
+
+def test_prefill_feeds_oldest_admitted_first(model):
+    """FIFO among admitted rows is by admission order, not slot order:
+    under a budget of one chunk the only row fed is the oldest; with the
+    derived quota every row is in every program, oldest first."""
+    module, params = model
+    prompts = [list(range(1 + 10 * i, 9 + 10 * i)) for i in range(3)]
+    kv = KVCacheConfig(block_size=4, prefill_chunk=4, prefill_budget=4,
+                       prefix_cache=False)
+    eng = _stopped_engine_with_prefilling(module, params, prompts,
+                                          seqs=[3, 1, 2], kv=kv)
+    fed = []
+    for _ in range(6):
+        (fut,) = eng._prefill_steps()
+        fed.append([sid for sid, _, _, _ in fut[2]])
+    assert fed == [[1], [1], [2], [2], [0], [0]]
+    assert eng._prefill_steps() == []
+
+    derived = KVCacheConfig(block_size=4, prefill_chunk=4,
+                            prefix_cache=False)
+    eng = _stopped_engine_with_prefilling(module, params, prompts,
+                                          seqs=[3, 1, 2], kv=derived)
+    futs = eng._prefill_steps()   # no slot decodes: no bound
+    assert [[sid for sid, _, _, _ in f[2]] for f in futs] == [[1, 2, 0]] * 2
+    assert not any(r.prefilling for r in eng._slots if r is not None)
+
+
+def test_refused_pages_sit_out_the_iteration(model):
+    """Back-pressure inside an iteration of several programs: a slot the
+    pool refuses pages is counted once and sits out the rest of the
+    iteration (nothing frees pages before the next harvest); the loop
+    ends when every remaining row is refused."""
+    module, params = model
+    kv = KVCacheConfig(block_size=4, num_blocks=16, prefill_chunk=4,
+                       prefix_cache=False)
+    prompts = [list(range(1, 41)), list(range(41, 81))]  # 10 pages each
+    eng = _stopped_engine_with_prefilling(module, params, prompts,
+                                          seqs=[1, 2], kv=kv)
+    futs = eng._prefill_steps()
+    assert len(futs) == 8                       # 16 pages, two a program
+    assert [r.prefill_pos for r in eng._slots[:2]] == [32, 32]
+    assert eng._pool.free_blocks == 0
+    assert int(eng._m_kv_blocked.value) == 2    # once a slot, not a program
+    assert all(r.prefilling for r in eng._slots[:2])
+
+
 def test_paged_engine_seeded_sampling_matches_monolithic(model):
     """Seeded sampling: identical tokens from the paged and monolithic
     engines (the fold_in(seed, position) streams are layout-blind)."""

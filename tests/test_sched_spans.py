@@ -4,6 +4,11 @@ sink beside the request spans.
 
 CPU, tiny model, a list sink. The pool is large enough that nothing is
 preempted and no request has an EOS, so every sum below is exact.
+
+Scenarios ``unique``, ``shared_prefix`` and ``monolithic`` run under an
+explicit ``prefill_budget`` (a cap on an iteration's prompt tokens);
+``derived`` runs the default quota: every mid-prefill slot fed every
+iteration, ``chunk_size // 2`` programs at most while a slot decodes.
 """
 
 import threading
@@ -28,6 +33,14 @@ UNIQUE = [([5, 9, 11], 6), ([7, 3, 2, 8, 1, 30, 12, 9, 4, 2, 6, 1, 8], 9),
 SHARED = [(SYSTEM + tail, n) for tail, n in
           [([11, 2], 5), ([9, 7], 6), ([44, 45, 46], 4), ([8], 7),
            ([7, 7, 7, 7, 7], 3), ([1, 2], 5)]]
+# The derived quota's mix. The first request runs alone: its 8 chunks go
+# through an idle engine. Of the rest, six prompts have two chunks or
+# more, so whatever order the threads arrive in, one of them is admitted
+# while another slot decodes (CHUNK // 2 = 2 programs an iteration).
+LONG = ([(list(range(100, 129)), 5)] + UNIQUE
+        + [(list(range(40, 10, -1)), 8), (list(range(50, 59)), 6),
+           (list(range(60, 71)), 3)])
+BUDGET = 8
 
 
 class ListSink:
@@ -47,12 +60,28 @@ def model(devices):
     return bundle.module, params
 
 
-def _engine(module, params, paged: bool = True, **kw):
-    kv = (KVCacheConfig(block_size=4, prefill_chunk=4, prefill_budget=8)
+def _engine(module, params, paged: bool = True, budget: int = BUDGET,
+            **kw):
+    kv = (KVCacheConfig(block_size=4, prefill_chunk=4,
+                        prefill_budget=budget)
           if paged else KVCacheConfig(paged=False))
     return ContinuousBatchingEngine(
         module, params, max_slots=MAX_SLOTS, chunk_size=CHUNK, kv=kv,
         registry=MetricsRegistry(), **kw)
+
+
+def _count_prefill_programs(eng) -> list:
+    """Every prefill program the engine dispatches fetches its jit by
+    ``_paged_prefill_jit`` once: count the fetches."""
+    calls = []
+    real = eng._paged_prefill_jit
+
+    def counting(nb, T, W):
+        calls.append((nb, T, W))
+        return real(nb, T, W)
+
+    eng._paged_prefill_jit = counting
+    return calls
 
 
 def _drive(eng, requests, first_alone: bool = False) -> list:
@@ -80,27 +109,45 @@ def _drive(eng, requests, first_alone: bool = False) -> list:
     return replies
 
 
-@pytest.fixture(scope="module", params=["unique", "shared_prefix",
-                                        "monolithic"])
-def run(request, model):
-    """One engine run per scenario, its sink's records split by kind."""
+def _run_scenario(model, name: str) -> dict:
     module, params = model
-    requests = SHARED if request.param == "shared_prefix" else UNIQUE
+    requests = {"shared_prefix": SHARED, "derived": LONG}.get(name, UNIQUE)
     sink = ListSink()
-    eng = _engine(module, params, paged=request.param != "monolithic",
+    eng = _engine(module, params, paged=name != "monolithic",
+                  budget=0 if name == "derived" else BUDGET,
                   event_log=sink)
+    programs = _count_prefill_programs(eng) if name != "monolithic" else []
     ring0 = len(flight.events())
     try:
         replies = _drive(eng, requests,
-                         first_alone=request.param == "shared_prefix")
+                         first_alone=name in ("shared_prefix", "derived"))
     finally:
         eng.stop()
-    return {"scenario": request.param, "requests": requests,
-            "replies": replies, "engine": eng,
+    return {"scenario": name, "requests": requests,
+            "replies": replies, "engine": eng, "programs": programs,
             "iters": [r for r in sink.records
                       if r.get("event") == "sched_iter"],
             "spans": [r for r in sink.records if r.get("event") == "span"],
             "ring": flight.events()[ring0:]}
+
+
+@pytest.fixture(scope="module")
+def scenario(model):
+    """One engine run per scenario, its sink's records split by kind."""
+    runs = {}
+
+    def get(name: str) -> dict:
+        if name not in runs:
+            runs[name] = _run_scenario(model, name)
+        return runs[name]
+
+    return get
+
+
+@pytest.fixture(scope="module", params=["unique", "shared_prefix",
+                                        "monolithic", "derived"])
+def run(request, scenario):
+    return scenario(request.param)
 
 
 def _total(run, field):
@@ -129,9 +176,9 @@ def test_census_sums_to_max_slots(run):
                                  "slots_free", "slots_other")]
         assert min(census) >= 0 and sum(census) == MAX_SLOTS, r
         assert r["queued"] >= 0
-    # Seven requests over four slots: some iteration saw a queue, and
-    # some saw every slot taken.
-    if run["scenario"] == "unique":
+    # More requests than slots: some iteration saw a queue, and some saw
+    # every slot taken.
+    if run["scenario"] in ("unique", "derived"):
         assert max(r["queued"] for r in run["iters"]) > 0
         assert min(r["slots_free"] for r in run["iters"]) == 0
 
@@ -145,16 +192,76 @@ def test_prompt_tokens_are_prefilled_or_hit_exactly_once(run):
         assert (sent, hit, _total(run, "prefill_rows")) == (0, 0, 0)
         return
     assert sent + hit == prompt_tokens
-    assert _total(run, "prefill_rows") == run["engine"].prefill_chunks_run
     if run["scenario"] == "shared_prefix":
         # Every later request skips the system prompt's whole blocks.
         assert hit >= (len(SHARED) - 1) * 8
     else:
         assert hit == 0
     for r in run["iters"]:
-        # The engine's own rule: at most prefill_budget tokens a step.
-        assert r["prefill_tokens"] <= 8
+        if run["scenario"] != "derived":
+            # An explicit prefill_budget still caps an iteration's tokens.
+            assert r["prefill_tokens"] <= BUDGET
         assert r["prefill_rows"] <= r["slots_prefilling"]
+
+
+def _row_chunks(run) -> int:
+    """Row-chunks as the requests' own waterfalls count them."""
+    n = 0
+    for s in run["spans"]:
+        for ph in s["waterfall"]["phases"]:
+            if ph["phase"] == "prefill":
+                n += len(ph.get("chunks", ()))
+    return n
+
+
+def test_prefill_steps_are_the_programs_dispatched(run):
+    """``prefill_steps`` counts programs, ``prefill_rows`` the distinct
+    slots they fed; row-chunks are the engine's ``prefill_chunks_run``."""
+    eng = run["engine"]
+    assert _total(run, "prefill_steps") == len(run["programs"])
+    if run["scenario"] == "monolithic":
+        assert eng.prefill_chunks_run == 0
+        return
+    assert len(run["programs"]) > 0
+    assert _row_chunks(run) == eng.prefill_chunks_run
+    assert (_total(run, "prefill_steps") <= eng.prefill_chunks_run
+            <= _total(run, "prefill_steps") * MAX_SLOTS)
+    assert _total(run, "prefill_rows") <= eng.prefill_chunks_run
+    for r in run["iters"]:
+        assert bool(r["prefill_steps"]) == bool(r["prefill_rows"]) \
+            == bool(r["prefill_tokens"])
+        assert r["prefill_rows"] <= r["prefill_steps"] * MAX_SLOTS
+        # No program is wider than the programs the engine has.
+        assert r["prefill_tokens"] <= r["prefill_steps"] * MAX_SLOTS * 4
+    assert all(nb <= MAX_SLOTS and T <= 8 for nb, T, _ in run["programs"])
+
+
+def test_derived_quota_feeds_every_prefilling_slot(scenario):
+    """The default quota: every mid-prefill slot gets prompt tokens in
+    every iteration (the pool refuses no pages here); while a slot
+    decodes an iteration dispatches at most ``chunk_size // 2`` prefill
+    programs; while none does, every prompt finishes its prefill in that
+    iteration and joins its decode chunk."""
+    run = scenario("derived")
+    assert int(run["engine"]._m_kv_blocked.value) == 0
+    stalled = unbounded = capped = 0
+    for r in run["iters"]:
+        if not r["slots_prefilling"]:
+            assert r["prefill_steps"] == 0
+            continue
+        assert r["prefill_rows"] == r["slots_prefilling"], r
+        if r["slots_decoding"]:
+            stalled += 1
+            assert 1 <= r["prefill_steps"] <= CHUNK // 2, r
+            capped += r["prefill_steps"] == CHUNK // 2
+        else:
+            unbounded += 1
+            assert r["decode_rows"] == r["slots_prefilling"], r
+    # Both regimes occurred, the cap was reached, and the first prompt's
+    # eight chunks went through in one iteration.
+    assert stalled and unbounded and capped
+    assert run["iters"][0]["prefill_steps"] == 8
+    assert run["iters"][0]["prefill_tokens"] == len(LONG[0][0])
 
 
 def test_decode_rows_match_the_engines_counters(run):
