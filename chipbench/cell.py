@@ -3,10 +3,11 @@
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 import os
-
-from chipbench.weights import Sizes
+import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -21,15 +22,25 @@ class Cell:
     traffic: dict         # the workload (traffic mix) file
     manifest: dict        # BENCHMARK.json
     root: str             # directory BENCHMARK.json lies in
-    bench_dir: str        # holds configs/, workloads/ and metrics/
+    bench_dir: str        # holds configs/, workloads/, metrics/, arch/
 
     @property
     def kind(self) -> str:
         return self.traffic["kind"]
 
     @property
-    def sizes(self) -> Sizes:
-        return Sizes.from_config(self.config, self.config.get("lora"))
+    def arch(self):
+        """The module of the architecture the configuration names: all
+        the harness knows of the model's shape (README, "An
+        architecture")."""
+        if "arch" not in self.config:
+            raise SystemExit(f"configuration {self.config_name!r} names no "
+                             f"\"arch\" (a file of {self.bench_dir}/arch/)")
+        return load_arch(self.config["arch"], self.bench_dir)
+
+    @property
+    def sizes(self):
+        return self.arch.sizes(self.config)
 
     def metrics(self, section: str) -> list:
         """This cell's metrics of ``end_to_end`` or ``per_layer``: those
@@ -40,24 +51,28 @@ class Cell:
     def program_config(self) -> dict:
         """The program's ``ExperimentConfig`` as a dict: the file's
         ``program`` section, with the model's sizes taken from the file's
-        published keys so that they are stated once."""
+        published keys by the architecture, so that they are stated once."""
         prog = json.loads(json.dumps(self.config["program"]))
-        c = self.config
-        ov = prog.setdefault("model_overrides", {})
-        ov.update({
-            "vocab_size": c["vocab_size"], "d_model": c["hidden_size"],
-            "n_layers": c["num_hidden_layers"],
-            "n_heads": c["num_attention_heads"],
-            "n_kv_heads": c["num_key_value_heads"],
-            "d_ff": c["intermediate_size"],
-            "max_seq_len": c["max_position_embeddings"],
-            "rope_theta": c["rope_theta"],
-            "tie_embeddings": c["tie_word_embeddings"],
-        })
-        lora = c.get("lora")
-        if lora:
-            ov.update({"lora_rank": lora["rank"], "lora_alpha": lora["alpha"]})
+        prog.setdefault("model_overrides", {}).update(
+            self.arch.model_overrides(self.config))
         return prog
+
+
+@functools.lru_cache(maxsize=None)
+def load_arch(name: str, bench_dir: str):
+    """``<bench_dir>/arch/<name>.py`` as a module, found as a file the way
+    ``run.load_metric_reader`` finds a reader, so that a later PR (or a
+    test's tree) drops one in. One module object per file: it is a static
+    argument of the jitted reference and weights."""
+    path = os.path.join(bench_dir, "arch", name + ".py")
+    if not os.path.exists(path):
+        raise SystemExit(f"architecture {name!r} has no file {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_arch_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod    # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _read_json(path: str) -> dict:

@@ -36,8 +36,8 @@ class SpanSink:
 
 
 def _weights(cell, seed: int, dtype):
-    return weights.to_program_tree(weights.make_weights(
-        cell.sizes, weights.seed_u32(seed), dtype))
+    return cell.arch.to_program_tree(weights.make_weights(
+        cell.arch, cell.sizes, weights.seed_u32(seed), dtype))
 
 
 def build(cell, seed: int, session: dict = None):
@@ -71,8 +71,8 @@ def build(cell, seed: int, session: dict = None):
     server = GenerationServer(
         module, params, host="127.0.0.1", port=0,
         max_batch=serve.get("max_batch", 8),
-        chunk_size=serve.get("chunk_size", 32), kv=cfg.kv)
-    server.engine.event_log = SpanSink()
+        chunk_size=serve.get("chunk_size", 32), kv=cfg.kv,
+        event_sink=SpanSink())
     server.start()
     if session is not None:
         session["server"] = server
@@ -85,59 +85,6 @@ def release(session: dict) -> None:
     server = session.get("server")
     if server is not None:
         server.engine.params = server.params = None
-
-
-def reachable_shapes(engine, mix_params: dict) -> tuple:
-    """The (nb, T, W) prefill and (nb, W) decode buckets that requests of
-    this mix can reach, by the engine's own bucket functions."""
-    from serverless_learn_tpu.inference.batching import _bucket
-    from serverless_learn_tpu.inference.continuous import _wbucket
-    from serverless_learn_tpu.inference.kvcache import pages_for
-
-    ps, chunk = engine._ps, engine.prefill_chunk
-    p, o = mix_params["prompt_tokens"], mix_params["output_tokens"]
-    nbs = sorted({_bucket(n, floor=1)
-                  for n in range(1, engine.max_slots + 1)})
-    t_cap = _bucket(chunk, floor=1)
-    pre_t = sorted({min(_bucket(t, floor=8), t_cap)
-                    for t in range(1, min(chunk, p["max"]) + 1)})
-    first = pages_for(min(chunk, p["min"]), ps)
-    pre_w = sorted({min(_wbucket(n), engine._max_pages)
-                    for n in range(first, pages_for(p["max"], ps) + 1)})
-    lo = pages_for(p["min"] + min(engine.chunk_size, o["min"]), ps)
-    hi = pages_for(p["max"] + o["max"], ps)
-    dec_w = sorted({min(_wbucket(n), engine._max_pages)
-                    for n in range(lo, hi + 1)})
-    return ([(nb, T, W) for nb in nbs for T in pre_t for W in pre_w],
-            [(nb, W) for nb in nbs for W in dec_w])
-
-
-def warm(engine, mix_params: dict) -> int:
-    """Run every reachable program once, on the engine's OWN pool: all
-    table entries and slot ids are sentinels, so every write drops. The
-    engine's ``warm_shapes`` does the same on a second, throwaway pool,
-    which at this size does not fit beside the first (PERF.md)."""
-    sent, M = engine._pool.sentinel, engine.max_slots
-    prefill, decode = reachable_shapes(engine, mix_params)
-    st = engine._state
-    for nb, W in decode:
-        pad = jnp.full((nb,), M, jnp.int32)
-        st["pages"], st["vecs"], toks = engine._paged_chunk_jit(nb, W)(
-            engine.params, st["pages"], st["vecs"],
-            jnp.full((nb, W), sent, jnp.int32), pad)
-    for nb, T, W in prefill:
-        pad = jnp.full((nb,), M, jnp.int32)
-        z = lambda dt: jnp.zeros((nb,), dt)
-        st["pages"], st["vecs"], toks = engine._paged_prefill_jit(nb, T, W)(
-            engine.params, st["pages"], st["vecs"],
-            jnp.full((nb, W), sent, jnp.int32), z(jnp.int32),
-            jnp.zeros((nb, T), jnp.int32), z(jnp.int32), pad,
-            z(jnp.bool_), z(jnp.float32), z(jnp.int32),
-            jnp.full((nb,), -1, jnp.int32), z(jnp.uint32),
-            jnp.full((nb,), sent, jnp.int32),
-            jnp.full((nb,), sent, jnp.int32))
-    jax.block_until_ready(toks)
-    return len(prefill) + len(decode)
 
 
 class _Conn:
@@ -327,7 +274,7 @@ def run(cell, seed: int, seconds: float, tracer, session: dict = None
     server, sz = build(cell, seed, session)
     engine = server.engine
     try:
-        n_programs = 0 if warmed else warm(engine, t)
+        n_programs = 0 if warmed else cell.arch.warm(engine, t)
         mix = traffic.request_mix(t, seed, sz.vocab)
         marks = {}
 
@@ -447,14 +394,15 @@ def _gaps(cell, seed: int, record: dict, precision: str) -> tuple:
         if len(r["new_tokens"]) != r["item"]["max_new_tokens"])
     if not sample:
         return [], wrong_length
+    arch = cell.arch
     w = weights.make_weights(
-        sz, weights.seed_u32(seed),
+        arch, sz, weights.seed_u32(seed),
         jnp.dtype(cell.config["program"]["train"]["param_dtype"]))
     pad_to = cell.traffic["prompt_tokens"]["max"] \
         + cell.traffic["output_tokens"]["max"]
     gaps = []
     for r in sample:
-        args = (w, r["item"]["prompt"], r["new_tokens"], sz, pad_to)
+        args = (arch, w, r["item"]["prompt"], r["new_tokens"], sz, pad_to)
         gaps.append(reference.served_token_gaps(*args)
                     if precision == "float32" else
                     reference.control_token_gaps(*args, precision))

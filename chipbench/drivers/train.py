@@ -64,15 +64,16 @@ def build(cell, seed: int, session: dict = None):
             session["trainer"] = trainer
     sz = cell.sizes
     dtype = jnp.dtype(cfg.train.param_dtype)
-    params = weights.to_program_tree(
-        weights.make_weights(sz, weights.seed_u32(seed), dtype))
+    params = cell.arch.to_program_tree(weights.make_weights(
+        cell.arch, sz, weights.seed_u32(seed), dtype))
     abstract = trainer.abstract_state()
     weights.check_tree_matches(params, abstract.params)
     if jax.tree_util.tree_leaves(abstract.model_state):
         raise RuntimeError("the model has state besides its parameters; "
                            "this driver does not make it")
     tx = make_optimizer(cfg.optimizer)
-    trainable = prune(params, trainer.bundle.trainable_mask(params))
+    mask = trainer.bundle.trainable_mask    # None: every leaf is trained
+    trainable = params if mask is None else prune(params, mask(params))
     state = TrainState(step=jnp.zeros((), jnp.int32), params=params,
                        opt_state=jax.jit(tx.init)(trainable), model_state={})
     state = jax.device_put(state, trainer.state_shardings)
@@ -87,8 +88,8 @@ def run(cell, seed: int, seconds: float, tracer, session: dict = None
     t = cell.traffic
     sz = cell.sizes
     cfg, trainer, state = build(cell, seed, session)
-    n_layers = sz.n_layers
-    start = _to_host(weights.adapters_of_program_tree(state.params, n_layers))
+    trained_of = lambda tree: cell.arch.trained_of_program_tree(tree, sz)
+    start = _to_host(trained_of(state.params))
     b1 = cfg.optimizer.b1
     batch_iter = traffic.train_batches(seed, sz.vocab,
                                        t["sequences_per_step"],
@@ -114,13 +115,11 @@ def run(cell, seed: int, seconds: float, tracer, session: dict = None
         if step <= CHECK_STEPS:
             seen["losses"].append(float(stats.metrics["loss"]))
         if step == 1:
-            mu = weights.adapters_of_program_tree(
-                _adam_mu(state.opt_state), n_layers)
+            mu = trained_of(_adam_mu(state.opt_state))
             seen["grads"] = jax.tree_util.tree_map(
                 lambda m: m / (1.0 - b1), _to_host(mu))
         if step == CHECK_STEPS:
-            end = _to_host(weights.adapters_of_program_tree(
-                state.params, n_layers))
+            end = _to_host(trained_of(state.params))
             seen["change"] = jax.tree_util.tree_map(
                 lambda a, b: a - b, end, start)
             jax.block_until_ready(state.params)
@@ -169,12 +168,12 @@ def run(cell, seed: int, seconds: float, tracer, session: dict = None
 def follow(cell, seed: int, record: dict, precision: str = "float32",
            batches=None) -> dict:
     """The reference follows the steps the program was fed."""
-    sz = cell.sizes
-    w = weights.make_weights(sz, weights.seed_u32(seed),
+    arch, sz = cell.arch, cell.sizes
+    w = weights.make_weights(arch, sz, weights.seed_u32(seed),
                              jnp.dtype(cell.config["program"]["train"]
                                        ["param_dtype"]))
     losses, grads, change = reference.train_reference(
-        w, record["fed"] if batches is None else batches, sz,
+        arch, w, record["fed"] if batches is None else batches, sz,
         record["opt"], precision)
     return {"losses": losses, "grads": _to_host(grads),
             "change": _to_host(change)}

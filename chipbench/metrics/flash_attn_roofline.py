@@ -1,7 +1,8 @@
 """Roofline share of the Pallas flash-attention kernels in the traced
 steps: the least time the chip could take for the calls that ran (from
-shapes, ``chipbench/flops.py``: the larger of FLOPs over peak and bytes
-over peak bandwidth, per call) over the kernels' device time.
+shapes, the cell's architecture's ``flash_attention_cost``: the larger of
+FLOPs over peak and bytes over peak bandwidth, per call) over the
+kernels' device time.
 
 The kernels are the ``tpu_custom_call`` custom-calls of the ``XLA Ops``
 line (they carry the name of the flax module that calls them, ``attn``).
@@ -35,7 +36,7 @@ def read(run, entry):
     if fwd_n + bwd_n == 0:
         return None
     cell = run["cell"]
-    cost = flops.flash_attention_cost(
+    cost = cell.arch.flash_attention_cost(
         cell.sizes, cell.traffic["sequences_per_step"],
         cell.traffic["tokens_per_sequence"])
     peak = flops.peaks(run["device"]["kind"])

@@ -1,6 +1,7 @@
 """Model-FLOPs utilisation of the serving window: the forward FLOPs of
 every prompt and output token of the replies that arrived in the window
-(``chipbench/flops.py``, each token over its own mean context) over the
+(the cell's architecture counts them, ``arch/<name>.py``
+``forward_flops_per_token``: each token over its own mean context) over the
 window times the chip's published peak. The whole path's share."""
 
 from chipbench import flops
@@ -10,10 +11,10 @@ def read(run, entry):
     c = run["record"]["counters"]
     if not c.get("requests_arrived"):
         return None
-    sz = run["cell"].sizes
+    cell = run["cell"]
     ctx = c["mean_context_arrived"]
     tokens = c["prompt_tokens_arrived"] + c["output_tokens_arrived"]
-    work = tokens * flops.forward_flops_per_token(sz, ctx / 2)
+    work = tokens * cell.arch.forward_flops_per_token(cell.sizes, ctx / 2)
     peak = flops.peaks(run["device"]["kind"])
     return 100.0 * work / (run["record"]["window_s"] * peak["flops_per_s"]
                            * run["device"]["count"])

@@ -1,5 +1,6 @@
-"""Model-FLOPs utilisation of the training window: the FLOPs a LoRA step
-requires per token (``chipbench/flops.py``: no frozen-weight gradient, no
+"""Model-FLOPs utilisation of the training window: the FLOPs a step
+requires per token (the cell's architecture counts them, ``arch/<name>.py``
+``train_flops_per_token``: no gradient of a frozen weight, no
 recomputation) times the window's tokens per second, over the chip's
 published peak. The whole step's share; bounds every kernel's roofline."""
 
@@ -11,7 +12,7 @@ def read(run, entry):
     if rate is None:
         return None
     cell = run["cell"]
-    per_token = flops.lora_train_flops_per_token(
+    per_token = cell.arch.train_flops_per_token(
         cell.sizes, cell.traffic["tokens_per_sequence"])
     peak = flops.peaks(run["device"]["kind"])
     return 100.0 * per_token * rate / (peak["flops_per_s"]

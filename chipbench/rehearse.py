@@ -5,12 +5,15 @@ v5e chip that is described and not attached. No chip, no run, no time.
     JAX_PLATFORMS=cpu python3 chipbench/rehearse.py serve 16
     JAX_PLATFORMS=cpu python3 chipbench/rehearse.py reference 16
 
-``train <depths>``: the LoRA train step of ``mistral7b-lora-train-4k`` at
-each depth; prints what the TPU compiler says the step needs, which is how
-the configuration's depth was picked. ``serve <depth>``: one decode chunk
-and one prefill chunk of ``mistral7b-serve-backlog`` at their largest
+``train <depths>``: the train step of ``mistral7b-lora-train-4k`` at each
+depth; prints what the TPU compiler says the step needs, which is how the
+configuration's depth was picked. ``serve <depth>``: one decode chunk and
+one prefill chunk of ``mistral7b-serve-backlog`` at their largest
 buckets. The compiler counts one program: the other programs' buffers
-(the window's batches in flight, the profiler) come on top.
+(the window's batches in flight, the profiler) come on top. How a
+configuration is put at another depth, and how the engine's programs are
+lowered, is its architecture's to say (``arch/<name>.py``: ``at_depth``,
+``lower_largest``).
 
 The program asks ``jax.default_backend()`` whether to interpret its Pallas
 kernels; here that answer is steered to "tpu" so that the real kernels
@@ -59,17 +62,27 @@ def _report(name: str, compiled, seconds: float) -> dict:
     return out
 
 
+TRAIN_CELL = "mistral7b-lora-train-4k"
+SERVE_CELL = "mistral7b-serve-backlog"
+
+
+def _cell_at(name: str, depth: int):
+    from chipbench.cell import load_cell
+
+    cell = load_cell(name)
+    cell.config = cell.arch.at_depth(cell.config, depth)
+    return cell
+
+
 def rehearse_train(depth: int) -> dict:
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from chipbench.cell import load_cell
     from serverless_learn_tpu.config import ExperimentConfig
     from serverless_learn_tpu.training.train_step import build_trainer
 
-    cell = load_cell("mistral7b-lora-train-4k")
-    cell.config["num_hidden_layers"] = depth
+    cell = _cell_at(TRAIN_CELL, depth)
     raw = cell.program_config()
     t = cell.traffic
     raw["train"].update(batch_size=t["sequences_per_step"])
@@ -96,15 +109,12 @@ def rehearse_serve(depth: int) -> list:
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
-    from chipbench.cell import load_cell
-    from chipbench.drivers.serve import reachable_shapes
     from serverless_learn_tpu import cli
     from serverless_learn_tpu.config import ExperimentConfig
     from serverless_learn_tpu.inference.continuous import (
         ContinuousBatchingEngine)
 
-    cell = load_cell("mistral7b-serve-backlog")
-    cell.config["num_hidden_layers"] = depth
+    cell = _cell_at(SERVE_CELL, depth)
     cfg = cli._serving_config(ExperimentConfig.from_dict(
         cell.program_config()))
     dev, mesh = _one_chip()
@@ -124,43 +134,16 @@ def rehearse_serve(depth: int) -> list:
     ContinuousBatchingEngine.__init__(eng, module, None, max_slots=8,
                                       chunk_size=32, kv=cfg.kv)
     eng.stop()
-    from serverless_learn_tpu.inference import kvcache
-    from serverless_learn_tpu.inference.generate import init_cache
-
-    state = jax.eval_shape(lambda: {
-        "pages": kvcache.split_cache(init_cache(eng._pmod, 8))[0],
-        "vecs": {"next_tok": jnp.zeros((8,), jnp.int32),
-                 "pos": jnp.zeros((8,), jnp.int32),
-                 "done": jnp.ones((8,), jnp.bool_),
-                 "temp": jnp.zeros((8,), jnp.float32),
-                 "topk": jnp.zeros((8,), jnp.int32),
-                 "eos": jnp.zeros((8,), jnp.int32),
-                 "seed": jnp.zeros((8,), jnp.uint32),
-                 "ci": jnp.zeros((8,), jnp.int32)}})
-    state = shaped(state)
-    prefill, decode = reachable_shapes(eng, cell.traffic)
+    prefill, decode = cell.arch.reachable_shapes(eng, cell.traffic)
     print({"reachable_prefill_programs": len(prefill),
            "reachable_decode_programs": len(decode),
            "pool_blocks": eng._pool.num_blocks})
-    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
     out = []
-    nb, W = decode[-1]
-    t0 = time.perf_counter()
-    c = eng._paged_chunk_jit(nb, W).lower(
-        params, state["pages"], state["vecs"], s((nb, W), jnp.int32),
-        s((nb,), jnp.int32)).compile()
-    out.append(_report(f"decode chunk nb={nb} W={W}, {depth} layers", c,
-                       time.perf_counter() - t0))
-    nb, T, W = prefill[-1]
-    i32 = lambda: s((nb,), jnp.int32)
-    t0 = time.perf_counter()
-    c = eng._paged_prefill_jit(nb, T, W).lower(
-        params, state["pages"], state["vecs"], s((nb, W), jnp.int32), i32(),
-        s((nb, T), jnp.int32), i32(), i32(), s((nb,), jnp.bool_),
-        s((nb,), jnp.float32), i32(), i32(), s((nb,), jnp.uint32), i32(),
-        i32()).compile()
-    out.append(_report(f"prefill chunk nb={nb} T={T} W={W}, {depth} layers",
-                       c, time.perf_counter() - t0))
+    for name, lowered in cell.arch.lower_largest(eng, params, cell.traffic,
+                                                 one):
+        t0 = time.perf_counter()
+        out.append(_report(f"{name}, {depth} layers", lowered.compile(),
+                           time.perf_counter() - t0))
     return out
 
 
@@ -172,38 +155,36 @@ def rehearse_reference(depth: int) -> list:
     from jax.sharding import SingleDeviceSharding
 
     from chipbench import reference, weights
-    from chipbench.cell import load_cell
 
     dev, _ = _one_chip()
     one = SingleDeviceSharding(dev)
     shaped = lambda tree: jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree)
     out = []
-    cell = load_cell("mistral7b-lora-train-4k")
-    cell.config["num_hidden_layers"] = depth
-    sz, t = cell.sizes, cell.traffic
+    cell = _cell_at(TRAIN_CELL, depth)
+    arch, sz, t = cell.arch, cell.sizes, cell.traffic
     w = jax.eval_shape(lambda: weights.make_weights(
-        sz, jnp.uint32(0), jnp.bfloat16))
-    frozen, adapters = jax.eval_shape(reference.split_adapters, w)
+        arch, sz, jnp.uint32(0), jnp.bfloat16))
+    frozen, adapters = jax.eval_shape(arch.split_trained, w)
     tokens = jax.ShapeDtypeStruct(
         (t["sequences_per_step"], t["tokens_per_sequence"]), jnp.int32,
         sharding=one)
     for precision in ("float32", "fp8"):
         t0 = time.perf_counter()
         c = reference.loss_and_grads.lower(
-            shaped(frozen), shaped(adapters), tokens, sz,
+            arch, shaped(frozen), shaped(adapters), tokens, sz,
             precision).compile()
         out.append(_report(f"reference loss+grads ({precision}), "
                            f"{depth} layers", c, time.perf_counter() - t0))
-    cell = load_cell("mistral7b-serve-backlog")
-    cell.config["num_hidden_layers"] = depth
-    sz, t = cell.sizes, cell.traffic
+    cell = _cell_at(SERVE_CELL, depth)
+    arch, sz, t = cell.arch, cell.sizes, cell.traffic
     w = jax.eval_shape(lambda: weights.make_weights(
-        sz, jnp.uint32(0), jnp.bfloat16))
+        arch, sz, jnp.uint32(0), jnp.bfloat16))
     pad = t["prompt_tokens"]["max"] + t["output_tokens"]["max"]
     t0 = time.perf_counter()
     c = reference._row_logits.lower(
-        shaped(w), jax.ShapeDtypeStruct((pad,), jnp.int32, sharding=one),
+        arch, shaped(w),
+        jax.ShapeDtypeStruct((pad,), jnp.int32, sharding=one),
         sz, "float32").compile()
     out.append(_report(f"reference forward, {pad} tokens, {depth} layers",
                        c, time.perf_counter() - t0))

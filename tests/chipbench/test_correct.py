@@ -140,16 +140,16 @@ def test_serving_control_comes_out_not_correct(tiny_root):
     float32 configuration, moves a tiny model's best token at about one
     position in a hundred, so some hundreds of tokens meet a few."""
     cell = load_cell("tiny-backlog", tiny_root)
-    sz = cell.sizes
-    w = weights.make_weights(sz, weights.seed_u32(5), jnp.float32)
+    arch, sz = cell.arch, cell.sizes
+    w = weights.make_weights(arch, sz, weights.seed_u32(5), jnp.float32)
     rng = np.random.default_rng(0)
     finished = []
     for _ in range(24):
         prompt = [int(t) for t in rng.integers(0, sz.vocab, 40)]
         served = []
         for _ in range(24):
-            at = reference._served_logits(w, prompt, served + [0], sz, 112,
-                                          "float32")
+            at = reference._served_logits(arch, w, prompt, served + [0], sz,
+                                          112, "float32")
             served.append(int(jnp.argmax(at[-1])))
         finished.append({"item": {"prompt": prompt, "max_new_tokens": 24,
                                   "shared_prefix": None},

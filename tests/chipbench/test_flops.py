@@ -1,13 +1,16 @@
-"""``flops.py`` against hand counts for one Mistral-7B layer."""
+"""The dense decoder's counts (``arch/dense_gqa.py``) against hand counts
+for one Mistral-7B layer, and ``flops.py``'s peaks and least time."""
 
 import pytest
 
+import tiny
 from chipbench import flops
-from chipbench.weights import Sizes
+from chipbench.cell import load_arch
 
-SZ = Sizes(vocab=32768, d_model=4096, n_layers=1, n_heads=32, n_kv_heads=8,
-           head_dim=128, d_ff=14336, rope_theta=1e6, rms_eps=1e-6,
-           lora_rank=16)
+arch = load_arch("dense_gqa", tiny.BENCH)
+SZ = arch.Sizes(vocab=32768, d_model=4096, n_layers=1, n_heads=32,
+                n_kv_heads=8, head_dim=128, d_ff=14336, rope_theta=1e6,
+                rms_eps=1e-6, lora_rank=16)
 
 
 def test_one_layers_weights_by_hand():
@@ -15,24 +18,24 @@ def test_one_layers_weights_by_hand():
     kv = 2 * 4096 * 1024
     o = 4096 * 4096
     mlp = 3 * 4096 * 14336
-    assert flops.layer_matmul_params(SZ) == q + kv + o + mlp == 218_103_808
+    assert arch.layer_matmul_params(SZ) == q + kv + o + mlp == 218_103_808
     # LoRA r16 on Q and V: A [4096,16] + B [16,4096]; A [4096,16] + B [16,1024]
-    assert flops.layer_adapter_params(SZ) == (65536 + 65536) + (65536 + 16384)
-    assert flops.head_params(SZ) == 4096 * 32768
-    assert flops.weight_bytes(SZ) == 2 * (218_103_808 + 134_217_728)
+    assert arch.layer_adapter_params(SZ) == (65536 + 65536) + (65536 + 16384)
+    assert arch.head_params(SZ) == 4096 * 32768
+    assert arch.weight_bytes(SZ) == 2 * (218_103_808 + 134_217_728)
 
 
 def test_attention_and_forward_flops_by_hand():
     # One query over 2048 keys: QK^T 2*32*128*2048, PV the same.
-    assert flops.attention_flops(SZ, 1, 2048) == 2 * (2 * 32 * 128 * 2048)
-    fwd = flops.forward_flops_per_token(SZ, 2048)
+    assert arch.attention_flops(SZ, 1, 2048) == 2 * (2 * 32 * 128 * 2048)
+    fwd = arch.forward_flops_per_token(SZ, 2048)
     by_hand = 2 * (218_103_808 + 212_992 + 134_217_728) + 2 * (
         2 * 32 * 128 * 2048)
     assert fwd == by_hand
 
 
 def test_lora_step_requires_no_frozen_weight_gradient():
-    per_token = flops.lora_train_flops_per_token(SZ, 4096)
+    per_token = arch.train_flops_per_token(SZ, 4096)
     frozen = 2 * (218_103_808 + 134_217_728)
     adapters = 2 * 212_992
     attn = 4 * 32 * 128 * 2048
@@ -42,7 +45,7 @@ def test_lora_step_requires_no_frozen_weight_gradient():
 
 
 def test_flash_cost_and_roofline_bound():
-    c = flops.flash_attention_cost(SZ, 2, 4096)
+    c = arch.flash_attention_cost(SZ, 2, 4096)
     product = 2 * 32 * 128 * 2 * 4096 * 2048
     assert c["fwd_flops"] == 2 * product and c["bwd_flops"] == 5 * product
     q_el, kv_el = 2 * 4096 * 32 * 128, 2 * 4096 * 8 * 128
@@ -53,8 +56,8 @@ def test_flash_cost_and_roofline_bound():
 
 
 def test_decode_is_memory_bound_and_unknown_chips_are_refused():
-    c = flops.decode_step_cost(SZ, rows=8, mean_context=512)
-    assert c["bytes"] == flops.weight_bytes(SZ) + 8 * 512 * (2 * 8 * 128 * 2)
+    c = arch.decode_step_cost(SZ, rows=8, mean_context=512)
+    assert c["bytes"] == arch.weight_bytes(SZ) + 8 * 512 * (2 * 8 * 128 * 2)
     t, bound = flops.least_seconds(c["flops"], c["bytes"],
                                    flops.peaks("TPU v5 lite"))
     assert bound == "memory"

@@ -1,6 +1,7 @@
 """A whole tiny benchmark tree for the harness's CPU tests: a manifest,
 two configurations of a 2-layer model and three traffic mixes, written
-into a temporary root; the readers are the real ones. Also where the
+into a temporary root; the readers and the architectures are the real
+ones. Also where the
 recorded TPU trace lies."""
 
 from __future__ import annotations
@@ -17,6 +18,7 @@ FIXTURE_TRACE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "fixtures", "tpu_v5e_small.xplane.pb")
 
 MODEL = {
+    "arch": "dense_gqa",
     "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
     "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
     "vocab_size": 256, "rope_theta": 10000.0,
@@ -51,8 +53,9 @@ def write_tree(root: str, param_dtype: str = "float32") -> str:
     bench = os.path.join(root, "bench")
     for sub in ("configs", "workloads"):
         os.makedirs(os.path.join(bench, sub), exist_ok=True)
-    shutil.copytree(os.path.join(BENCH, "metrics"),
-                    os.path.join(bench, "metrics"), dirs_exist_ok=True)
+    for sub in ("metrics", "arch"):
+        shutil.copytree(os.path.join(BENCH, sub), os.path.join(bench, sub),
+                        dirs_exist_ok=True)
     train_prog = {"model": "llama_tiny", "mesh": {"dp": 1},
                   "train": {"dtype": param_dtype, "param_dtype": param_dtype,
                             "remat": True},
@@ -122,3 +125,15 @@ def write_tree(root: str, param_dtype: str = "float32") -> str:
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(manifest, f)
     return root
+
+
+def seeded_trees(cell, seed: int, dtype: str = "float32") -> tuple:
+    """(canonical weights, the program's tree of them) of a cell from a
+    seed, as both drivers make them."""
+    import jax.numpy as jnp
+
+    from chipbench import weights
+
+    w = weights.make_weights(cell.arch, cell.sizes, weights.seed_u32(seed),
+                             jnp.dtype(dtype))
+    return w, cell.arch.to_program_tree(w)
