@@ -336,9 +336,11 @@ def kv_bytes_per_token(sz: Sizes, bytes_per_el: int = 2) -> int:
     return 2 * sz.n_layers * sz.n_kv_heads * sz.head_dim * bytes_per_el
 
 
-def decode_step_cost(sz: Sizes, rows: float, mean_context: float) -> dict:
+def decode_step_cost(sz: Sizes, rows: float, mean_context: float,
+                     record: dict = None) -> dict:
     """One decode step over ``rows`` live sequences: every weight once,
-    each row's keys and values once."""
+    each row's keys and values once. ``record`` (the run's record) is not
+    needed: a dense step reads the same whatever the rows hold."""
     flops = rows * forward_flops_per_token(sz, mean_context)
     nbytes = weight_bytes(sz) + rows * mean_context * kv_bytes_per_token(sz)
     return {"flops": flops, "bytes": nbytes}

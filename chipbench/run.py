@@ -191,6 +191,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     e2e = dict(record["end_to_end"], setup_s=setup_s)
     result = {"correct": correct, "attempted": record["attempted"],
               "failed": record["failed"]}
+    # What a reader has to say beside its number (the counts it divided,
+    # why it left the number out) goes into the line's ``notes``.
+    reader_notes: dict = {}
     if not trace:
         result["metrics"] = {
             m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
@@ -206,7 +209,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = traced_s
         run = {"cell": cell, "record": record, "trace": reduced,
-               "traced_s": traced_s, "end_to_end": e2e, "device": device}
+               "traced_s": traced_s, "end_to_end": e2e, "device": device,
+               "notes": reader_notes}
         metrics = {}
         for m in cell.metrics("per_layer"):
             value = load_metric_reader(m["name"], cell.bench_dir)(run, m)
@@ -219,7 +223,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             "idle_gaps": [[n, s] for n, s in reduced["gaps"]]}
     result["device"] = device
     result["window_s"] = record["window_s"]
-    result["notes"] = {**verdict["notes"], **record["counters"]}
+    result["notes"] = {**verdict["notes"], **record["counters"],
+                       **reader_notes}
     result["checked"] = numbers
     for name, n in numbers.items():
         print(f"checked {name}: value {n['value']!r} limit {n['limit']!r}",

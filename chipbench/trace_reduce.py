@@ -89,13 +89,26 @@ def _host_spans(profile) -> list:
     return out
 
 
+def _is_frame(name: str) -> bool:
+    """The Python tracer names an interpreter frame ``$file.py:line
+    function``; a ``TraceAnnotation`` has the name the program gave it."""
+    return name.startswith("$")
+
+
 def _host_label(host: list, gap: tuple) -> str:
-    """Name the gap by the SHORTEST host span that covers its midpoint
-    (the innermost annotation); else the one that overlaps it most."""
+    """Name the gap by the annotations that cover its midpoint, outermost
+    first (``sched.iter > sched.harvest > np.asarray(jax.Array)``): what
+    the program says it was doing, down to the runtime's own scope under
+    it, not the innermost interpreter frame (``$array.py:631 _value``). A
+    gap that no annotation covers takes the shortest frame that does; one
+    that nothing covers, the span that overlaps it most."""
     mid = 0.5 * (gap[0] + gap[1])
-    covering = [(e - s, n) for s, e, n in host if s <= mid <= e]
+    covering = sorted((e - s, n) for s, e, n in host if s <= mid <= e)
+    annotated = [n for _, n in reversed(covering) if not _is_frame(n)]
+    if annotated:
+        return " > ".join(dict.fromkeys(annotated))
     if covering:
-        return min(covering)[1]
+        return covering[0][1]
     best, name = 0.0, "no host span"
     for s, e, n in host:
         ov = min(e, gap[1]) - max(s, gap[0])
@@ -113,9 +126,11 @@ def reduce_trace(path: str, top: int = 10) -> dict:
     their number, keyed by the HLO instruction's name (``op_text`` keeps
     one whole HLO line per name, for readers that look for a kernel's
     call target). ``modules``: {program name: [durations in seconds]} of
-    device 0. ``gaps``: the longest idle gaps of device 0 as
-    (label, seconds), the label naming the programs either side and the
-    host span that covers the gap.
+    device 0; ``module_events``: the same events as (start, end, program
+    name), and ``recorded``: the first and the last instant device 0's
+    lines hold, for ``whole_events``. ``gaps``: the longest idle gaps of
+    device 0 as (label, seconds), the label naming the programs either
+    side and the host span that covers the gap.
     """
     profile = load(path)
     devices = []
@@ -150,18 +165,22 @@ def reduce_trace(path: str, top: int = 10) -> dict:
             op_counts[short] += 1.0 / n
             op_text.setdefault(short, name)
     _, op_events, mod_events = devices[0]
+    module_events = [(s, e, module_name(name)) for s, e, name in mod_events]
     modules = defaultdict(list)
-    for s, e, name in mod_events:
-        modules[module_name(name)].append(e - s)
+    for s, e, name in module_events:
+        modules[name].append(e - s)
+    both = op_events + mod_events          # each sorted by start
+    recorded = (min(s for s, _, _ in both),
+                max(e for _, e, _ in both)) if both else None
     _, gaps = union_seconds(op_events)
     gaps = sorted((g for g in gaps if g[1] - g[0] >= MIN_GAP_S),
                   key=lambda g: g[0] - g[1])[:top]
     host = _host_spans(profile)
     labelled = []
     for g in gaps:
-        before = [module_name(nm) for s, e, nm in mod_events if e <= g[0] + 1e-9]
-        after = [module_name(nm) for s, e, nm in mod_events if s >= g[1] - 1e-9]
-        inside = [module_name(nm) for s, e, nm in mod_events
+        before = [nm for s, e, nm in module_events if e <= g[0] + 1e-9]
+        after = [nm for s, e, nm in module_events if s >= g[1] - 1e-9]
+        inside = [nm for s, e, nm in module_events
                   if s < g[0] and e > g[1]]
         where = (f"inside {inside[0]}" if inside else
                  f"{before[-1] if before else 'start'} -> "
@@ -175,19 +194,55 @@ def reduce_trace(path: str, top: int = 10) -> dict:
         "ops": dict(ops),
         "op_counts": dict(op_counts),
         "op_text": op_text,
-        "modules": {k: v for k, v in modules.items()},
+        "modules": dict(modules),
+        "module_events": module_events,
+        "recorded": recorded,
         "gaps": labelled,
     }
+
+
+def whole_events(reduced: dict, program: str) -> list:
+    """Durations of device 0's launches of the programs whose name holds
+    ``program`` that the trace holds WHOLE. A launch that is running when
+    the trace stops is recorded up to that instant only, and one that is
+    running when it starts from its first instant on (on the v5e 0.376 s
+    decode chunks were found as 0.094 s ending with the trace and as
+    0.321 s beginning with it), so an event that touches the first or
+    the last recorded instant is cut. Both are left out: a sum over
+    events that counts a cut one as a whole launch reads too high."""
+    if not reduced.get("recorded"):
+        return []
+    first, last = reduced["recorded"]
+    return [e - s for s, e, name in reduced["module_events"]
+            if program in name and s > first + MIN_GAP_S
+            and e < last - MIN_GAP_S]
 
 
 CONTAINER_OPS = ("while", "conditional", "call")
 
 
+KERNEL_CALL = re.compile(r"custom-call\(.*tpu_custom_call", re.S)
+
+
+def kernel_name(op: str) -> str:
+    """A Pallas kernel's own name (``pallas_call(name=)``) from its HLO
+    instruction's: ``flash_fwd.37`` -> ``flash_fwd``."""
+    return re.sub(r"(\.\d+)+$", "", op)
+
+
 def top_ops(reduced: dict, top: int = 10) -> list:
     """The operations that took most device time. A loop's or a branch's
     own event spans its body's events, which are listed themselves: it is
-    left out here (the busy union counts either once)."""
-    leaf = {n: s for n, s in reduced["ops"].items()
-            if not n.startswith(CONTAINER_OPS)}
+    left out here (the busy union counts either once). A Pallas kernel
+    is called from many places under one name (``flash_fwd.37``,
+    ``flash_fwd.42``: the forward kernel of two layers): its calls are
+    summed under the name, so that the list tells the kernels apart and
+    not the layers."""
+    leaf = defaultdict(float)
+    for n, s in reduced["ops"].items():
+        if n.startswith(CONTAINER_OPS):
+            continue
+        kernel = KERNEL_CALL.search(reduced["op_text"].get(n, ""))
+        leaf[kernel_name(n) if kernel else n] += s
     return [[name, secs] for name, secs in
             sorted(leaf.items(), key=lambda kv: -kv[1])[:top]]

@@ -233,10 +233,13 @@ def experts_touched(sz: Sizes, rows: float) -> float:
     return sz.n_experts * (1.0 - (1.0 - sz.top_k / sz.n_experts) ** rows)
 
 
-def decode_step_cost(sz: Sizes, rows: float, mean_context: float) -> dict:
+def decode_step_cost(sz: Sizes, rows: float, mean_context: float,
+                     record: dict = None) -> dict:
     """One decode step: the attention's weights, the router and the head
     once, the experts that the step's rows touch once (not every expert),
-    each row's keys and values once."""
+    each row's keys and values once. The program counts no routing, so
+    the experts touched are the expectation for ``rows`` rows; an
+    architecture whose program does count them reads ``record``."""
     per_layer = (_attention_params(sz) + sz.d_model * sz.n_experts
                  + experts_touched(sz, rows) * _expert_params(sz))
     weights = 2 * (sz.n_layers * per_layer + sz.d_model * sz.vocab)
@@ -264,3 +267,7 @@ def reachable_shapes(engine, mix_params: dict) -> tuple:
 
 def warm(engine, mix_params: dict) -> int:
     return _dense().warm(engine, mix_params)
+
+
+def lower_largest(engine, params, mix_params: dict, sharding) -> list:
+    return _dense().lower_largest(engine, params, mix_params, sharding)

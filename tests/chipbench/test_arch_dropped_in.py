@@ -9,7 +9,6 @@ counts, and an expert left out of the program's tree is not correct."""
 
 import json
 import os
-import shutil
 
 import pytest
 
@@ -17,77 +16,29 @@ import tiny
 from chipbench import cell as cell_mod
 from chipbench import flops, run, trace_reduce
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-MOE = {
-    "arch": "moe_topk",
-    "hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-    "vocab_size": 256, "rope_theta": 10000.0,
-    "max_position_embeddings": 256, "rms_norm_eps": 1e-6,
-    "num_local_experts": 4, "num_experts_per_tok": 2,
-}
-F32 = {"dtype": "float32", "param_dtype": "float32"}
-# The interface of ``chipbench/README.md``, "An architecture".
+MOE = tiny.MOE
+# The interface of ``chipbench/README.md``, "An architecture": what every
+# architecture defines, and what one with a ``train`` cell defines too.
 EVERY_ARCH = ("sizes", "model_overrides", "leaf_shapes", "to_program_tree",
               "trunk", "head", "forward_flops_per_token", "decode_step_cost",
               "reachable_shapes", "warm")
-TRAINED_ARCH = ("trained_of_program_tree", "split_trained", "merge_trained",
-                "train_flops_per_token")
+TRAINED_ARCH = tiny.TRAINED_NAMES
 
 
 @pytest.fixture(scope="module")
 def moe_root(tmp_path_factory):
     """The tiny tree, grown by files and manifest entries alone."""
-    root = tiny.write_tree(str(tmp_path_factory.mktemp("moe_bench")))
-    bench = os.path.join(root, "bench")
-    shutil.copy(os.path.join(HERE, "fixtures", "moe_topk.py"),
-                os.path.join(bench, "arch", "moe_topk.py"))
-    files = {
-        "configs/tiny-moe-serve.json": dict(
-            MOE, program={"model": "moe_tiny", "mesh": {"dp": 1},
-                          "train": F32, "kv": {"num_blocks": 96}},
-            serve={"max_batch": 4},
-            limits={"serve": {"served_logit_gap": 1e-3}}),
-        "configs/tiny-moe-train.json": dict(
-            MOE, program={"model": "moe_tiny", "mesh": {"dp": 1},
-                          "train": F32, "data": {"prefetch": 2},
-                          "optimizer": {"name": "adamw",
-                                        "learning_rate": 2e-4}},
-            limits={"train": {"loss1_gap": 1e-4, "loss2_gap": 1e-4,
-                              "loss3_gap": 1e-4, "grad_norm_gap": 1e-2,
-                              "change_norm_gap": 1e-2}}),
-        "workloads/moe-backlog.json": dict(
-            tiny.LENGTHS, kind="serve_closed", clients=6, warm_in_replies=6,
-            pool=256, pool_seed=11, length_cycle=6, check_requests=6,
-            trace={"seconds": 0.3}),
-        "workloads/moe-train.json": {
-            "kind": "train", "sequences_per_step": 2,
-            "tokens_per_sequence": 32, "trace": {"units": 1}},
-    }
-    for rel, body in files.items():
-        with open(os.path.join(bench, rel), "w") as f:
-            json.dump(body, f)
-    path = os.path.join(root, "BENCHMARK.json")
-    with open(path) as f:
-        manifest = json.load(f)
-    cells = {"tiny-moe-backlog": ("tiny-moe-serve", "moe-backlog",
-                                  ("serve_tokens_per_s", "serve_mfu.tput",
-                                   "decode_roofline.tput")),
-             "tiny-moe-train": ("tiny-moe-train", "moe-train",
-                                ("train_tokens_per_s", "train_mfu.train"))}
-    for name, (config, traffic, listed_under) in cells.items():
-        manifest["configs"].append(
-            {"name": config, "source": "tests", "reduced": [],
-             "file": f"bench/configs/{config}.json", "why": "dropped in"})
-        manifest["workloads"].append(
-            {"name": name, "config": config, "traffic": traffic, "chips": 1,
-             "why": "an architecture added as files"})
-        for m in manifest["end_to_end"] + manifest["per_layer"]:
-            if m["name"] in listed_under:
-                m["workloads"].append(name)
-    with open(path, "w") as f:
-        json.dump(manifest, f)
-    return root
+    return tiny.grow_moe(tiny.write_tree(
+        str(tmp_path_factory.mktemp("moe_bench"))))
+
+
+@pytest.fixture(scope="module")
+def serving_only_root(tmp_path_factory):
+    """The same with no training cell, and the architecture's file
+    without its four training names."""
+    return tiny.grow_moe(tiny.write_tree(
+        str(tmp_path_factory.mktemp("moe_serving_only"))),
+        serving_only=True)
 
 
 @pytest.fixture(autouse=True)
@@ -124,16 +75,20 @@ def test_the_shared_readers_read_through_the_dropped_in_counts(
     under the decode chunk's name. The values mean nothing and go
     nowhere; what they were computed FROM is what is asserted.)"""
     recorded = trace_reduce.reduce_trace(tiny.FIXTURE_TRACE)
-    chunk_s = recorded["modules"]["jit_fixture_step"]
+    as_chunks = dict(recorded, module_events=[
+        (s, e, "jit_chunk") for s, e, _ in recorded["module_events"]])
+    chunk_s = trace_reduce.whole_events(as_chunks, "chunk")
+    assert len(chunk_s) == 2
     monkeypatch.setattr(trace_reduce, "find_xplane", lambda d: d)
-    monkeypatch.setattr(trace_reduce, "reduce_trace",
-                        lambda path: dict(recorded,
-                                          modules={"jit_chunk": chunk_s}))
+    monkeypatch.setattr(trace_reduce, "reduce_trace", lambda path: as_chunks)
     monkeypatch.setattr(run, "device_info", lambda chips, require_chip: {
         "platform": "cpu", "kind": "TPU v5 lite", "count": 1})
     line = _run(moe_root, "tiny-moe-backlog", trace=True)
     assert line["correct"] is True, line["checked"]
-    assert set(line["metrics"]) == {"serve_mfu.tput", "decode_roofline.tput"}
+    assert set(line["metrics"]) == {
+        "serve_mfu.tput", "decode_roofline.tput", "useful_decode_share.tput",
+        "device_idle_share.tput"}
+    assert line["notes"]["decode_chunks_whole"] == 2
     moe, dense = _archs(moe_root)
     sz = moe.sizes(MOE)
     c, peak = line["notes"], flops.peaks("TPU v5 lite")
@@ -146,7 +101,7 @@ def test_the_shared_readers_read_through_the_dropped_in_counts(
 
     def roofline(arch, sizes):
         cost = arch.decode_step_cost(sizes, c["decoded_rows"]
-                                     / c["chunks_run"], ctx)
+                                     / c["chunks_run"], ctx, None)
         t, _ = flops.least_seconds(cost["flops"], cost["bytes"], peak)
         return 100.0 * len(chunk_s) * c["chunk_size"] * t / sum(chunk_s)
 
@@ -227,15 +182,47 @@ def test_a_configuration_names_its_architecture(moe_root):
         cell.sizes
 
 
+def _architectures_of_train_cells(root) -> set:
+    """The ``arch`` of every configuration that a ``train`` cell names."""
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        names = [w["name"] for w in json.load(f)["workloads"]]
+    cells = [cell_mod.load_cell(name, root) for name in names]
+    return {c.config["arch"] for c in cells if c.kind == "train"}
+
+
+def _missing(arch, has_train_cell: bool) -> list:
+    """``EVERY_ARCH`` is asked of every file of ``arch/``, ``TRAINED_ARCH``
+    only of one that a configuration of a ``train`` cell names."""
+    asked = EVERY_ARCH + (TRAINED_ARCH if has_train_cell else ())
+    return [n for n in asked if not hasattr(arch, n)]
+
+
 @pytest.mark.parametrize("name", sorted(
     f[:-3] for f in os.listdir(os.path.join(tiny.BENCH, "arch"))
     if f.endswith(".py")))
 def test_every_architecture_of_the_benchmark_defines_the_interface(name):
-    arch = cell_mod.load_arch(name, tiny.BENCH)
-    missing = [n for n in EVERY_ARCH + TRAINED_ARCH if not hasattr(arch, n)]
-    assert not missing, missing
+    trained = _architectures_of_train_cells(tiny.ROOT)
+    assert "dense_gqa" in trained      # the LoRA cell: its names are asked
+    assert not _missing(cell_mod.load_arch(name, tiny.BENCH),
+                        name in trained)
 
 
 def test_the_dropped_in_architecture_defines_the_interface(moe_root):
+    assert "moe_topk" in _architectures_of_train_cells(moe_root)
     moe, _ = _archs(moe_root)
-    assert not [n for n in EVERY_ARCH + TRAINED_ARCH if not hasattr(moe, n)]
+    assert not _missing(moe, True)
+
+
+def test_a_serving_only_architecture_needs_no_training_names(
+        serving_only_root):
+    """The fixture with its four training names cut out and no ``train``
+    cell: the interface asks nothing more of it, and it serves its cell.
+    Were a ``train`` cell to name it, exactly those four would be
+    missing."""
+    moe, _ = _archs(serving_only_root)
+    assert "moe_topk" not in _architectures_of_train_cells(serving_only_root)
+    assert not _missing(moe, False)
+    assert sorted(_missing(moe, True)) == sorted(TRAINED_ARCH)
+    line = _run(serving_only_root, "tiny-moe-backlog")
+    assert line["correct"] is True, line["checked"]
+    assert line["failed"] == 0 and line["notes"]["tokens_compared"] >= 40

@@ -1,5 +1,13 @@
 """``BENCHMARK.json`` against the contract's own limits, and against the
-files it names: a manifest outside them is refused before a single run."""
+files it names: a manifest outside them is refused before a single run.
+
+Every check is a function of a manifest's root, run on the repo's own
+tree and on a tiny tree grown by a second architecture's configuration
+(``tiny.grow_moe``: other widths, its own ``published``), so that the
+next configuration of any model is held to its OWN source: no width may
+be listed in ``reduced``, at top level or inside a nested group, whatever
+the model. What this file knows of one model is a pin looked up by
+``source``."""
 
 import json
 import os
@@ -7,17 +15,27 @@ import re
 
 import pytest
 
+import tiny
+
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 WIDTH = re.compile(r"hidden_size|intermediate_size|latent|state_size|_proj|"
                    r"_dim$|_rank$|head_dim|expansion|experts_per_tok")
+# Published widths of a source, held exactly in every configuration that
+# names it (from memory of the source's config.json; the configuration
+# files say so under ``assumed``).
+PINNED = {
+    "https://huggingface.co/mistralai/Mistral-7B-v0.3/blob/main/config.json":
+        {"hidden_size": 4096, "intermediate_size": 14336,
+         "num_attention_heads": 32, "num_key_value_heads": 8,
+         "head_dim": 128, "vocab_size": 32768},
+}
 
 
-@pytest.fixture(scope="module")
-def manifest():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+def _manifest(root):
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
         return json.load(f)
 
 
@@ -26,10 +44,18 @@ def _line(text):
             and "\n" not in text and "\t" not in text)
 
 
-def test_top_level_keys_and_sizes(manifest):
+def _bench_dir(root, manifest):
+    """Where ``cell.load_cell`` looks for workloads/ and metrics/: two
+    levels above the first configuration's file."""
+    return os.path.dirname(os.path.dirname(os.path.join(
+        root, manifest["configs"][0]["file"])))
+
+
+def check_top_level_keys_and_sizes(root):
+    manifest = _manifest(root)
     assert set(manifest) == {"command", "paths", "run_seconds", "configs",
                              "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
+    assert os.path.getsize(os.path.join(root, "BENCHMARK.json")) <= 64 * 1024
     assert isinstance(manifest["run_seconds"], int)
     assert 1 <= manifest["run_seconds"] <= 51
     assert 1 <= len(manifest["command"]) <= 32
@@ -38,12 +64,25 @@ def test_top_level_keys_and_sizes(manifest):
     for p in manifest["paths"]:
         assert re.match(r"^[A-Za-z0-9_.\-/]{1,200}$", p)
         assert not p.startswith("/") and ".." not in p.split("/")
-        assert os.path.isdir(os.path.join(ROOT, p))
+        assert os.path.isdir(os.path.join(root, p))
     assert any(manifest["command"][1].startswith(p + "/")
                for p in manifest["paths"])
 
 
-def test_configs(manifest):
+def _no_width_differs(body, published, where):
+    """Inside a nested group that ``reduced`` lists, every key that names
+    a width equals the published one, however deep."""
+    for key, value in body.items():
+        if WIDTH.search(key):
+            assert key in published and value == published[key], \
+                f"{where}.{key}"
+        elif isinstance(value, dict):
+            _no_width_differs(value, published.get(key, {}),
+                              f"{where}.{key}")
+
+
+def check_configs(root):
+    manifest = _manifest(root)
     assert 1 <= len(manifest["configs"]) <= 24
     names = [c["name"] for c in manifest["configs"]]
     files = [c["file"] for c in manifest["configs"]]
@@ -55,23 +94,26 @@ def test_configs(manifest):
         assert _line(c["why"]) and _line(c["source"])
         assert any(c["file"].startswith(p + "/") for p in manifest["paths"])
         assert len(c["reduced"]) <= 16
-        with open(os.path.join(ROOT, c["file"])) as f:
+        with open(os.path.join(root, c["file"])) as f:
             body = json.load(f)
         for key in c["reduced"]:
+            # Never a width, whatever the model.
             assert NAME.match(key) and not WIDTH.search(key), key
             assert key in body and key in body["reduced"], key
-            assert body[key] != body["published"][key]
-        # What the file says it reduced is what the manifest lists.
+            assert body[key] != body["published"][key], key
+            if isinstance(body[key], dict):
+                _no_width_differs(body[key], body["published"][key], key)
+        # What the file says it reduced, and what it says the source
+        # published otherwise, is what the manifest lists.
         assert sorted(body["reduced"]) == sorted(c["reduced"])
+        assert sorted(body["published"]) == sorted(c["reduced"])
         assert body["source"] == c["source"]
-        # No width differs from the published Mistral-7B-v0.3 config.
-        assert (body["hidden_size"], body["intermediate_size"],
-                body["num_attention_heads"], body["num_key_value_heads"],
-                body["head_dim"], body["vocab_size"]) == (
-                    4096, 14336, 32, 8, 128, 32768)
+        for key, value in PINNED.get(c["source"], {}).items():
+            assert body[key] == value, (c["name"], key)
 
 
-def test_workloads(manifest):
+def check_workloads(root):
+    manifest = _manifest(root)
     cells = manifest["workloads"]
     assert 1 <= len(cells) <= 24
     names = [w["name"] for w in cells]
@@ -85,10 +127,11 @@ def test_workloads(manifest):
         assert w["config"] in configs and w["chips"] in (1, 4)
         assert _line(w["why"])
         assert os.path.exists(os.path.join(
-            ROOT, "chipbench", "workloads", w["traffic"] + ".json"))
+            _bench_dir(root, manifest), "workloads", w["traffic"] + ".json"))
 
 
-def test_metrics(manifest):
+def check_metrics(root):
+    manifest = _manifest(root)
     e2e, per = manifest["end_to_end"], manifest["per_layer"]
     assert 1 <= len(e2e) <= 16 and 1 <= len(per) <= 128
     names = [m["name"] for m in e2e + per]
@@ -121,8 +164,8 @@ def test_metrics(manifest):
             assert m["moves"] in reports[c], (m["name"], c)
             layers_of[c] += 1
         stem = m["name"].split(".", 1)[0]
-        assert os.path.exists(os.path.join(ROOT, "chipbench", "metrics",
-                                           stem + ".py"))
+        assert os.path.exists(os.path.join(
+            _bench_dir(root, manifest), "metrics", stem + ".py"))
     for c in cells:
         assert "setup_s" in reports[c] and len(reports[c]) >= 2
         assert layers_of[c] >= 1
@@ -133,11 +176,88 @@ def test_metrics(manifest):
         assert any(n.startswith("device_idle_share") for n in mine)
 
 
-def test_every_file_under_paths_is_named_from_a_names_characters(manifest):
+def check_every_file_under_paths_is_named_from_a_names_characters(root):
     ok = re.compile(r"^[A-Za-z0-9_.\-/]+$")
-    for p in manifest["paths"]:
-        for d, dirs, files in os.walk(os.path.join(ROOT, p)):
+    for p in _manifest(root)["paths"]:
+        for d, dirs, files in os.walk(os.path.join(root, p)):
             dirs[:] = [x for x in dirs if x != "__pycache__"]
             for f in files:
-                rel = os.path.relpath(os.path.join(d, f), ROOT)
+                rel = os.path.relpath(os.path.join(d, f), root)
                 assert ok.match(rel), rel
+
+
+CHECKS = [check_top_level_keys_and_sizes, check_configs, check_workloads,
+          check_metrics,
+          check_every_file_under_paths_is_named_from_a_names_characters]
+_ids = lambda check: check.__name__[len("check_"):]
+
+
+@pytest.mark.parametrize("check", CHECKS, ids=_ids)
+def test_the_repos_own_manifest(check):
+    check(ROOT)
+
+
+@pytest.fixture(scope="module")
+def grown_root(tmp_path_factory):
+    return tiny.grow_moe(tiny.write_tree(
+        str(tmp_path_factory.mktemp("grown_manifest"))))
+
+
+@pytest.mark.parametrize("check", CHECKS, ids=_ids)
+def test_a_tree_grown_by_another_architectures_configuration(check,
+                                                             grown_root):
+    """Other widths (64 / 128 / 4 heads of 16 / 256), their own
+    ``published``, a nested group whose list is cut: every check passes
+    with no edit to this file."""
+    check(grown_root)
+
+
+# ---- planted faults: each has to fail ``check_configs`` ------------------
+
+def _a_width_listed_as_reduced(body, entry):
+    body.update(hidden_size=32)
+    body["published"]["hidden_size"] = 64
+    body["reduced"]["hidden_size"] = "half the published width"
+    entry["reduced"] = sorted(body["reduced"])
+
+
+def _a_width_changed_inside_a_listed_group(body, entry):
+    body["router_config"]["router_dim"] = 32
+
+
+def _a_width_left_out_of_a_listed_group(body, entry):
+    del body["published"]["router_config"]["router_dim"]
+
+
+def _the_pinned_source_with_another_width(body, entry):
+    mistral = next(iter(PINNED))
+    body.update(source=mistral, hidden_size=2048)
+    entry["source"] = mistral
+
+
+def _a_change_the_manifest_does_not_list(body, entry):
+    body.update(vocab_size=128)
+    body["published"]["vocab_size"] = 256
+
+
+def _a_listed_key_that_did_not_change(body, entry):
+    body["num_hidden_layers"] = body["published"]["num_hidden_layers"]
+
+
+FAULTS = [_a_width_listed_as_reduced, _a_width_changed_inside_a_listed_group,
+          _a_width_left_out_of_a_listed_group,
+          _the_pinned_source_with_another_width,
+          _a_change_the_manifest_does_not_list,
+          _a_listed_key_that_did_not_change]
+
+
+@pytest.mark.parametrize("fault", FAULTS,
+                         ids=lambda fault: fault.__name__.lstrip("_"))
+def test_a_configuration_at_fault_is_refused(fault, tmp_path):
+    root = tiny.grow_moe(tiny.write_tree(str(tmp_path)), edit_config=fault)
+    with pytest.raises(AssertionError):
+        check_configs(root)
+    # Nothing else about the tree is at fault.
+    for check in CHECKS:
+        if check is not check_configs:
+            check(root)

@@ -46,3 +46,28 @@ def test_flash_kernels_compile_at_the_cells_widths(one_chip):
             q, kv, kv).compile()
     # `auto` took the flash path: forward, dq and dk/dv kernels.
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_the_rehearsal_takes_a_dropped_in_serving_cell(one_chip, tmp_path,
+                                                       capsys):
+    """``rehearse.py serve <depth> --workload <cell>`` on a cell that a
+    later PR added as files (``tiny.grow_moe``: another architecture,
+    serving only): its largest decode and prefill programs, and its
+    reference's forward pass, compile for the described chip; a cell of
+    the wrong kind is refused by name."""
+    import tiny
+    from chipbench import rehearse
+
+    root = tiny.grow_moe(tiny.write_tree(str(tmp_path)), serving_only=True)
+    programs = rehearse.rehearse_serve(2, "tiny-moe-backlog", root)
+    assert [p["program"].split(":")[0] for p in programs] == \
+        ["tiny-moe-backlog"] * 2
+    assert any("decode chunk" in p["program"] for p in programs)
+    assert any("prefill chunk" in p["program"] for p in programs)
+    assert all(p["needs_GiB"] < 1.0 for p in programs)
+    # The engine was built with the cell's own slots, not the default 8.
+    assert "nb=4" in programs[0]["program"]
+    ref = rehearse.rehearse_reference(2, "tiny-moe-backlog", root)
+    assert len(ref) == 1 and "reference forward" in ref[0]["program"]
+    with pytest.raises(SystemExit, match="not a train cell"):
+        rehearse.rehearse_train(2, "tiny-moe-backlog", root)
