@@ -51,13 +51,24 @@ control returns only once per chunk, and the dispatcher keeps
 ``pipeline_depth`` chunks in flight (JAX async dispatch). The defaults
 (32 steps, depth 2) were sized against a ~100 ms host round trip that a
 locally attached chip does not have; ROADMAP S7 re-derives them from the
-measured step time. Paged compile keys are (live-batch bucket, table-window
-bucket) for decode and (batch, chunk, window) buckets for prefill — the
-round-5 admit-bucket warm-compile machinery extended to paged shapes.
-In-order device execution makes page recycling safe: every in-flight
-chunk that can still write a retired slot's pages was dispatched before
-the harvest that freed them, so it executes before any later prefill
-that reuses them.
+measured step time. A chunk's tokens reach the host at its HARVEST, one
+to two chunks after its dispatch, so a paged slot is not held until its
+request's last token is seen: it is **released at the dispatch that
+exhausts the request's budget** (prefill yields the first token and each
+chunk ``chunk_size`` more, so after ``ceil((max_new - 1) / chunk_size)``
+chunks no further chunk can add a token to the reply, EOS or not). The
+request lives on in its futures' snapshots until harvest answers it; the
+slot and its pages go to a successor, whose prompt is prefilled behind
+the released row's last chunk and joins the next one. Only a reply that
+ends by EOS before its budget is found at harvest. Paged compile keys
+are (live-batch bucket, table-window bucket) for decode and (batch,
+chunk, window) buckets for prefill — the round-5 admit-bucket
+warm-compile machinery extended to paged shapes.
+In-order device execution makes page recycling safe: the pool and the
+slot vectors are threaded through every program, and every in-flight
+chunk that can still read or write a retired slot's pages was dispatched
+before the release or harvest that freed them, so it executes before any
+later prefill that reuses them.
 
 Per-slot sampling state (temperature, top_k, EOS id, PRNG seed) rides in
 [max_slots] device arrays, so a batch can mix greedy and sampled traffic —
@@ -187,7 +198,11 @@ class _Request:
     # ---- paged-mode scheduling state ----
     prefilling: bool = False   # mid chunked prefill (not yet decodable)
     prefill_pos: int = 0       # prompt tokens written (incl. shared prefix)
-    chunks_dispatched: int = 0  # decode chunks launched for this residency
+    # Decode chunks launched for this residency. The slot is released at
+    # the dispatch that makes ``1 + chunks_dispatched * chunk_size`` reach
+    # ``max_new`` (``_release_if_budget_dispatched``), not at that
+    # chunk's harvest.
+    chunks_dispatched: int = 0
     admit_seq: int = 0         # admission order (preemption picks youngest)
     gen: int = 0               # residency epoch; preemption invalidates
     #                            in-flight futures from the old epoch
@@ -209,6 +224,12 @@ class ContinuousBatchingEngine:
         self.max_top_k = max_top_k
         self._q: queue.Queue = queue.Queue()
         self._stop = threading.Event()
+        # The dispatcher's own: requests drained from the queue and not
+        # yet admitted, and the futures in flight. Held here because a
+        # request released at dispatch lives in a future's snapshot alone,
+        # and ``stop()`` must still answer it.
+        self._staged: List[_Request] = []
+        self._futures: deque = deque()
         # Host-side slot table: index -> live _Request (None = free).
         self._slots: List[Optional[_Request]] = [None] * max_slots
 
@@ -275,6 +296,10 @@ class ContinuousBatchingEngine:
         # bench discounts decode goodput by.
         self.decoded_rows_total = 0
         self.dispatched_rows_total = 0
+        # Paged slots released at the dispatch that exhausted their
+        # request's budget (the rest are retired at harvest: EOS before
+        # the budget, a cancelled submitter).
+        self.slots_released_total = 0
         self.preemptions = 0
         self._admit_counter = 0
         # warm() raises this so a known batch size admits as ONE bucket
@@ -850,6 +875,17 @@ class ContinuousBatchingEngine:
         self._pending_cow.pop(sid, None)
         self._slots[sid] = None
 
+    def _release_if_budget_dispatched(self, sid: int, r: _Request):
+        """Retire ``r``'s slot if no further chunk can add a token to its
+        reply: prefill yields the first token and each dispatched chunk
+        ``chunk_size`` more, EOS or not. Called right after a dispatch;
+        the programs in flight keep the table rows they were given and
+        run before any program that reuses the pages, and ``r`` is
+        answered from their snapshots at harvest."""
+        if 1 + r.chunks_dispatched * self.chunk_size >= r.max_new:
+            self._retire_slot(sid)
+            self.slots_released_total += 1
+
     def _note_kv_blocked(self):
         """Pool exhaustion = admission backpressure, surfaced for the
         doctor: counted, and emitted as a rate-limited health-engine-
@@ -1031,8 +1067,8 @@ class ContinuousBatchingEngine:
         ``prefill_chunk`` tokens, oldest admitted first; the rows stop at
         the first that does not fit the token ``budget`` (``inf``: none).
         A slot the pool refuses pages joins ``refused`` and sits out the
-        rest of the iteration (nothing frees pages before the next
-        harvest)."""
+        rest of the iteration (pages come back when a slot is released
+        or retired: at the decode dispatch or the harvest that follow)."""
         rows = []
         for sid, r in enumerate(self._slots):
             if r is None or not r.prefilling or r.finished \
@@ -1139,6 +1175,8 @@ class ContinuousBatchingEngine:
                     self._trie.register(r.prompt,
                                         self._slot_pages[sid][:n_full])
             snapshot.append((sid, r, bool(fin[i]), r.gen))
+            if fin[i]:
+                self._release_if_budget_dispatched(sid, r)  # max_new == 1
         self.prefill_chunks_run += len(batch)
         self.prefill_tokens_total += sum(tk for _, _, tk in batch)
         self._m_prefill_chunks.inc(len(batch))
@@ -1216,6 +1254,7 @@ class ContinuousBatchingEngine:
             r = self._slots[sid]
             r.chunks_dispatched += 1
             snapshot.append((sid, r, r.gen))
+            self._release_if_budget_dispatched(sid, r)
         try:
             toks.copy_to_host_async()
         except (AttributeError, RuntimeError):
@@ -1367,7 +1406,8 @@ class ContinuousBatchingEngine:
     def _slot_census(self) -> tuple:
         """(decoding, prefilling, free, other) over the slot table; the
         four sum to ``max_slots``. ``other``: cancelled by a timed-out
-        submitter, or finished, and not yet retired."""
+        submitter and not yet retired (monolithic: or finished). A
+        request released at dispatch holds no slot and is not counted."""
         dec = pre = free = 0
         for r in self._slots:
             if r is None:
@@ -1388,7 +1428,8 @@ class ContinuousBatchingEngine:
         return (self.prefill_tokens_total,
                 int(self._m_kv_hit_tokens.value), self.decoded_rows_total,
                 self.chunks_run, self.tokens_out_total,
-                self.requests_finished, self.harvest_wait_s_total)
+                self.requests_finished, self.slots_released_total,
+                self.harvest_wait_s_total)
 
     def _sched_record(self, seq: int, ts: list, idle: bool, c0: tuple,
                       census: tuple, queued: int, sent: list) -> dict:
@@ -1405,7 +1446,7 @@ class ContinuousBatchingEngine:
         it dispatched. No ``marks_s`` or ``waterfall`` key: readers pick
         request spans out of the same sink by those."""
         (pre_toks, hit_toks, dec_rows, chunks, toks_out,
-         finished, wait_s) = (
+         finished, released, wait_s) = (
             b - a for a, b in zip(c0, self._sched_counts()))
         dec, pre, free, other = census
         # ``prefill_steps``: the prefill programs it dispatched;
@@ -1432,11 +1473,9 @@ class ContinuousBatchingEngine:
             "prefill_hit_tokens": hit_toks, "decode_rows": dec_rows,
             "decode_steps": chunks * self.chunk_size,
             "tokens_out": toks_out, "requests_finished": finished,
-            "requests": list(ids)}
+            "slots_released": released, "requests": list(ids)}
 
     def _dispatch_loop(self):
-        futures: deque = deque()
-        staged: List[_Request] = []
         seq = 0
         while not self._stop.is_set():
             seq += 1
@@ -1444,12 +1483,26 @@ class ContinuousBatchingEngine:
             # clock, beside the jit_pre / jit_chunk programs they launch
             # in any captured trace.
             with annotate("sched.iter"):
-                self._iterate(seq, futures, staged)
+                self._iterate(seq)
 
-    def _iterate(self, seq: int, futures: deque, staged: List[_Request]):
+    def _fail_held(self, err: dict) -> None:
+        """Answer with ``err`` every unfinished request the dispatcher
+        holds: in a future's snapshot (a request released at dispatch
+        lives only there), staged for admission, or in a slot."""
+        held = [entry[1] for _, _, snapshot in self._futures
+                for entry in snapshot]
+        held += self._staged
+        held += [r for r in self._slots if r is not None]
+        for r in held:
+            if not r.finished:
+                r.finished, r.result = True, dict(err)
+                r.done.set()
+
+    def _iterate(self, seq: int):
         """One scheduler iteration: drain the queue, admit, the prefill
         programs its quota allows (``_prefill_steps``), one decode chunk,
         harvest down to ``pipeline_depth`` futures in flight."""
+        futures, staged = self._futures, self._staged
         sink = self.event_log   # read once: its owner may swap it
         on = sink is not None
         if on:
@@ -1520,12 +1573,16 @@ class ContinuousBatchingEngine:
                 ts.append(time.perf_counter())
             if sent or admitted:
                 self._m_activity.set(time.time())
-            # Keep <= pipeline_depth chunks in flight; drain fully
-            # when nothing is active (nobody else will harvest).
+            # Keep <= pipeline_depth chunks in flight; drain fully when
+            # no slot is occupied and nobody waits for one (no later
+            # dispatch would push these out). With a request staged the
+            # slots that the last chunk released are admitted next
+            # iteration, behind that chunk on the device.
             with annotate("sched.harvest"):
                 while futures and (len(futures) > self.pipeline_depth
-                                   or not any(r is not None
-                                              for r in self._slots)):
+                                   or not (staged
+                                           or any(r is not None
+                                                  for r in self._slots))):
                     # The harvest's device_get is where dispatched decode
                     # work actually drains: productive "decode" time.
                     with goodput.phase("decode"):
@@ -1541,23 +1598,10 @@ class ContinuousBatchingEngine:
         except Exception as ex:
             # Fail every in-flight and staged request; a poisoned
             # device state must not wedge the dispatcher silently.
-            err = {"error": f"{type(ex).__name__}: {ex}"}
-            for _, _, snapshot in futures:
-                for entry in snapshot:
-                    r = entry[1]
-                    if not r.finished:
-                        r.finished, r.result = True, dict(err)
-                        r.done.set()
+            self._fail_held({"error": f"{type(ex).__name__}: {ex}"})
             futures.clear()
-            for r in staged:
-                r.finished, r.result = True, dict(err)
-                r.done.set()
             staged.clear()
-            for i, r in enumerate(self._slots):
-                if r is not None and not r.finished:
-                    r.finished, r.result = True, dict(err)
-                    r.done.set()
-                self._slots[i] = None
+            self._slots[:] = [None] * self.max_slots
             if self._paged:
                 # Rebuild the allocator with the device state: a
                 # poisoned pool's tables point at freed pages.
@@ -1732,7 +1776,4 @@ class ContinuousBatchingEngine:
                 r.done.set()
         except queue.Empty:
             pass
-        for r in self._slots:
-            if r is not None and not r.finished:
-                r.result = {"error": "server shutting down"}
-                r.done.set()
+        self._fail_held({"error": "server shutting down"})

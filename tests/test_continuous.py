@@ -18,10 +18,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from serverless_learn_tpu.config import KVCacheConfig
 from serverless_learn_tpu.inference.continuous import (
     ContinuousBatchingEngine)
 from serverless_learn_tpu.inference.generate import generate
 from serverless_learn_tpu.models.registry import get_model
+from serverless_learn_tpu.telemetry.registry import MetricsRegistry
 
 
 @pytest.fixture(scope="module")
@@ -287,3 +289,144 @@ def test_server_with_continuous_engine(model):
             assert reps[i].get("new_tokens") == _solo(module, params, p, 4)
     finally:
         srv.stop()
+
+
+# -- a paged slot is released when its request's budget is dispatched --------
+#
+# Threaded, through ``submit``: whatever order the clients arrive in, the
+# engine pays one row-chunk per row-chunk owed and the replies do not
+# depend on how many chunks it keeps in flight.
+
+CHUNK = 4
+# (prompt, max_new, temperature, top_k, seed): more requests than slots,
+# budgets from 1 token (no chunk owed) to 17 (four chunks), prompts of one
+# to four prefill chunks; every reply ends by its budget (no EOS id).
+MIX = [([5, 9, 11], 1, 0.0, 0, 0),
+       ([7, 3, 2, 8, 1, 30, 12, 9, 4, 2, 6, 1, 8], 2, 0.0, 0, 0),
+       ([4], 5, 0.0, 0, 0),
+       ([1, 2], 6, 0.9, 8, 11),
+       ([9, 8, 7, 6, 5, 4], 9, 0.0, 0, 0),
+       ([2, 2, 3, 3, 4, 4, 5], 4, 0.7, 0, 12),
+       ([6, 1], 13, 0.0, 0, 0),
+       ([3, 1, 4, 1, 5, 9, 2, 6, 5], 3, 0.0, 0, 0),
+       ([8, 8, 1], 8, 1.0, 4, 13),
+       ([11, 12, 13, 14, 15], 17, 0.0, 0, 0)]
+LIMIT_S = 120.0     # each client's own time limit, and each join's
+
+
+def _paged(module, params, depth):
+    return ContinuousBatchingEngine(
+        module, params, max_slots=4, chunk_size=CHUNK,
+        pipeline_depth=depth, registry=MetricsRegistry(),
+        kv=KVCacheConfig(block_size=4, prefill_chunk=4))
+
+
+def _start_clients(eng, mix):
+    """One client thread per request of ``mix``, started; the replies
+    land in the returned list."""
+    replies = [None] * len(mix)
+
+    def client(i):
+        prompt, n, temp, topk, seed = mix[i]
+        replies[i] = eng.submit(prompt, n, temp, topk, None, seed,
+                                timeout_s=LIMIT_S)
+
+    threads = [threading.Thread(target=client, args=(i,), daemon=True)
+               for i in range(len(mix))]
+    for t in threads:
+        t.start()
+    return threads, replies
+
+
+def _join_clients(threads, limit_s):
+    for t in threads:
+        t.join(timeout=limit_s)
+    assert not any(t.is_alive() for t in threads), "a client hung"
+
+
+def _submit_all(eng, mix):
+    threads, replies = _start_clients(eng, mix)
+    _join_clients(threads, LIMIT_S + 30)
+    return replies
+
+
+@pytest.fixture(scope="module")
+def mix_run(model):
+    """One run of MIX through a paged engine per ``pipeline_depth``."""
+    runs = {}
+
+    def get(depth: int) -> dict:
+        if depth not in runs:
+            eng = _paged(*model, depth)
+            try:
+                replies = _submit_all(eng, MIX)
+            finally:
+                eng.stop()
+            runs[depth] = {"engine": eng, "replies": replies}
+        return runs[depth]
+
+    return get
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_row_chunks_paid_equal_row_chunks_owed(mix_run, depth):
+    """Prefill yields a reply's first token and each chunk CHUNK more, so
+    a reply of n tokens owes ceil((n - 1) / CHUNK) row-chunks; the engine
+    dispatches exactly those, because a slot is released at the dispatch
+    that exhausts its budget and not at that chunk's harvest."""
+    eng = mix_run(depth)["engine"]
+    owed = sum(-(-(n - 1) // CHUNK) for _, n, _, _, _ in MIX)
+    assert eng.decoded_rows_total == owed == 17
+    assert eng.slots_released_total == len(MIX) == eng.requests_finished
+    assert eng.preemptions == 0
+    st = eng.kv_stats()
+    assert (st["blocks_total"] - st["blocks_free"]
+            == st["prefix_blocks_cached"])
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_released_greedy_replies_match_solo_generate(model, mix_run, depth):
+    module, params = model
+    for (prompt, n, temp, _, _), rep in zip(MIX, mix_run(depth)["replies"]):
+        assert rep is not None and "error" not in rep, rep
+        assert len(rep["new_tokens"]) == n
+        if temp == 0.0:
+            assert rep["new_tokens"] == _solo(module, params, prompt, n)
+
+
+def test_replies_do_not_depend_on_pipeline_depth(model, mix_run):
+    """Greedy and sampled alike: a row's stream depends on its request
+    alone (``fold_in(seed, position)``), so two chunks in flight give the
+    tokens one gives, and a sampled request alone gives them again."""
+    one, two = mix_run(1)["replies"], mix_run(2)["replies"]
+    assert [r["new_tokens"] for r in one] == [r["new_tokens"] for r in two]
+    sampled = [m for m in MIX if m[2] > 0.0]
+    assert len(sampled) == 3
+    eng = _paged(*model, 2)
+    try:
+        for m in sampled:
+            alone = _submit_all(eng, [m])[0]
+            assert alone["new_tokens"] == two[MIX.index(m)]["new_tokens"]
+    finally:
+        eng.stop()
+
+
+def test_stop_mid_traffic_answers_every_client(model):
+    """``stop()`` while requests are queued, in slots and (released at
+    dispatch) in flight: it returns, and every client gets its answer or
+    an error; none waits out its timeout."""
+    eng = _paged(*model, 2)
+    mix = [(p, 24, 0.0, 0, 0) for p, _, _, _, _ in MIX]
+    threads, replies = _start_clients(eng, mix)
+    deadline = time.time() + LIMIT_S
+    while ((eng._m_requests.value < len(mix) or eng.chunks_run < 3)
+           and time.time() < deadline):
+        time.sleep(0.005)
+    t0 = time.time()
+    eng.stop()
+    _join_clients(threads, 20)
+    assert time.time() - t0 < 40
+    for rep in replies:
+        assert rep is not None and ("new_tokens" in rep or "error" in rep)
+    assert any("error" in rep for rep in replies), \
+        "ten replies of 24 tokens cannot all have been done by chunk 3"

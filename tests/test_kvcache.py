@@ -312,7 +312,7 @@ def test_prefill_feeds_oldest_admitted_first(model):
 def test_refused_pages_sit_out_the_iteration(model):
     """Back-pressure inside an iteration of several programs: a slot the
     pool refuses pages is counted once and sits out the rest of the
-    iteration (nothing frees pages before the next harvest); the loop
+    iteration (nothing frees pages before its decode dispatch); the loop
     ends when every remaining row is refused."""
     module, params = model
     kv = KVCacheConfig(block_size=4, num_blocks=16, prefill_chunk=4,
@@ -488,6 +488,254 @@ def test_decode_cost_tracks_live_slots(model):
              f"{eng.decoded_rows_total} rows over {eng.chunks_run} chunks")
     finally:
         eng.stop()
+
+
+# -- a slot is released when its request's budget is dispatched --------------
+#
+# Driven by hand: the dispatcher thread is stopped and the test calls
+# ``_iterate`` itself, so what is in flight at each step is known.
+
+
+class _ByHand:
+    """A paged engine whose dispatcher has stopped; requests go on its
+    queue as the engine's own ``_Request`` and the test steps the
+    scheduler. Two slots, chunks of 4, pages of 4, a pool of 16 pages
+    (one max-length sequence), no prefix cache."""
+
+    def __init__(self, module, params, max_slots=2, pipeline_depth=2):
+        self.module, self.params = module, params
+        self.eng = ContinuousBatchingEngine(
+            module, params, max_slots=max_slots, chunk_size=4,
+            pipeline_depth=pipeline_depth, registry=MetricsRegistry(),
+            kv=KVCacheConfig(block_size=4, num_blocks=16, prefill_chunk=4,
+                             prefix_cache=False))
+        self.eng.stop()
+        self.seq = 0
+        self.retired = []   # (slot, its pages) at every retirement
+        real = self.eng._retire_slot
+
+        def retire(sid):
+            self.retired.append((sid, list(self.eng._slot_pages[sid])))
+            real(sid)
+
+        self.eng._retire_slot = retire
+
+    def put(self, prompt, max_new, eos_id=None):
+        from serverless_learn_tpu.inference.continuous import _Request
+
+        r = _Request(prompt=np.asarray(prompt, np.int32), max_new=max_new,
+                     temperature=0.0, top_k=0, eos_id=eos_id, seed=0)
+        self.eng._q.put(r)
+        return r
+
+    def step(self, n=1):
+        for _ in range(n):
+            self.seq += 1
+            self.eng._iterate(self.seq)
+
+    def run_out(self, *requests, limit=200):
+        """Step until every request is answered: the test's time limit,
+        in iterations."""
+        while not all(r.done.is_set() for r in requests):
+            assert self.seq < limit, "the scheduler made no progress"
+            self.step()
+
+    def slot_of(self, r):
+        return [x is r for x in self.eng._slots].index(True)
+
+    def in_flight(self, r) -> bool:
+        return any(entry[1] is r for _, _, snapshot in self.eng._futures
+                   for entry in snapshot)
+
+    def assert_exact(self, *requests):
+        for r in requests:
+            assert r.result is not None and "error" not in r.result, \
+                r.result
+            assert r.result["new_tokens"] == _solo(
+                self.module, self.params, [int(t) for t in r.prompt],
+                r.max_new, eos_id=r.eos_id)
+
+
+N_PROMPT, A_PROMPT = [7, 3, 2, 8], [5, 9, 11, 3, 1, 4, 1, 5]
+B_SHORT, B_LONG = [6, 2, 8, 3, 1, 8], list(range(20, 32))
+
+
+def _released_in_flight(model, b_prompt=B_LONG, depth=2):
+    """Two iterations in: a long-running neighbour N holds slot 0; A's
+    second and last chunk has just been dispatched, so slot 1 is free
+    and A's pages are back in the pool while A waits for its harvest; B
+    has waited in the queue for a slot."""
+    h = _ByHand(*model, pipeline_depth=depth)
+    n = h.put(N_PROMPT, 30)
+    a = h.put(A_PROMPT, 9)       # owes ceil(8 / 4) = 2 chunks
+    b = h.put(b_prompt, 6)
+    h.step()
+    assert (h.slot_of(n), h.slot_of(a)) == (0, 1) and not b.admitted
+    assert h.eng.slots_released_total == 0
+    h.step()
+    return h, n, a, b
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_released_pages_go_to_successor_with_last_chunk_in_flight(model,
+                                                                  depth):
+    """The slot and the pages leave A at the dispatch of its last chunk;
+    B takes both in the next iteration, while that chunk is still in
+    flight. The device runs B's prefill after the chunk, so all three
+    replies equal their solo runs."""
+    h, n, a, b = _released_in_flight(model, depth=depth)
+    eng = h.eng
+    assert eng.slots_released_total == 1 and eng._slots[1] is None
+    assert not a.done.is_set() and not a.finished and h.in_flight(a)
+    (sid, a_pages), = h.retired
+    assert sid == 1 and len(a_pages) == 4
+    assert eng._pool.free_blocks == 16 - len(eng._slot_pages[0])
+    seen = {}
+    real = eng._admit_paged
+
+    def admit(staged):
+        out = real(staged)
+        if b.admitted and not seen:
+            seen.update(a_done=a.done.is_set(), a_in_flight=h.in_flight(a),
+                        pages=list(eng._slot_pages[1]))
+        return out
+
+    eng._admit_paged = admit
+    h.step()
+    assert eng._slots[1] is b
+    assert seen["a_in_flight"] and not seen["a_done"]
+    assert set(seen["pages"]) <= set(a_pages), \
+        "the LIFO pool hands the successor the released row's pages"
+    h.run_out(n, a, b)
+    h.assert_exact(n, a, b)
+    assert eng.preemptions == 0
+    assert eng._pool.free_blocks == 16
+    # Paid equals owed: ceil((max_new - 1) / 4) row-chunks a reply.
+    assert eng.decoded_rows_total == 8 + 2 + 2
+    assert eng.slots_released_total == 3 == eng.requests_finished
+
+
+def test_successor_joins_the_chunk_after_the_released_rows_last(model):
+    """A prompt that fits the iteration's prefill quota: the slot goes
+    from one reply's last chunk to the next reply's first with no chunk
+    between."""
+    h, n, a, b = _released_in_flight(model, b_prompt=B_SHORT)
+    chunks = h.eng.chunks_run
+    h.step()
+    assert h.eng.chunks_run == chunks + 1
+    kind, _, snapshot = h.eng._futures[-1]
+    assert kind == "pchunk"
+    assert [(sid, r) for sid, r, _ in snapshot] == [(0, n), (1, b)]
+    h.run_out(n, a, b)
+    h.assert_exact(n, a, b)
+
+
+def test_staged_successor_keeps_the_released_chunk_in_flight(model):
+    """Every slot released and a request waiting: the harvest does not
+    drain ahead of it (the successor's prefill is queued behind the
+    chunk on the device); with nobody waiting it drains at once."""
+    h = _ByHand(*model, max_slots=1)
+    a, b = h.put(A_PROMPT, 5), h.put(B_SHORT, 5)
+    h.step()
+    assert h.eng._slots == [None] and h.eng.slots_released_total == 1
+    assert not a.done.is_set() and h.in_flight(a)
+    h.step()
+    assert a.done.is_set() and h.eng.slots_released_total == 2
+    assert h.eng._slots == [None] and b.done.is_set(), \
+        "nobody waits: the iteration that released b also answered it"
+    h.assert_exact(a, b)
+    assert h.eng.decoded_rows_total == 2
+
+
+def test_max_new_one_is_released_at_its_last_prefill_program(model):
+    h = _ByHand(*model)
+    n = h.put(N_PROMPT, 30)
+    h.step()
+    one = h.put(B_LONG, 1)      # three prefill programs, two a step
+    h.step()
+    assert h.eng._slots[1] is one and one.prefilling
+    h.step()
+    assert h.eng._slots[1] is None and h.eng.slots_released_total == 1
+    assert one.chunks_dispatched == 0
+    h.run_out(one)
+    h.assert_exact(one)
+    rows = h.eng.decoded_rows_total
+    assert rows == h.eng.chunks_run, "only the neighbour ever decoded"
+    h.run_out(n)
+    h.assert_exact(n)
+
+
+def test_eos_before_the_budget_is_found_at_harvest(model):
+    """EOS ends a reply sooner than its budget: found at the harvest of
+    its chunk as before (no release at dispatch), filled to ``max_new``
+    like solo generate, its slot and pages retired there."""
+    module, params = model
+    first = _solo(module, params, A_PROMPT, 1)[0]
+    h = _ByHand(module, params)
+    n = h.put(N_PROMPT, 30)
+    e = h.put(A_PROMPT, 24, eos_id=first)
+    h.run_out(e)
+    assert e.result["new_tokens"] == [first] * 24
+    h.assert_exact(e)
+    assert h.eng.slots_released_total == 0
+    assert e.chunks_dispatched < 6, "it never reached its budget"
+    assert [sid for sid, _ in h.retired] == [1]
+    h.run_out(n)
+    h.assert_exact(n)
+    assert h.eng._pool.free_blocks == 16
+
+
+def test_cancelled_neighbour_while_a_released_request_is_in_flight(model):
+    h, n, a, b = _released_in_flight(model)
+    n.cancelled = True          # its submitter timed out
+    h.run_out(a, b)
+    assert n.finished and "cancelled" in n.result["error"]
+    assert h.eng.requests_cancelled == 1
+    h.assert_exact(a, b)
+    assert h.eng._slots == [None, None]
+    assert h.eng._pool.free_blocks == 16
+    assert h.eng.slots_released_total == 2
+
+
+def test_preempting_neighbour_while_a_released_request_is_in_flight(model):
+    """The pool is squeezed to one free page, one that A just gave back.
+    B is admitted onto it and prefills into it; N's next chunk needs a
+    page, so N preempts B (the youngest) and decodes into that same
+    page, all while A's last chunk is in flight. A released request is
+    no preemption victim, and every reply equals its solo run."""
+    h, n, a, b = _released_in_flight(model)
+    eng = h.eng
+    (_, a_pages), = h.retired
+    ballast = eng._pool.alloc(eng._pool.free_blocks)
+    ballast.remove(a_pages[0])
+    eng._pool.decref(a_pages[:1])
+    h.step()
+    assert eng.preemptions == 1 and not b.admitted and b.gen == 1
+    assert eng._slots[1] is None and h.retired[-1][1][0] in a_pages
+    assert eng._slot_pages[0][-1] in a_pages
+    eng._pool.decref(ballast)
+    h.run_out(n, a, b)
+    h.assert_exact(n, a, b)
+    assert eng.preemptions == 1 and eng._pool.free_blocks == 16
+    # B's first residency was preempted before it decoded: nothing paid
+    # twice, and its second residency is released at dispatch too.
+    assert eng.decoded_rows_total == 8 + 2 + 2
+    assert eng.slots_released_total == 3
+
+
+def test_stop_answers_a_released_request_in_flight(model):
+    """A request released at dispatch lives in a future's snapshot
+    alone; ``stop()`` still answers it, as it answers the slots' and the
+    staged requests."""
+    h, n, a, b = _released_in_flight(model)
+    assert h.in_flight(a) and h.eng._slots[1] is None
+    assert not b.admitted and h.eng._staged[0] is b
+    t0 = time.time()
+    h.eng.stop()
+    assert time.time() - t0 < 10
+    for r in (n, a, b):
+        assert r.done.is_set() and r.finished
+        assert r.result == {"error": "server shutting down"}
 
 
 def test_block_table_write_padding_drops(model):

@@ -274,6 +274,31 @@ def test_decode_rows_match_the_engines_counters(run):
         assert bool(r["decode_rows"]) == bool(r["decode_steps"])
 
 
+def test_slots_released_are_the_requests_that_ended_by_budget(run):
+    """No request has an EOS id, so every reply ends by its budget: the
+    paged engine releases each slot at the dispatch that exhausts it,
+    and so dispatches exactly the row-chunks the replies owe. The
+    monolithic engine finds every finished row at harvest."""
+    eng = run["engine"]
+    assert _total(run, "slots_released") == eng.slots_released_total
+    for r in run["iters"]:
+        # At most the rows of its chunk and its finishing prefill rows.
+        assert 0 <= r["slots_released"] <= 2 * MAX_SLOTS
+    owed = sum(-(-(n - 1) // CHUNK) for _, n in run["requests"])
+    if run["scenario"] == "monolithic":
+        assert eng.slots_released_total == 0
+        assert _total(run, "decode_rows") >= owed
+        return
+    assert _total(run, "slots_released") == len(run["requests"]) \
+        == _total(run, "requests_finished")
+    assert _total(run, "decode_rows") == owed
+    # A released request holds no slot: nothing is found finished and
+    # not yet retired, and no submitter timed out.
+    assert _total(run, "slots_other") == 0
+    for r in run["iters"]:
+        assert r["slots_released"] <= r["decode_rows"] + r["prefill_rows"]
+
+
 def test_tokens_out_are_the_replies(run):
     reply_tokens = sum(len(r["new_tokens"]) for r in run["replies"])
     assert reply_tokens == sum(n for _, n in run["requests"])
