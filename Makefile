@@ -31,7 +31,7 @@ racecheck:  ## concurrency surface under the vector-clock happens-before race de
 
 jitcheck:  ## inference/training compile discipline under the runtime jit monitor
 	SLT_JITCHECK=1 python -m pytest tests/test_continuous.py \
-		tests/test_serve_batching.py tests/test_train_step.py \
+		tests/test_kvcache.py tests/test_train_step.py \
 		tests/test_grad_accum_eval.py tests/test_jitcheck.py \
 		-q -m "not slow"
 	python -m serverless_learn_tpu jit --self-check

@@ -90,27 +90,24 @@ def run(model: str = "llama_tiny", batch: int = 8, prompt_len: int = 128,
 
 def run_concurrent(model: str = "llama_tiny", clients: int = 4,
                    prompt_len: int = 128, new_tokens: int = 64,
-                   reqs: int = 3, engine: str = "static",
-                   stagger_ms: float = 0.0) -> dict:
+                   reqs: int = 3, stagger_ms: float = 0.0) -> dict:
     """Aggregate multi-client serving throughput: ``clients`` threads each
-    fire ``reqs`` sequential requests at the chosen engine, once batched
-    and once serialized (max_batch/max_slots=1 — what the round-3 server
-    did to every workload). The ratio is the batching win; the round-3
-    verdict's bar is >= 2.5x with 4 clients. Decode is HBM-bound on TPU,
-    so batch-4 decode steps cost ~ the same wall time as batch-1 —
-    near-linear aggregate scaling is the expected physics.
+    fire ``reqs`` sequential requests at the serving engine, once batched
+    and once serialized (max_slots=1). The ratio is the batching win.
+    Decode is HBM-bound on TPU, so batch-4 decode steps cost ~ the same
+    wall time as batch-1 — near-linear aggregate scaling is the expected
+    physics.
 
-    ``engine="continuous"`` measures the round-5 slot scheduler on the
-    same workload. ``stagger_ms``: per-client start offset — the arrival
-    pattern where run-to-completion groups lose (a request landing one
-    tick after dispatch waits out the whole group) and slot-level
-    admission wins. Per-request latencies are recorded; p50/p95 ride in
-    the row."""
+    ``stagger_ms``: per-client start offset — arrivals that land between
+    chunk boundaries, which slot-level admission takes at the next one.
+    Per-request latencies are recorded; p50/p95 ride in the row."""
     import threading
 
     import jax
     import jax.numpy as jnp
 
+    from serverless_learn_tpu.inference.continuous import (
+        ContinuousBatchingEngine)
     from serverless_learn_tpu.models.registry import get_model
     from serverless_learn_tpu.telemetry import MetricsRegistry
 
@@ -123,25 +120,13 @@ def run_concurrent(model: str = "llama_tiny", clients: int = 4,
         jax.random.randint(rng, (clients, prompt_len), 0,
                            module.cfg.vocab_size))]
 
-    def make_engine(width: int):
+    def measure(width: int):
         # Private registry per engine: the bench attaches this arm's
         # queue-wait/TTFT percentiles to its row without cross-arm (or
         # cross-process-default) contamination.
-        reg = MetricsRegistry()
-        if engine == "continuous":
-            from serverless_learn_tpu.inference.continuous import (
-                ContinuousBatchingEngine)
-
-            return ContinuousBatchingEngine(module, params,
-                                            max_slots=width,
-                                            chunk_size=32, registry=reg)
-        from serverless_learn_tpu.inference.batching import BatchingEngine
-
-        return BatchingEngine(module, params, max_batch=width,
-                              batch_wait_ms=5.0, registry=reg)
-
-    def measure(width: int):
-        eng = make_engine(width)
+        eng = ContinuousBatchingEngine(module, params, max_slots=width,
+                                       chunk_size=32,
+                                       registry=MetricsRegistry())
         try:
             def round_trip():
                 barrier = threading.Barrier(clients)
@@ -178,22 +163,12 @@ def run_concurrent(model: str = "llama_tiny", clients: int = 4,
                     raise RuntimeError(f"serving errors: {errors[:3]}")
                 return dt, sorted(lat)
 
-            # Deterministically compile EVERY batch bucket the timed round
-            # could form (grouping is timing-dependent: a straggler thread
-            # can split 4 clients into groups of 3+1, and an uncompiled
-            # bucket inside the timed window would bill a multi-second XLA
-            # compile as serving time). Every power-of-two bucket up to
-            # min(clients, width) is covered; the continuous engine's
-            # chunk shape is bucket-independent and its warm() gates the
-            # dispatcher so each size admits as ONE bucket — admission
-            # splits were thread-timing-dependent before (a size-2 warm
-            # admitting 1+1 compiled only the nb=1 admit; ADVICE round 5).
-            sizes = {1}
-            b = 1
-            while b < min(clients, width):
-                b *= 2
-                sizes.add(min(b, width))
-            eng.warm(prompt_len, new_tokens, batch_sizes=sorted(sizes))
+            # Compile every bucket the timed round could form, without
+            # traffic (how arrivals batch is timing-dependent, and an
+            # uncompiled bucket inside the timed window would bill a
+            # multi-second XLA compile as serving time).
+            eng.warm_shapes([(prompt_len, new_tokens)],
+                            batch_sizes=range(1, min(clients, width) + 1))
             round_trip()  # warm the queue path itself
             dt, lat = round_trip()
             return clients * reqs * new_tokens / dt, lat, eng.registry
@@ -203,8 +178,8 @@ def run_concurrent(model: str = "llama_tiny", clients: int = 4,
     serialized, _, _ = measure(1)
     batched, lat, reg = measure(clients * 2)
     rec = {
-        "metric": f"{model}_serve_concurrent_tokens_per_sec",
-        "clients": clients, "prompt_len": prompt_len,
+        "metric": f"{model}_serve_continuous_tokens_per_sec",
+        "engine": "continuous", "clients": clients, "prompt_len": prompt_len,
         "new_tokens": new_tokens,
         "value": round(batched, 1), "unit": "tokens/sec aggregate",
         "serialized_tokens_per_sec": round(serialized, 1),
@@ -218,14 +193,11 @@ def run_concurrent(model: str = "llama_tiny", clients: int = 4,
     # can track serving latency shape, not just aggregate throughput.
     for hname, key in (("slt_request_queue_wait_seconds", "queue_wait"),
                        ("slt_request_ttft_seconds", "ttft")):
-        h = reg.histogram(hname, engine=engine)
+        h = reg.histogram(hname, engine="continuous")
         for q, sfx in ((0.5, "p50"), (0.95, "p95")) if h.count else ():
             p = h.percentile(q)
             if p is not None:
                 rec[f"{key}_{sfx}_ms"] = round(p * 1e3, 2)
-    if engine != "static":
-        rec["metric"] = f"{model}_serve_{engine}_tokens_per_sec"
-        rec["engine"] = engine
     if stagger_ms:
         rec["stagger_ms"] = stagger_ms
     return rec

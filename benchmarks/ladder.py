@@ -26,9 +26,8 @@ Rows (chip-side unless noted):
     decodemoe  MoE decode (moe_tiny, per-token top-2 routing)
     spec       speculative decode (llama_1b, 4-layer prefix draft, K=4;
                acceptance recorded — weight-dependent)
-    serve      4-client batched-serving aggregate vs serialized
-    servec     continuous vs static engines under staggered arrivals
-               (aggregate + p50/p95; round-5 slot scheduler)
+    serve      4-client serving aggregate vs serialized under staggered
+               arrivals (aggregate + p50/p95)
     llama8b    8B-width per-layer step time on real silicon (labeled
                extrapolation to the full model)
     llama8b_real  REAL full-depth Llama-8B on ONE chip: QLoRA train step
@@ -569,40 +568,15 @@ def row_spec():
 
 
 def row_serve():
-    """Multi-client batched serving aggregate (round-3 verdict #2).
-    Round 5: best-of-3 with recorded spread (verdict #6) — single-sample
-    serve runs swung 756-805 tokens/s and tripped the guard."""
-    from benchmarks.gen_bench import run_concurrent
-
-    rec = _best_of(lambda: run_concurrent(
-        "llama_tiny", clients=4, prompt_len=128, new_tokens=64))
-    rec["device_kind"] = _device_kind()
-    return record_history(rec, HISTORY, better="max",
-                          key_fields=("metric", "device_kind", "clients",
-                                      "prompt_len", "new_tokens"))
-
-
-def row_servec():
-    """Continuous vs static serving under STAGGERED arrivals (round-5
-    verdict #2's bar: aggregate >= the static engine with lower p50).
-    Arrivals offset by 40 ms per client — the pattern where
-    run-to-completion groups lose (a late request waits out the whole
-    group; the slot scheduler admits it at the next chunk boundary).
-    Value = continuous aggregate; the static run's aggregate and both
-    p50s ride in-row so the comparison is one guarded record."""
+    """Multi-client serving aggregate against the serialized engine under
+    STAGGERED arrivals: 40 ms per client, so requests land between chunk
+    boundaries and are admitted at the next one. Best-of-3 with recorded
+    spread (single-sample serve runs tripped the guard)."""
     from benchmarks.gen_bench import run_concurrent
 
     rec = _best_of(lambda: run_concurrent(
         "llama_tiny", clients=4, prompt_len=128, new_tokens=64,
-        engine="continuous", stagger_ms=40.0))
-    st = _best_of(lambda: run_concurrent(
-        "llama_tiny", clients=4, prompt_len=128, new_tokens=64,
-        engine="static", stagger_ms=40.0))
-    rec["static_tokens_per_sec"] = st["value"]
-    rec["static_p50_latency_ms"] = st["p50_latency_ms"]
-    rec["static_p95_latency_ms"] = st["p95_latency_ms"]
-    rec["continuous_over_static"] = round(
-        rec["value"] / max(st["value"], 1e-9), 2)
+        stagger_ms=40.0))
     rec["device_kind"] = _device_kind()
     return record_history(rec, HISTORY, better="max",
                           key_fields=("metric", "device_kind", "clients",
@@ -752,7 +726,6 @@ ROWS = {
     "decodemoe": row_decodemoe,
     "spec": row_spec,
     "serve": row_serve,
-    "servec": row_servec,
     "llama8b": row_llama8b_width,
     "llama8b_real": row_llama8b_real,
     "localsgd": row_localsgd,

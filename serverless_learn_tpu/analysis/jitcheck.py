@@ -14,7 +14,7 @@ reports to a process-global :class:`JitMonitor`:
   lives NEXT TO the bucket functions (``continuous.py``,
   ``train_step.py``); a declared site whose jit object compiles more
   than N times is a violation — the memoized-bucket contract
-  (``_admit_jit(nb, pb)`` compiles exactly once per key) machine-
+  (``_paged_chunk_jit(nb, W)`` compiles exactly once per key) machine-
   checked;
 * **frozen windows**: ``with jitcheck.frozen("post-warmup")`` marks a
   region (after ``warm_shapes()``, inside a measured bench window)
@@ -608,7 +608,7 @@ def self_check() -> List[str]:
         finally:
             os.unlink(path)
 
-    site = "serverless_learn_tpu/inference/continuous.py:_admit_jit"
+    site = "serverless_learn_tpu/inference/continuous.py:_paged_chunk_jit"
     clean = _run([
         {"ev": "declare", "site": site, "budget": 1},
         {"ev": "compile", "site": site, "n": 1, "args": ["f32[8]"]},

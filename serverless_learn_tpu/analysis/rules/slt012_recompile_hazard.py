@@ -23,7 +23,7 @@ call sites in another):
   never hits the compile cache.
 * **unbucketed shape key** (error): a call to a *bucketed jit factory*
   (a function that memoizes/returns ``jax.jit`` objects keyed by its
-  int params, e.g. ``_admit_jit(nb, pb)``) whose argument resolves to a
+  int params, e.g. ``_paged_chunk_jit(nb, W)``) whose argument resolves to a
   raw ``len(...)``/arithmetic chain with NO bucket-function call in it
   — unbounded compile-key cardinality. Bucket functions are declared
   with ``@jitcheck.bucket`` (see ``analysis/jitcheck.py``); ``min``/
@@ -230,8 +230,8 @@ def _check_jit_in_loop(sf, findings: List[Finding]):
 
 def _jit_factories(tree: ast.AST) -> Dict[str, List[str]]:
     """name -> int-ish param names, for functions that memoize or
-    return a jax.jit keyed by their parameters (the `_admit_jit(nb,
-    pb)` shape-factory idiom)."""
+    return a jax.jit keyed by their parameters (the
+    `_paged_chunk_jit(nb, W)` shape-factory idiom)."""
     out: Dict[str, List[str]] = {}
     for node in ast.walk(tree):
         if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):

@@ -724,8 +724,6 @@ def cmd_serve(args) -> int:
     params, _ = _load_inference_params(args, cfg, trainer)
     module, params = _maybe_quantize(args, trainer, params)
     kv = cfg.kv
-    if args.kv_monolithic:
-        kv = dataclasses.replace(kv, paged=False)
     if args.kv_block_size is not None:
         kv = dataclasses.replace(kv, block_size=args.kv_block_size)
     if args.kv_num_blocks is not None:
@@ -737,8 +735,6 @@ def cmd_serve(args) -> int:
     server = GenerationServer(module, params,
                               host=args.host, port=args.port,
                               max_batch=args.max_batch,
-                              batch_wait_ms=args.batch_wait_ms,
-                              engine=args.serve_engine,
                               chunk_size=args.chunk_size,
                               metrics_port=args.metrics_port,
                               event_log_path=args.events_log,
@@ -926,17 +922,6 @@ def cmd_loadgen(args) -> int:
         # overhead share is exported and bounded.
         rep = loadgen.run_canary_smoke(
             seed=args.seed,
-            history_path=args.history if args.record else None)
-        print(json.dumps(rep, indent=None if args.compact else 2))
-        return 0 if rep["ok"] else 1
-    if args.kv_smoke:
-        # Round-13 serving headline: same seeded shared-prefix workload
-        # at the same offered load vs the paged and monolithic engines;
-        # exit 0 iff the paged engine measurably wins (short-class p99
-        # down, decode goodput share up) with zero hard failures.
-        rep = loadgen.run_kv_smoke(
-            seed=args.seed, rate_rps=args.rate or 10.0,
-            duration_s=args.duration or 6.0,
             history_path=args.history if args.record else None)
         print(json.dumps(rep, indent=None if args.compact else 2))
         return 0 if rep["ok"] else 1
@@ -2191,31 +2176,20 @@ def build_parser() -> argparse.ArgumentParser:
                     help="bind address (0.0.0.0 to accept remote clients)")
     sv.add_argument("--port", type=int, default=50060)
     sv.add_argument("--max-batch", type=int, default=8,
-                    help="admission queue coalesces up to this many "
-                         "compatible concurrent requests per device batch")
-    sv.add_argument("--batch-wait-ms", type=float, default=3.0,
-                    help="how long the dispatcher waits to co-batch "
-                         "requests (latency floor under load)")
+                    help="decode slots: concurrent requests sharing one "
+                         "device batch")
     sv.add_argument("--quant", choices=["int8"], default=None,
                     help="weight-only int8 serving (see generate --quant)")
-    sv.add_argument("--serve-engine", choices=["continuous", "static"],
-                    default="continuous",
-                    help="continuous: slot-level scheduler (admit at chunk "
-                         "boundaries, retire at EOS, FIFO); static: "
-                         "round-4 group coalescer")
     sv.add_argument("--chunk-size", type=int, default=32,
                     help="decode tokens per jitted chunk between admission "
-                         "boundaries (continuous engine)")
-    sv.add_argument("--kv-monolithic", action="store_true",
-                    help="legacy per-slot monolithic KV rows instead of "
-                         "the paged block pool (equivalence baseline)")
+                         "boundaries")
     sv.add_argument("--kv-block-size", type=int, default=None,
-                    help="paged KV: tokens per block (config kv.block_size)")
+                    help="KV pool: tokens per block (config kv.block_size)")
     sv.add_argument("--kv-num-blocks", type=int, default=None,
-                    help="paged KV: pool blocks per layer; 0 = auto "
+                    help="KV pool: blocks per layer; 0 = auto "
                          "no-overcommit sizing (config kv.num_blocks)")
     sv.add_argument("--prefill-chunk", type=int, default=None,
-                    help="paged KV: prompt tokens per prefill chunk "
+                    help="KV pool: prompt tokens per prefill chunk "
                          "interleaved between decode boundaries "
                          "(config kv.prefill_chunk; 0 = whole prompt)")
     sv.add_argument("--no-prefix-cache", action="store_true",
@@ -2353,13 +2327,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "its overhead share stays bounded; --record "
                          "appends the canary_candidate_p99_ms row with "
                          "verdict attribution columns")
-    lg.add_argument("--kv-smoke", action="store_true",
-                    help="paged-KV serving headline: seeded shared-prefix "
-                         "+ long-prompt workload at fixed offered load vs "
-                         "paged AND monolithic engines (real tiny model); "
-                         "exit 0 iff paged wins p99 + decode goodput "
-                         "share with zero hard failures; --record appends "
-                         "serve_kv_* rows for `slt bench --gate`")
     lg.add_argument("--compact", action="store_true",
                     help="single-line JSON (for scripts)")
     lg.set_defaults(fn=cmd_loadgen)

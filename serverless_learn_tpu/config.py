@@ -413,12 +413,11 @@ class CheckpointConfig:
 
 @dataclass(frozen=True)
 class KVCacheConfig:
-    """Paged KV cache for the serving engines (``inference/kvcache.py``,
-    consumed by ``inference/continuous.py`` and ``inference/batching.py``).
+    """Paged KV cache of the serving engine (``inference/kvcache.py``,
+    consumed by ``inference/continuous.py``).
 
-    ``paged=True`` replaces the per-slot monolithic KV rows with one
-    device-resident block pool per layer (``[num_blocks, block_size, K,
-    D]``), a host-side free-list allocator and per-slot block tables, so a
+    One device-resident block pool per layer (``[num_blocks, block_size,
+    K, D]``), a host-side free-list allocator and per-slot block tables: a
     slot only holds blocks for tokens it has actually produced and
     retirement returns blocks to the free list immediately. On top of the
     pool ride hash-based shared-prefix reuse (``prefix_cache``: identical
@@ -429,7 +428,6 @@ class KVCacheConfig:
     from its own slots unless ``prefill_budget`` caps it).
     """
 
-    paged: bool = True            # False = legacy monolithic KV rows
     block_size: int = 16          # tokens per KV block (page)
     # Total pool blocks per layer. 0 = auto: max_slots * ceil(max_seq_len
     # / block_size) plus one row of slack for the prefix cache — the
@@ -464,8 +462,7 @@ class KVCacheConfig:
 @dataclass(frozen=True)
 class WaterfallConfig:
     """Per-request waterfall ledger knobs (``telemetry/waterfall.py``,
-    threaded through ``inference/continuous.py`` and
-    ``inference/batching.py``).
+    threaded through ``inference/continuous.py``).
 
     A decode gap counts as a STALL when it exceeds the request's EWMA
     inter-token baseline by ``stall_mult``x AND by at least

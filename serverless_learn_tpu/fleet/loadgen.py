@@ -365,246 +365,6 @@ def record_rows(rows: List[dict], history_path: str,
     return rows
 
 
-# -- the paged-KV serving headline -------------------------------------------
-
-
-def shared_prefix_request_factory(rng: random.Random, prefix: List[int],
-                                  long_frac: float = 0.3,
-                                  tail_len: int = 32,
-                                  short_len: int = 8,
-                                  long_max_new: int = 8,
-                                  short_max_new: int = 4,
-                                  vocab: int = 100) -> Callable[[int], dict]:
-    """The round-13 mixed workload: ~``long_frac`` long prompts sharing
-    one system ``prefix`` (the fleet's system-prompt scenario — prefix
-    reuse's bread and butter) interleaved with short interactive
-    requests whose latency is TTFT-dominated. All greedy, so both engine
-    modes are deterministic and comparable. Requests carry ``_class``
-    ("long"/"short") for the caller's per-class latency split; the
-    serving wire ignores unknown keys."""
-    def make(i: int) -> dict:
-        if rng.random() < long_frac:
-            tail = [rng.randrange(1, vocab) for _ in range(tail_len)]
-            return {"prompt": list(prefix) + tail,
-                    "max_new_tokens": long_max_new, "_class": "long"}
-        return {"prompt": [rng.randrange(1, vocab)
-                           for _ in range(short_len)],
-                "max_new_tokens": short_max_new, "_class": "short"}
-    return make
-
-
-def run_kv_smoke(seed: int = 0, rate_rps: float = 10.0,
-                 duration_s: float = 6.0, warmup_s: float = 4.0,
-                 prefix_len: int = 192,
-                 history_path: Optional[str] = None) -> dict:
-    """The paged-KV serving headline, measured not asserted: the SAME
-    seeded long-prompt + shared-system-prompt workload at the SAME
-    offered load against (a) the legacy monolithic continuous engine and
-    (b) the paged engine (block pool + prefix reuse + chunked prefill).
-    Reports p99 latency of the short interactive class (TTFT-dominated —
-    the head-of-line-blocking victim), engine-histogram TTFT p99, the
-    decode-phase goodput share from a per-leg ledger (discounted by
-    decode-row utilization, so the monolithic engine's retired-row burn
-    counts as the waste it is), and tokens/s.
-    ``ok`` iff zero hard failures AND the paged engine beats monolithic
-    on both short-class p99 and decode goodput share. Rows land in
-    bench_history via ``record_rows`` (better=min), gated by
-    ``slt bench --gate --metric serve_kv``."""
-    import jax
-    import jax.numpy as jnp
-
-    from serverless_learn_tpu.config import KVCacheConfig
-    from serverless_learn_tpu.inference.server import GenerationServer
-    from serverless_learn_tpu.models.registry import get_model
-    from serverless_learn_tpu.telemetry import goodput as goodput_mod
-    from serverless_learn_tpu.telemetry.registry import MetricsRegistry
-
-    bundle = get_model("llama_tiny", dtype=jnp.float32,
-                       param_dtype=jnp.float32, max_seq_len=512)
-    module = bundle.module
-    params = module.init(jax.random.PRNGKey(0),
-                         jnp.zeros((1, 8), jnp.int32))["params"]
-    prefix_rng = random.Random(f"kv-prefix-{seed}")
-    prefix = [prefix_rng.randrange(1, 100) for _ in range(prefix_len)]
-
-    def _reg_val(reg, name):
-        fam = reg.snapshot().get(name) or {}
-        return sum(s.get("value", 0) for s in fam.get("series", []))
-
-    def _reg_hist_p99(reg, name):
-        fam = reg.snapshot().get(name) or {}
-        from serverless_learn_tpu.telemetry.registry import (
-            percentile_from_buckets)
-
-        for s in fam.get("series", []):
-            if s.get("count"):
-                return percentile_from_buckets(s["buckets"],
-                                               s["cumulative"], 0.99)
-        return None
-
-    def leg(paged: bool) -> dict:
-        registry = MetricsRegistry()
-        ledger = goodput_mod.PhaseLedger(emit=False)
-        prev = goodput_mod.set_ledger(ledger)
-        kv = KVCacheConfig(paged=paged, block_size=16, prefill_chunk=32)
-        srv = GenerationServer(module, params, engine="continuous",
-                               max_batch=4, chunk_size=8,
-                               registry=registry, kv=kv).start()
-        lat: Dict[str, List[float]] = {"long": [], "short": []}
-        fails: List[str] = []
-        lock = threading.Lock()
-
-        def fire(req, measured):
-            cls = req.pop("_class")
-            t0 = time.monotonic()
-            try:
-                out = _one_request(srv.addr, req, timeout_s=120.0)
-                bad = "error" in out
-            except (OSError, ValueError) as e:
-                out, bad = {"error": str(e)}, True
-            dt = time.monotonic() - t0
-            if not measured:
-                return
-            with lock:
-                if bad:
-                    fails.append(str(out.get("error"))[:200])
-                else:
-                    lat[cls].append(dt)
-
-        def open_loop(dur, seed_sfx, measured):
-            rng = random.Random(f"kv-loadgen-{seed}-{seed_sfx}")
-            make = shared_prefix_request_factory(rng, prefix, tail_len=64)
-            offsets = poisson_arrivals(rate_rps, dur, rng)
-            reqs = [make(i) for i in range(len(offsets))]
-            threads, t0 = [], time.monotonic()
-            for off, req in zip(offsets, reqs):
-                delay = t0 + off - time.monotonic()
-                if delay > 0:
-                    time.sleep(delay)
-                t = threading.Thread(target=fire, args=(req, measured),
-                                     daemon=True)
-                t.start()
-                threads.append(t)
-            for t in threads:
-                t.join(timeout=180.0)
-
-        try:
-            # Deterministic compile warm FIRST: every (nb, T, W) prefill
-            # / (nb, W) decode bucket the workload shapes can touch is
-            # compiled up front (paged), or the admit buckets via the
-            # gated submit warm (monolithic) — a measured window must
-            # pay zero XLA compiles regardless of how Poisson arrivals
-            # happen to batch. The traffic warmup below then covers
-            # steady state: trie population, allocator churn, caches.
-            srv.engine.warm_shapes([(8, 4), (prefix_len + 64, 8)])
-            # Warmup leg: same workload shapes, so the measured window
-            # pays (almost) no XLA compiles; the ledger resets after.
-            open_loop(warmup_s, "warm", measured=False)
-            ledger.reset()
-            eng = srv.engine
-            rows0 = eng.decoded_rows_total
-            disp0 = eng.dispatched_rows_total
-            t0 = time.monotonic()
-            open_loop(duration_s, "run", measured=True)
-            wall = time.monotonic() - t0
-            rep = ledger.report()
-            # Decode goodput share of BUSY time: at fixed offered load a
-            # faster engine spends MORE wall-clock idle, so a
-            # share-of-total would punish the win. Of the time the
-            # engine worked, how much was PRODUCTIVE decode? The decode
-            # phase is discounted by decode-row utilization (rows that
-            # still owed tokens / rows of compute dispatched): the
-            # monolithic engine pays max_slots rows every chunk whether
-            # live or retired, and counting that burn as goodput would
-            # reward exactly the defect the paged pool removes.
-            ph = rep["phases"]
-            decode_s = ph.get("decode", {}).get("seconds", 0.0)
-            idle_s = ph.get("idle", {}).get("seconds", 0.0)
-            busy = max(rep["total_s"] - idle_s, 1e-9)
-            disp = eng.dispatched_rows_total - disp0
-            util = ((eng.decoded_rows_total - rows0) / disp
-                    if disp > 0 else 1.0)
-            decode_share = decode_s * util / busy
-            shorts = sorted(lat["short"])
-            longs = sorted(lat["long"])
-            out = {
-                "paged": paged,
-                "sent": len(shorts) + len(longs) + len(fails),
-                "hard_failures": len(fails),
-                "failure_examples": fails[:3],
-                "short_p99_ms": _ms(percentile(shorts, 0.99)),
-                "short_p50_ms": _ms(percentile(shorts, 0.50)),
-                "long_p99_ms": _ms(percentile(longs, 0.99)),
-                # Engine-histogram TTFT; warmup-INCLUSIVE (histograms
-                # don't reset), so the gated row is the client-measured
-                # short-class p99 over the measured window alone.
-                "engine_ttft_p99_ms_warmup_incl": _ms(_reg_hist_p99(
-                    registry, "slt_request_ttft_seconds")),
-                "decode_goodput_share": round(decode_share, 4),
-                "decode_row_utilization": round(util, 4),
-                "idle_frac": round(idle_s / max(rep["total_s"], 1e-9), 4),
-                "badput_breakdown": rep["badput_breakdown"],
-                "tokens_per_sec": round(
-                    _reg_val(registry, "slt_decode_tokens_total")
-                    / max(wall, 1e-9), 2),
-                "prefill_chunks": getattr(eng, "prefill_chunks_run", 0),
-                "kv": eng.kv_stats() if hasattr(eng, "kv_stats") else None,
-            }
-            return out
-        finally:
-            goodput_mod.set_ledger(prev)
-            srv.stop()
-
-    mono = leg(paged=False)
-    paged = leg(paged=True)
-    improved = (
-        mono["short_p99_ms"] is not None
-        and paged["short_p99_ms"] is not None
-        and paged["short_p99_ms"] < mono["short_p99_ms"]
-        and paged["decode_goodput_share"] > mono["decode_goodput_share"])
-    rep = {
-        "ok": (mono["hard_failures"] == 0 and paged["hard_failures"] == 0
-               and improved),
-        "improved": improved,
-        "offered_rps": rate_rps, "duration_s": duration_s,
-        "prefix_len": prefix_len,
-        "monolithic": mono, "paged": paged,
-    }
-    rows = []
-    for name, point, better in (
-            (f"serve_kv_paged_{rate_rps:g}rps_short_p99_ms", paged, "min"),
-            (f"serve_kv_mono_{rate_rps:g}rps_short_p99_ms", mono, "min")):
-        if point["short_p99_ms"] is None:
-            continue
-        rows.append({
-            "metric": name, "value": point["short_p99_ms"], "unit": "ms",
-            "device_kind": "serve-cpu", "offered_rps": rate_rps,
-            "decode_goodput_share": point["decode_goodput_share"],
-            "tokens_per_sec": point["tokens_per_sec"],
-            "_better": better,
-        })
-    if paged.get("tokens_per_sec"):
-        rows.append({
-            "metric": f"serve_kv_paged_{rate_rps:g}rps_tokens_per_sec",
-            "value": paged["tokens_per_sec"], "unit": "tokens/s",
-            "device_kind": "serve-cpu", "offered_rps": rate_rps,
-            "_better": "max",
-        })
-    rep["bench_rows"] = rows
-    if history_path:
-        from serverless_learn_tpu.utils.benchlog import record
-
-        betters = [row.pop("_better") for row in rows]
-        stamp_bundle(rows, history_path, role="loadgen-kv")
-        for row, better in zip(rows, betters):
-            record(row, history_path, better=better,
-                   key_fields=("metric", "device_kind"))
-    else:
-        for row in rows:
-            row.pop("_better", None)
-    return rep
-
-
 def run_waterfall_smoke(seed: int = 0, events_path: Optional[str] = None,
                         history_path: Optional[str] = None) -> dict:
     """The waterfall acceptance proof (round 21), measured not asserted:
@@ -668,7 +428,7 @@ def run_waterfall_smoke(seed: int = 0, events_path: Optional[str] = None,
     #   hit an unwarmed (nb, W) decode bucket the moment their page
     #   count outgrows the warmed width — while their token gaps are
     #   being traced.
-    kv = KVCacheConfig(paged=True, block_size=16, num_blocks=18,
+    kv = KVCacheConfig(block_size=16, num_blocks=18,
                        prefix_cache=False, prefill_chunk=32)
     eng = ContinuousBatchingEngine(module, params, max_slots=4,
                                    chunk_size=8, registry=registry,

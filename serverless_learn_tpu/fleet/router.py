@@ -96,8 +96,8 @@ class Replica:
         self.requests = 0
         self.errors = 0
         # Paged-KV pressure from the replica's ping reply (round 13):
-        # free-block fraction + prefix hit rate. None until a paged
-        # replica reports them; monolithic replicas never do.
+        # free-block fraction + prefix hit rate. None until the
+        # replica reports them (a stub engine never does).
         self.kv_free_frac: Optional[float] = None
         self.prefix_hit_rate: Optional[float] = None
         # Resident-prefix digest from the ping (round 22): the chain
@@ -459,7 +459,7 @@ class FleetRouter:
 
     def _kv_pressure(self) -> float:
         """Min free KV-block fraction across the eligible set; 1.0 when
-        no replica reports paged-KV stats (monolithic fleets are never
+        no replica reports KV stats (such a fleet is never
         memory-shed)."""
         now = self.clock()
         with self._lock:

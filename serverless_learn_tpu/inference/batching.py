@@ -1,59 +1,18 @@
-"""Batched serving: an admission queue that coalesces concurrent requests.
+"""Shape buckets of the serving engine.
 
-Round-3 verdict #2: a generation *server* exists to batch — serializing N
-clients gives each 1/N of the chip. This engine is the missing middle layer
-between the socket threads and ``generate``:
-
-* Connection threads ``submit()`` requests and block on a per-request event.
-* One dispatcher thread drains the admission queue, coalesces compatible
-  requests (same sampling params), right-pads their prompts to a shared
-  bucketed shape, and runs ONE batched prefill+decode for the group.
-* Unequal prompt lengths are handled exactly, not approximately: prompts
-  right-pad to the bucket and ``generate(prompt_lengths=...)`` gives every
-  sequence its own cache index (``models/transformer.py`` keeps
-  ``cache_index`` as a [B] vector), so each request's continuation is
-  byte-identical to what a solo call would produce (greedy; sampled
-  requests share the batch PRNG — see below).
-
-Static bucketing bounds the jit-cache: prompt lengths round up to powers of
-two, batch sizes round up to powers of two (shorter/missing rows are
-padding the caller discards), and ``max_new_tokens`` rounds up to a power
-of two (extra tokens are generated then truncated — bounded at <2x decode
-work, amortized by the batching win). Each (batch_bucket, prompt_bucket,
-new_bucket, sampling params) tuple compiles once and is reused forever.
-
-Sampling reproducibility: sampled (temperature > 0) requests key their
-group on ``seed`` too, so a client's requested seed is never silently
-replaced by a batch-mate's. The draws still flow from ONE stream shaped
-by the padded batch, so a sampled request's tokens can vary with batch
-composition. Greedy requests (temperature=0, the default) ignore the
-PRNG entirely and are exact and batch-invariant. Callers that need
-bit-reproducible sampling should serialize themselves.
-
-The reference has no inference at all (its "model" is a gossiped double
-vector, ``/root/reference/src/protos/serverless_learn.proto:81-83``); this
-surface is judged against the matching-or-beating bar alone.
+``_bucket`` rounds a batch size or a token count up to a power of two, so
+that the jit cache holds one program per bucket and not one per value;
+``PROMPT_BUCKETS`` are the edges of the prompt-length histogram. Their one
+user in the package is ``inference/continuous.py``. They live in a module of
+their own, under this name, because the on-chip benchmark imports ``_bucket``
+from here (``chipbench/arch/dense_gqa.py``) to warm the shapes the engine
+will ask for, and a PR that edits the engine may not edit the benchmark
+(ROADMAP D2 moves them once the engine warms a traffic mix by itself).
 """
 
 from __future__ import annotations
 
-import queue
-import threading
-import time
-from dataclasses import dataclass, field
-from typing import List, Optional
-
-import jax
-import jax.numpy as jnp
-import numpy as np
-
 from serverless_learn_tpu.analysis import jitcheck
-from serverless_learn_tpu.inference.generate import generate, init_cache
-from serverless_learn_tpu.telemetry import (RATE_BUCKETS, SIZE_BUCKETS,
-                                            Span, get_registry, goodput)
-from serverless_learn_tpu.telemetry import flight
-from serverless_learn_tpu.telemetry.tracing import node_name
-from serverless_learn_tpu.telemetry.waterfall import RequestWaterfall
 
 
 @jitcheck.bucket
@@ -67,329 +26,3 @@ def _bucket(n: int, floor: int = 8) -> int:
 # Prompt-length histogram buckets (slt_request_prompt_tokens): prompts
 # span tokens-to-books, unlike the batch-size-shaped SIZE_BUCKETS.
 PROMPT_BUCKETS = (1, 4, 16, 64, 256, 1024, 4096, 16384)
-
-
-@dataclass
-class _Pending:
-    prompt: np.ndarray  # compact int32 array, built ONCE at submit()
-    max_new: int
-    temperature: float
-    top_k: int
-    eos_id: Optional[int]
-    seed: int
-    done: threading.Event = field(default_factory=threading.Event)
-    result: Optional[dict] = None
-    group_key: tuple = ()  # set by the engine (includes padded shapes)
-    span: Optional[Span] = None  # request trace: submit/admit/done
-    wf: Optional[RequestWaterfall] = None  # round-21 reduced ledger
-
-
-def _shape_buckets(prompt_len: int, max_new: int,
-                   max_seq_len: int) -> tuple:
-    """(prompt_bucket, new_bucket) with prompt_bucket >= prompt_len,
-    new_bucket >= max_new, and their sum <= max_seq_len — power-of-two
-    padding must never push a request past the model window a solo call
-    would have satisfied (the server validates prompt_len + max_new <=
-    max_seq_len per request, which guarantees feasibility here)."""
-    nb = _bucket(max_new, floor=1)
-    pb = _bucket(prompt_len)
-    if pb + nb > max_seq_len:
-        pb = max_seq_len - nb
-        if pb < prompt_len:
-            pb = prompt_len
-            nb = min(nb, max_seq_len - pb)
-    return pb, nb
-
-
-class BatchingEngine:
-    """Owns the device; coalesces submitted requests into batched decodes."""
-
-    def __init__(self, module, params, max_batch: int = 8,
-                 batch_wait_ms: float = 3.0, registry=None, kv=None,
-                 event_log=None, waterfall=None):
-        self.module = module
-        self.params = params
-        self.max_batch = max_batch
-        self.batch_wait_s = batch_wait_ms / 1e3
-        self._q: queue.Queue = queue.Queue()
-        self._stop = threading.Event()
-        # Paged KV (round 13): the static engine shares the pool
-        # abstraction — each group runs against a per-group paged cache
-        # with a dense row-major block table (no cross-group sharing;
-        # groups are transient). Mostly an equivalence surface: the
-        # continuous engine is where the free list / prefix trie earn
-        # their keep.
-        self.kv = kv
-        self._paged = bool(kv is not None and kv.paged)
-        self._paged_modules: dict = {}
-        # Round 21: this engine emits request spans too (it never did
-        # before — only the continuous engine's showed up in `slt
-        # trace`), each carrying a REDUCED waterfall: run-to-completion
-        # groups have no decode trace, so the ledger is queue/admit/
-        # compile/generate with TTFT == latency by construction.
-        self.event_log = event_log
-        if waterfall is None:
-            from serverless_learn_tpu.config import WaterfallConfig
-            waterfall = WaterfallConfig()
-        self.waterfall = waterfall
-        reg = registry or get_registry()
-        self.registry = reg
-        lbl = {"engine": "static"}
-        self._m_requests = reg.counter(
-            "slt_requests_total", "requests accepted by the engine", **lbl)
-        self._m_finished = reg.counter("slt_requests_finished_total", **lbl)
-        self._m_tokens = reg.counter(
-            "slt_decode_tokens_total", "tokens returned to callers", **lbl)
-        self._m_qwait = reg.histogram(
-            "slt_request_queue_wait_seconds",
-            "submit -> batched dispatch", **lbl)
-        # This engine runs each group to completion, so first token and
-        # last token reach the host together: TTFT == latency here by
-        # construction (the continuous engine is where they part ways).
-        self._m_ttft = reg.histogram(
-            "slt_request_ttft_seconds", "submit -> first token on host",
-            **lbl)
-        self._m_latency = reg.histogram(
-            "slt_request_latency_seconds", "submit -> final token", **lbl)
-        self._m_admit_sz = reg.histogram(
-            "slt_admit_batch_size", "requests per coalesced group",
-            buckets=SIZE_BUCKETS, **lbl)
-        self._m_tps = reg.histogram(
-            "slt_request_tokens_per_sec", buckets=RATE_BUCKETS, **lbl)
-        self._m_prompt_tokens = reg.histogram(
-            "slt_request_prompt_tokens",
-            "prompt length per accepted request", buckets=PROMPT_BUCKETS,
-            **lbl)
-        # Dispatcher liveness stamp (see the continuous engine): the
-        # health engine reads this beside the chunk/batch counters.
-        self._m_activity = reg.gauge(
-            "slt_engine_last_activity_unix_s",
-            "wall time of the dispatcher's last group dispatch", **lbl)
-        # Goodput: group shapes seen before — a fresh one pays the XLA
-        # compile, charged to "compile" rather than "decode".
-        self._compiled_groups: set = set()
-        self._thread = threading.Thread(target=self._dispatch_loop,
-                                        daemon=True)
-        self._thread.start()
-        self.batches_run = 0
-        self.requests_batched = 0
-
-    # -- client side -------------------------------------------------------
-
-    def submit(self, prompt: List[int], max_new: int, temperature: float,
-               top_k: int, eos_id: Optional[int], seed: int,
-               timeout_s: float = 600.0, trace=None) -> dict:
-        """Blocks until the dispatcher serves this request; returns either
-        {"new_tokens": [...]} or {"error": ...}."""
-        max_seq = self.module.cfg.max_seq_len
-        if len(prompt) == 0:
-            # An empty prompt would make prompt_lengths-1 == -1, which
-            # take_along_axis clamps to index 0 — garbage tokens from an
-            # all-pad row rather than an error.
-            return {"error": "prompt must contain at least one token"}
-        if len(prompt) + max_new > max_seq:
-            # Validate HERE, not only in the server: _shape_buckets would
-            # otherwise clamp new_bucket and silently return fewer tokens
-            # than asked to direct engine callers.
-            return {"error": f"prompt ({len(prompt)}) + max_new_tokens "
-                             f"({max_new}) exceeds max_seq_len {max_seq}"}
-        # ONE compact array per request, built here and never re-copied.
-        p = _Pending(prompt=np.asarray(prompt, np.int32), max_new=max_new,
-                     temperature=temperature, top_k=top_k, eos_id=eos_id,
-                     seed=seed)
-        self._m_prompt_tokens.observe(len(prompt))
-        # Compatible requests share sampling params and padded shapes.
-        # Sampled requests additionally key on seed: a coalesced batch
-        # draws one PRNG stream seeded by the group's FIRST request, so
-        # grouping different seeds would silently discard the others'.
-        # Greedy (temperature=0) ignores the PRNG and groups freely.
-        p.group_key = (temperature, top_k, eos_id,
-                       seed if temperature > 0 else None,
-                       _shape_buckets(len(prompt), max_new, max_seq))
-        p.span = (Span("request", trace_id=trace.trace_id,
-                       parent_id=trace.span_id)
-                  if trace is not None else Span("request"))
-        if self.waterfall.enabled:
-            p.wf = RequestWaterfall(engine="static")
-        self._m_requests.inc()
-        self._q.put(p)
-        if not p.done.wait(timeout_s):
-            return {"error": "generation timed out in the admission queue"}
-        return p.result
-
-    # -- dispatcher --------------------------------------------------------
-
-    def _emit_span(self, span) -> None:
-        """Span record -> the JSONL event log + flight ring (same sink
-        discipline as the continuous engine, so `slt waterfall` merges
-        both engines' records from the same files)."""
-        rec = span.to_event()
-        rec.setdefault("node", node_name())
-        if self.event_log is not None:
-            self.event_log.emit(rec)
-        flight.record(rec)
-
-    def _dispatch_loop(self):
-        while not self._stop.is_set():
-            try:
-                with goodput.phase("idle"):
-                    first = self._q.get(timeout=0.1)
-            except queue.Empty:
-                continue
-            group = [first]
-            extras: List[_Pending] = []
-            deadline = time.perf_counter() + self.batch_wait_s
-            # Admission window: wait briefly for co-batchable requests —
-            # the latency cost is bounded by batch_wait_ms; the win is the
-            # whole point of a server. On the ledger it is "admit_wait"
-            # badput (deliberate, bounded — but accounted).
-            with goodput.phase("admit_wait"):
-                while len(group) < self.max_batch:
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0:
-                        break
-                    try:
-                        nxt = self._q.get(timeout=remaining)
-                    except queue.Empty:
-                        break
-                    if nxt.group_key == first.group_key:
-                        group.append(nxt)
-                    else:
-                        extras.append(nxt)
-            for e in extras:  # mismatched keys go back for the next round
-                self._q.put(e)
-            try:
-                self._m_activity.set(time.time())
-                self._run_group(group)
-            except Exception as ex:
-                for p in group:
-                    p.result = {"error": f"{type(ex).__name__}: {ex}"}
-                    p.done.set()
-
-    def _run_group(self, group: List[_Pending]):
-        first = group[0]
-        # The shared key guarantees every member's prompt fits the prompt
-        # bucket and its max_new fits the new bucket (see _shape_buckets).
-        prompt_bucket, new_bucket = first.group_key[-1]
-        n = len(group)
-        batch_bucket = 1
-        while batch_bucket < n:
-            batch_bucket *= 2
-        batch_bucket = min(batch_bucket, self.max_batch)
-
-        prompts = np.zeros((batch_bucket, prompt_bucket), np.int32)
-        lengths = np.ones((batch_bucket,), np.int32)  # pad rows: len 1
-        self._m_admit_sz.observe(n)
-        for i, p in enumerate(group):
-            prompts[i, :len(p.prompt)] = p.prompt
-            lengths[i] = len(p.prompt)
-            if p.span is not None:
-                p.span.mark("admit")
-                wait = p.span.between(None, "admit")
-                if wait is not None:
-                    self._m_qwait.observe(wait)
-        # Pad rows replicate row 0 so they can't inject out-of-range ids.
-        for i in range(n, batch_bucket):
-            prompts[i] = prompts[0]
-            lengths[i] = lengths[0]
-
-        shape_key = (batch_bucket, prompt_bucket, new_bucket,
-                     first.temperature > 0, first.top_k > 0,
-                     first.eos_id is not None)
-        new_shape = shape_key not in self._compiled_groups
-        self._compiled_groups.add(shape_key)
-        module, cache = self.module, None
-        if self._paged:
-            module, cache = self._paged_group(batch_bucket)
-        t_g0 = time.perf_counter()
-        with goodput.phase("compile" if new_shape else "decode"):
-            tokens = generate(
-                module, self.params, jnp.asarray(prompts), new_bucket,
-                temperature=first.temperature, top_k=first.top_k,
-                eos_id=first.eos_id, rng=jax.random.PRNGKey(first.seed),
-                prompt_lengths=jnp.asarray(lengths), cache=cache)
-            new = np.asarray(jax.device_get(tokens))[:, prompt_bucket:]
-        t_g1 = time.perf_counter()
-        self.batches_run += 1
-        self.requests_batched += n
-        for i, p in enumerate(group):
-            p.result = {"new_tokens": [int(t) for t in new[i, :p.max_new]],
-                        "batch_size": n}
-            self._m_finished.inc()
-            self._m_tokens.inc(p.max_new)
-            if p.span is not None:
-                p.span.mark("first_token")
-                p.span.mark("done")
-                lat = p.span.between(None, "done")
-                if lat is not None:
-                    self._m_ttft.observe(lat)
-                    self._m_latency.observe(lat)
-                    if lat > 0:
-                        self._m_tps.observe(p.max_new / lat)
-                if p.wf is not None:
-                    # Reduced static ledger: a cold group charges the
-                    # whole generate wall to "compile" (the jit is not
-                    # separable from the run here); warm groups show it
-                    # as the "generate" phase. No decode trace — tokens
-                    # land together, TTFT == latency by construction.
-                    if new_shape:
-                        p.wf.note_compile(t_g0, t_g1)
-                    p.span.meta["waterfall"] = p.wf.finalize(p.span)
-                p.span.meta["max_new"] = p.max_new
-                p.span.meta["batch_size"] = n
-                self._emit_span(p.span)
-            p.done.set()
-
-    def _paged_group(self, batch_bucket: int):
-        """(paged twin module, fresh cache) for one group: a dense
-        row-major block table over an exact-fit pool — the shared paged
-        abstraction (``inference/kvcache.py``) without cross-group
-        sharing. Token-identical to the monolithic cache (pinned by
-        tests/test_kvcache.py)."""
-        from serverless_learn_tpu.inference import kvcache
-
-        ps = self.kv.block_size
-        max_pages = kvcache.pages_for(self.module.cfg.max_seq_len, ps)
-        pm = self._paged_modules.get(batch_bucket)
-        if pm is None:
-            pm = kvcache.paged_module(self.module, ps,
-                                      batch_bucket * max_pages)
-            self._paged_modules[batch_bucket] = pm
-        cache = init_cache(pm, batch_bucket)
-        tbl = jnp.asarray(kvcache.sequential_table(
-            batch_bucket, max_pages, pm.cfg.kv_pages))
-        return pm, kvcache.with_tables(
-            cache, tbl, jnp.zeros((batch_bucket,), jnp.int32))
-
-    def warm(self, prompt_len: int, max_new: int, temperature: float = 0.0,
-             top_k: int = 0, eos_id: Optional[int] = None,
-             batch_sizes=(1,)):
-        """Pre-compile the decode buckets a known workload will hit, by
-        running synthetic groups straight through ``_run_group`` (bypassing
-        the queue — call only while no live submissions are in flight).
-        Benchmarks use this so a timed window never pays an XLA compile
-        for a batch bucket the warm traffic happened not to form."""
-        for n in batch_sizes:
-            group = []
-            for _ in range(n):
-                p = _Pending(prompt=np.full((prompt_len,), 1, np.int32),
-                             max_new=max_new, temperature=temperature,
-                             top_k=top_k, eos_id=eos_id, seed=0)
-                p.group_key = (temperature, top_k, eos_id,
-                               0 if temperature > 0 else None,
-                               _shape_buckets(prompt_len, max_new,
-                                              self.module.cfg.max_seq_len))
-                group.append(p)
-            self._run_group(group)
-
-    def stop(self):
-        self._stop.set()
-        self._thread.join(timeout=30.0)
-        # Fail any stragglers rather than leaving submitters blocked.
-        try:
-            while True:
-                p = self._q.get_nowait()
-                p.result = {"error": "server shutting down"}
-                p.done.set()
-        except queue.Empty:
-            pass

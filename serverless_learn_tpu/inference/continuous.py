@@ -1,25 +1,21 @@
 """Continuous batching: a slot-level decode scheduler over a paged KV pool.
 
-Round-5 verdict #2: the round-4 ``BatchingEngine`` coalesces an admission
-window and then runs the group to completion — an early-EOS sequence burns
-its decode slot to the end of the group, a request arriving one tick after
-dispatch waits out the whole group, a long request head-of-line-blocks its
-bucket, and a steady stream of compatible traffic can starve a mismatched
-request behind new arrivals. This engine replaces run-to-completion groups
-with a persistent decode loop over ``max_slots`` KV-cache slots.
+A persistent decode loop over ``max_slots`` KV-cache slots: requests are
+admitted at chunk boundaries, FIFO, and leave one by one — an early-EOS
+sequence does not hold its slot to the end of a group, a late arrival does
+not wait out a group, and nothing starves behind a mismatched neighbour.
 
-Round 13 replaced the slots' ONE monolithic resident KV allocation
-(``max_slots`` full-length ``[slot, max_seq_len, K, D]`` rows) with a
-**paged KV pool** (``inference/kvcache.py``, ``KVCacheConfig``):
+The slots' keys and values live in a **paged KV pool**
+(``inference/kvcache.py``, ``KVCacheConfig``; ``kv=None`` means
+``KVCacheConfig()``):
 
 * Each layer owns a block pool ``pages_k/v [num_blocks, block_size, K,
   D]``; a host-side free-list allocator hands pages to slots through
   per-slot block tables, so a slot only holds pages for tokens it has
   actually produced and retirement returns them immediately. Decode runs
   over a COMPACTED live batch with a bucketed table window ``W`` —
-  retired slots stop burning FLOPs and short sequences stop attending
-  over ``max_seq_len`` (both were the documented SPMD cost of the
-  monolithic layout).
+  retired slots burn no FLOPs and short sequences do not attend over
+  ``max_seq_len``.
 * **Shared-prefix reuse** (``prefix_cache``): full prompt blocks are
   published to a token-keyed trie after prefill; an identical later
   prefix (the fleet's system prompts) adopts the refcounted read-only
@@ -40,10 +36,6 @@ Round 13 replaced the slots' ONE monolithic resident KV allocation
   preempts the youngest slot (restart is token-identical — the per-slot
   ``fold_in(seed, position)`` streams are position-based).
 
-The legacy monolithic layout (``KVCacheConfig(paged=False)`` or
-``kv=None``) is kept as the equivalence baseline; the paged path is pinned
-token-identical to it (greedy + seeded) by ``tests/test_kvcache.py``.
-
 TPU shape discipline: decode runs in jitted CHUNKS — a ``lax.scan`` of
 ``chunk_size`` single-token steps — because XLA wants static shapes and a
 per-token host round trip would idle the device between steps. Host
@@ -52,7 +44,7 @@ control returns only once per chunk, and the dispatcher keeps
 (32 steps, depth 2) were sized against a ~100 ms host round trip that a
 locally attached chip does not have; ROADMAP S7 re-derives them from the
 measured step time. A chunk's tokens reach the host at its HARVEST, one
-to two chunks after its dispatch, so a paged slot is not held until its
+to two chunks after its dispatch, so a slot is not held until its
 request's last token is seen: it is **released at the dispatch that
 exhausts the request's budget** (prefill yields the first token and each
 chunk ``chunk_size`` more, so after ``ceil((max_new - 1) / chunk_size)``
@@ -60,10 +52,10 @@ chunks no further chunk can add a token to the reply, EOS or not). The
 request lives on in its futures' snapshots until harvest answers it; the
 slot and its pages go to a successor, whose prompt is prefilled behind
 the released row's last chunk and joins the next one. Only a reply that
-ends by EOS before its budget is found at harvest. Paged compile keys
-are (live-batch bucket, table-window bucket) for decode and (batch,
-chunk, window) buckets for prefill — the round-5 admit-bucket
-warm-compile machinery extended to paged shapes.
+ends by EOS before its budget is found at harvest. Compile keys are
+(live-batch bucket, table-window bucket) for decode and (batch, chunk,
+window) buckets for prefill; ``warm_shapes()`` compiles them ahead of
+traffic.
 In-order device execution makes page recycling safe: the pool and the
 slot vectors are threaded through every program, and every in-flight
 chunk that can still read or write a retired slot's pages was dispatched
@@ -71,18 +63,16 @@ before the release or harvest that freed them, so it executes before any
 later prefill that reuses them.
 
 Per-slot sampling state (temperature, top_k, EOS id, PRNG seed) rides in
-[max_slots] device arrays, so a batch can mix greedy and sampled traffic —
-the static engine had to segregate them into separate groups. Sampled
-slots draw from ``fold_in(PRNGKey(seed), position)``: every token's
-randomness depends only on the request's own seed and position, so
-sampled output is REPRODUCIBLE and BATCH-INVARIANT (stronger than the
-static engine, whose group shape shaped the draws — its documented
-caveat). The stream differs from solo ``generate()``'s ``split``-based
-stream; greedy output is byte-identical to solo (pinned by
-``tests/test_continuous.py``). Per-slot top_k is implemented against a
-static ``max_top_k`` bound (``lax.top_k`` needs a static k; the k-th
-threshold is then gathered per row), so requests may use any
-``top_k <= max_top_k`` — larger values error at submit.
+[max_slots] device arrays, so a batch can mix greedy and sampled traffic.
+Sampled slots draw from ``fold_in(PRNGKey(seed), position)``: every
+token's randomness depends only on the request's own seed and position,
+so sampled output is REPRODUCIBLE and BATCH-INVARIANT. The stream differs
+from solo ``generate()``'s ``split``-based stream; greedy output is
+byte-identical to solo (pinned by ``tests/test_continuous.py``). Per-slot
+top_k is implemented against a static ``max_top_k`` bound (``lax.top_k``
+needs a static k; the k-th threshold is then gathered per row), so
+requests may use any ``top_k <= max_top_k`` — larger values error at
+submit.
 
 The reference has no inference path at all (its "model" is a gossiped
 double vector, ``/root/reference/src/protos/serverless_learn.proto:81-83``);
@@ -136,8 +126,7 @@ def _wbucket(n: int) -> int:
 # shape bucket, so each jit OBJECT compiles exactly once — a second
 # compile means a key leaked past its cache (or a bucket function was
 # bypassed) and fails the session with the triggering stack.
-for _site in ("_build_chunk", "_admit_jit", "_paged_prefill_jit",
-              "_paged_chunk_jit"):
+for _site in ("_paged_prefill_jit", "_paged_chunk_jit"):
     jitcheck.declare_budget(
         f"serverless_learn_tpu/inference/continuous.py:{_site}",
         max_compiles_per_jit=1)
@@ -195,7 +184,7 @@ class _Request:
     span: Optional[Span] = None  # request trace: submit/admit/first/done
     wf: Optional[RequestWaterfall] = None  # round-21 lifecycle ledger
     preempt_t: float = 0.0  # perf_counter at preemption (0 = not preempted)
-    # ---- paged-mode scheduling state ----
+    # ---- scheduling state ----
     prefilling: bool = False   # mid chunked prefill (not yet decodable)
     prefill_pos: int = 0       # prompt tokens written (incl. shared prefix)
     # Decode chunks launched for this residency. The slot is released at
@@ -233,50 +222,45 @@ class ContinuousBatchingEngine:
         # Host-side slot table: index -> live _Request (None = free).
         self._slots: List[Optional[_Request]] = [None] * max_slots
 
-        # ---- paged KV pool (round 13) ----
+        # ---- paged KV pool ----
+        if kv is None:
+            kv = KVCacheConfig()
         self.kv = kv
-        self._paged = bool(kv is not None and kv.paged)
         max_seq = module.cfg.max_seq_len
-        if self._paged:
-            ps = kv.block_size
-            self._ps = ps
-            self._max_pages = pages_for(max_seq, ps)
-            num_blocks = kv.num_blocks or (
-                max_slots * self._max_pages
-                + (self._max_pages if kv.prefix_cache else 0))
-            if num_blocks < self._max_pages:
-                raise ValueError(
-                    f"kv.num_blocks ({num_blocks}) cannot hold one "
-                    f"max-length sequence ({self._max_pages} blocks of "
-                    f"{ps}); the engine could deadlock")
-            self._pool = BlockPool(num_blocks, ps)
-            self._trie = (PrefixTrie(
-                self._pool,
-                max_blocks=kv.prefix_cache_blocks or num_blocks // 4,
-                hit_window=kv.prefix_hit_window)
-                if kv.prefix_cache else None)
-            self._pmod = kvcache.paged_module(module, ps, num_blocks)
-            self.prefill_chunk = kv.prefill_chunk or max_seq
-            # 0 = derived per iteration (``_prefill_steps``). An explicit
-            # cap is at least one chunk: the oldest row always advances.
-            self.prefill_budget = (max(kv.prefill_budget,
-                                       self.prefill_chunk)
-                                   if kv.prefill_budget > 0 else 0)
-            # Host-owned block tables: [max_slots, max_pages] page ids,
-            # sentinel (== num_blocks) marking unallocated entries.
-            self._tbl = np.full((max_slots, self._max_pages),
-                                self._pool.sentinel, np.int32)
-            self._slot_pages: List[List[int]] = [[] for _ in
-                                                 range(max_slots)]
-            self._pending_cow: Dict[int, tuple] = {}
-            self._prefill_jits: Dict[tuple, object] = {}
-            self._chunk_jits: Dict[tuple, object] = {}
-            self._kv_alert_firing = False
-            self._last_kv_alert = 0.0
+        ps = kv.block_size
+        self._ps = ps
+        self._max_pages = pages_for(max_seq, ps)
+        num_blocks = kv.num_blocks or (
+            max_slots * self._max_pages
+            + (self._max_pages if kv.prefix_cache else 0))
+        if num_blocks < self._max_pages:
+            raise ValueError(
+                f"kv.num_blocks ({num_blocks}) cannot hold one "
+                f"max-length sequence ({self._max_pages} blocks of "
+                f"{ps}); the engine could deadlock")
+        self._pool = BlockPool(num_blocks, ps)
+        self._trie = (PrefixTrie(
+            self._pool,
+            max_blocks=kv.prefix_cache_blocks or num_blocks // 4,
+            hit_window=kv.prefix_hit_window)
+            if kv.prefix_cache else None)
+        self._pmod = kvcache.paged_module(module, ps, num_blocks)
+        self.prefill_chunk = kv.prefill_chunk or max_seq
+        # 0 = derived per iteration (``_prefill_steps``). An explicit
+        # cap is at least one chunk: the oldest row always advances.
+        self.prefill_budget = (max(kv.prefill_budget, self.prefill_chunk)
+                               if kv.prefill_budget > 0 else 0)
+        # Host-owned block tables: [max_slots, max_pages] page ids,
+        # sentinel (== num_blocks) marking unallocated entries.
+        self._tbl = np.full((max_slots, self._max_pages),
+                            self._pool.sentinel, np.int32)
+        self._slot_pages: List[List[int]] = [[] for _ in range(max_slots)]
+        self._pending_cow: Dict[int, tuple] = {}
+        self._prefill_jits: Dict[tuple, object] = {}
+        self._chunk_jits: Dict[tuple, object] = {}
+        self._kv_alert_firing = False
+        self._last_kv_alert = 0.0
         self._state = self._init_state()
-        if not self._paged:
-            self._chunk_jit = self._build_chunk()
-        self._admit_jits: Dict[tuple, object] = {}
         self.chunks_run = 0
         self.requests_finished = 0
         self.requests_cancelled = 0
@@ -290,22 +274,16 @@ class ContinuousBatchingEngine:
         self.harvest_wait_s_total = 0.0
         # Decode row accounting: ``decoded_rows_total`` counts rows that
         # still owed tokens at dispatch; ``dispatched_rows_total`` counts
-        # rows of compute actually paid (paged: the compacted nb bucket;
-        # monolithic: ALL max_slots rows, every chunk — the retired-row
-        # burn). Their ratio is the decode-row utilization the serving
-        # bench discounts decode goodput by.
+        # rows of compute actually paid (the compacted nb bucket). Their
+        # ratio is the decode-row utilization.
         self.decoded_rows_total = 0
         self.dispatched_rows_total = 0
-        # Paged slots released at the dispatch that exhausted their
+        # Slots released at the dispatch that exhausted their
         # request's budget (the rest are retired at harvest: EOS before
         # the budget, a cancelled submitter).
         self.slots_released_total = 0
         self.preemptions = 0
         self._admit_counter = 0
-        # warm() raises this so a known batch size admits as ONE bucket
-        # (compiling deterministically) instead of splitting on thread
-        # arrival timing; 1 in normal service.
-        self._min_admit = 1
         self.event_log = event_log
         # ---- per-request waterfall ledger (round 21) ----
         self.waterfall = waterfall if waterfall is not None \
@@ -350,7 +328,7 @@ class ContinuousBatchingEngine:
             "slt_request_prompt_tokens",
             "prompt length per accepted request (the prefix-hit-rate "
             "denominator)", buckets=PROMPT_BUCKETS, **lbl)
-        # Paged-KV telemetry (zero/static in monolithic mode).
+        # KV pool telemetry.
         self._m_kv_total = reg.gauge(
             "slt_kv_blocks_total", "KV pool size in blocks", **lbl)
         self._m_kv_in_use = reg.gauge(
@@ -372,9 +350,8 @@ class ContinuousBatchingEngine:
             "slt_kv_preemptions_total",
             "slots preempted to free KV blocks (deterministic restart)",
             **lbl)
-        if self._paged:
-            self._m_kv_total.set(self._pool.num_blocks)
-            self._m_kv_in_use.set(0)
+        self._m_kv_total.set(self._pool.num_blocks)
+        self._m_kv_in_use.set(0)
         # Waterfall-fed serving attribution (round 21): harvest-granular
         # inter-token latency, plus the prefill-interference share of
         # decode wall-clock (chunked prefill's documented cost, finally
@@ -454,90 +431,12 @@ class ContinuousBatchingEngine:
             "topk": jnp.zeros((B,), jnp.int32),
             "eos": jnp.full((B,), -1, jnp.int32),
             "seed": jnp.zeros((B,), jnp.uint32),
+            "ci": jnp.zeros((B,), jnp.int32),     # absolute cache index
         }
-        if self._paged:
-            pages, _ = kvcache.split_cache(init_cache(self._pmod, B))
-            vecs["ci"] = jnp.zeros((B,), jnp.int32)  # absolute cache index
-            return {"pages": pages, "vecs": vecs}
-        return {"cache": init_cache(self.module, B), **vecs}
+        pages, _ = kvcache.split_cache(init_cache(self._pmod, B))
+        return {"pages": pages, "vecs": vecs}
 
-    def _build_chunk(self):
-        module, C, ktop = self.module, self.chunk_size, self.max_top_k
-
-        def chunk(params, st):
-            def step(carry, _):
-                cache, tok, pos, done = carry
-                logits, upd = module.apply(
-                    {"params": params, "cache": cache}, tok[:, None],
-                    decode=True, mutable=["cache"])
-                cache = upd["cache"]
-                nxt = _sample_slots(logits[:, 0], st["temp"], st["topk"],
-                                    st["seed"], pos, ktop)
-                # EOS contract (matches generate): finished slots keep
-                # emitting their EOS id (or 0 when the request had none).
-                keep = jnp.maximum(st["eos"], 0)
-                nxt = jnp.where(done, keep, nxt)
-                done = done | ((st["eos"] >= 0) & (nxt == st["eos"]))
-                return (cache, nxt, pos + 1, done), nxt
-
-            (cache, tok, pos, done), toks = jax.lax.scan(
-                step, (st["cache"], st["next_tok"], st["pos"], st["done"]),
-                None, length=C)
-            out = dict(st, cache=cache, next_tok=tok, pos=pos, done=done)
-            return out, jnp.swapaxes(toks, 0, 1)  # [B, C]
-
-        # Donate the state: the cache is the engine's dominant allocation
-        # and each chunk consumes its predecessor's.
-        return jax.jit(chunk, donate_argnums=(1,))
-
-    def _admit_jit(self, nb: int, pb: int):
-        """Compiled admit for (new-batch bucket, prompt bucket): batched
-        prefill of the new prompts in a compacted [nb, pb] shape, sample
-        each row's FIRST token from its own last-real-position logits,
-        then scatter cache rows + slot arrays into the big state at
-        ``slot_ids`` (padded ids >= max_slots drop). Monolithic mode
-        only — the paged path admits through ``_prefill_step``."""
-        key = (nb, pb)
-        if key in self._admit_jits:
-            return self._admit_jits[key]
-        module, ktop = self.module, self.max_top_k
-        small_shapes = jax.eval_shape(lambda: init_cache(module, nb))
-
-        def admit(params, st, prompts, lengths, slot_ids, temp, topk, eos,
-                  seed):
-            small = jax.tree_util.tree_map(
-                lambda s: jnp.zeros(s.shape, s.dtype), small_shapes)
-            logits, upd = module.apply(
-                {"params": params, "cache": small}, prompts,
-                prefill=True, mutable=["cache"], seq_lengths=lengths)
-            small = upd["cache"]
-            last = jnp.take_along_axis(
-                logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-            tok0 = _sample_slots(last, temp, topk, seed,
-                                 jnp.zeros((nb,), jnp.int32), ktop)
-            done0 = (eos >= 0) & (tok0 == eos)
-
-            def put(big, new):
-                return big.at[slot_ids].set(new, mode="drop")
-
-            out = dict(
-                st,
-                cache=jax.tree_util.tree_map(put, st["cache"], small),
-                next_tok=put(st["next_tok"], tok0),
-                pos=put(st["pos"], jnp.ones((nb,), jnp.int32)),
-                done=put(st["done"], done0),
-                temp=put(st["temp"], temp),
-                topk=put(st["topk"], topk),
-                eos=put(st["eos"], eos),
-                seed=put(st["seed"], seed),
-            )
-            return out, tok0
-
-        fn = jax.jit(admit, donate_argnums=(1,))
-        self._admit_jits[key] = fn
-        return fn
-
-    # -- paged jits --------------------------------------------------------
+    # -- compiled programs -------------------------------------------------
 
     def _paged_prefill_jit(self, nb: int, T: int, W: int):
         """Compiled prefill chunk for (batch, chunk, table-window)
@@ -626,6 +525,8 @@ class ContinuousBatchingEngine:
                 pages, ci = kvcache.split_cache(upd["cache"])
                 nxt = _sample_slots(logits[:, 0], temp, topk, seed, pos,
                                     ktop)
+                # EOS contract (matches generate): finished rows keep
+                # emitting their EOS id (or 0 when the request had none).
                 keep = jnp.maximum(eos, 0)
                 nxt = jnp.where(done, keep, nxt)
                 done = done | ((eos >= 0) & (nxt == eos))
@@ -644,6 +545,8 @@ class ContinuousBatchingEngine:
                        ci=put(vecs["ci"], ci))
             return pages, out, jnp.swapaxes(toks, 0, 1)  # [nb, C]
 
+        # Donate the state: the pool is the engine's dominant allocation
+        # and each chunk consumes its predecessor's.
         fn = jax.jit(chunk, donate_argnums=(1, 2))
         self._chunk_jits[key] = fn
         return fn
@@ -655,8 +558,7 @@ class ContinuousBatchingEngine:
                timeout_s: float = 600.0,
                trace: Optional[TraceContext] = None) -> dict:
         """Blocks until the dispatcher finishes this request; returns
-        {"new_tokens": [...]} or {"error": ...}. Same contract as
-        ``BatchingEngine.submit`` so the server swaps engines freely.
+        {"new_tokens": [...]} or {"error": ...}.
         ``trace``: the caller's trace context (e.g. from an ``X-SLT-Trace``
         / ``"traceparent"`` member on the wire request) — the request span
         chains under it, completing the client -> server causal edge in
@@ -782,62 +684,7 @@ class ContinuousBatchingEngine:
             if r is not None:
                 r.peak_batch = max(r.peak_batch, live)
 
-    # ---- monolithic admission (legacy baseline) ----
-
-    def _admit(self, staged: List[_Request]) -> Optional[tuple]:
-        self._drop_cancelled(staged)
-        free = self._free_slots()
-        n = min(len(free), len(staged))
-        if n < max(1, min(self._min_admit, self.max_slots)):
-            return None
-        batch = [staged.pop(0) for _ in range(n)]
-        ids = free[:n]
-        nb = _bucket(n, floor=1)
-        pb = _bucket(max(len(r.prompt) for r in batch))
-        pb = min(pb, self.module.cfg.max_seq_len)
-        prompts = np.zeros((nb, pb), np.int32)
-        lengths = np.ones((nb,), np.int32)
-        slot_ids = np.full((nb,), self.max_slots, np.int32)  # pad: dropped
-        temp = np.zeros((nb,), np.float32)
-        topk = np.zeros((nb,), np.int32)
-        eos = np.full((nb,), -1, np.int32)
-        seed = np.zeros((nb,), np.uint32)
-        for i, r in enumerate(batch):
-            prompts[i, :len(r.prompt)] = r.prompt
-            lengths[i] = len(r.prompt)
-            slot_ids[i] = ids[i]
-            temp[i] = r.temperature
-            topk[i] = r.top_k
-            eos[i] = -1 if r.eos_id is None else r.eos_id
-            seed[i] = r.seed & 0xFFFFFFFF
-            self._note_admitted(r, ids[i])
-        self._post_admit_stats(n)
-        # Goodput: a first-seen (nb, pb) bucket pays an XLA compile here
-        # — that wall-clock is "compile" badput, not admission work.
-        new_bucket = (nb, pb) not in self._admit_jits
-        fn = self._admit_jit(nb, pb)
-        t_j0 = time.perf_counter()
-        with goodput.phase("compile" if new_bucket else "admit"):
-            self._state, tok0 = fn(self.params, self._state,
-                                   jnp.asarray(prompts),
-                                   jnp.asarray(lengths),
-                                   jnp.asarray(slot_ids), jnp.asarray(temp),
-                                   jnp.asarray(topk), jnp.asarray(eos),
-                                   jnp.asarray(seed))
-        if new_bucket:
-            t_j1 = time.perf_counter()
-            self._wf_events.note("compile", t_j0, t_j1)
-            for r in batch:
-                if r.wf is not None:
-                    r.wf.note_compile(t_j0, t_j1)
-        try:
-            tok0.copy_to_host_async()  # overlap the D2H copy (see chunk)
-        except (AttributeError, RuntimeError):
-            pass
-        # The admit's first tokens harvest like a 1-token chunk, in order.
-        return ("admit", tok0, [(ids[i], batch[i]) for i in range(n)])
-
-    # ---- paged allocation helpers ----
+    # ---- page allocation ----
 
     def _try_alloc(self, n: int) -> Optional[List[int]]:
         """Allocate, evicting cached prefixes under pressure; None when
@@ -968,14 +815,12 @@ class ContinuousBatchingEngine:
         self.preemptions += 1
         self._m_preempt.inc()
 
-    # ---- paged admission + prefill + decode ----
+    # ---- admission, prefill, decode ----
 
     def _admit_paged(self, staged: List[_Request]) -> bool:
         self._drop_cancelled(staged)
         free = self._free_slots()
         n = min(len(free), len(staged))
-        if n < max(1, min(self._min_admit, self.max_slots)):
-            return False
         ps = self._ps
         admitted = 0
         for _ in range(n):
@@ -1255,36 +1100,14 @@ class ContinuousBatchingEngine:
             r.chunks_dispatched += 1
             snapshot.append((sid, r, r.gen))
             self._release_if_budget_dispatched(sid, r)
-        try:
-            toks.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
-        return ("pchunk", toks, snapshot)
-
-    def _decode_step(self) -> tuple:
-        """Monolithic decode chunk (legacy baseline): always pays
-        ``max_slots`` rows of compute, live or not."""
-        with goodput.phase("compile" if self.chunks_run == 0
-                           else "decode"):
-            self._state, toks = self._chunk_jit(self.params, self._state)
-        self.chunks_run += 1
-        self._m_chunks.inc()
-        self.decoded_rows_total += sum(
-            1 for r in self._slots if r is not None and not r.finished)
-        self.dispatched_rows_total += self.max_slots
-        # Start the D2H transfer NOW, behind the enqueued compute:
-        # serial per-chunk fetches put a host round trip between chunks
-        # (measured 0.38x of the static engine before this). With the
-        # copy launched at dispatch, harvest's np.asarray finds the
-        # bytes already en route / landed and the transfer overlaps the
-        # in-flight chunks' compute.
+        # Start the D2H transfer NOW, behind the enqueued compute: with
+        # the copy launched at dispatch, harvest finds the bytes already
+        # en route and the transfer overlaps the in-flight chunks.
         try:
             toks.copy_to_host_async()
         except (AttributeError, RuntimeError):
             pass  # platform without async D2H: harvest blocks
-        return ("chunk", toks,
-                [(i, r) for i, r in enumerate(self._slots)
-                 if r is not None])
+        return ("pchunk", toks, snapshot)
 
     # -- harvest -----------------------------------------------------------
 
@@ -1298,21 +1121,15 @@ class ContinuousBatchingEngine:
             # The dispatcher sat blocked in this device_get: tokens of
             # LATER in-flight futures stall behind it (harvest drain).
             self._wf_events.note("harvest_drain", t_h0, t_now)
-        if kind == "admit":
-            arr = arr[:, None]  # [nb] -> [nb, 1], rows indexed by snapshot
-            pairs = [(sid, r, arr[i]) for i, (sid, r)
-                     in enumerate(snapshot)]
-        elif kind == "prefill":
+        if kind == "prefill":
             # Only rows whose prompt COMPLETED carry a first token; rows
             # mid-prefill (or preempted since dispatch) yield nothing.
             pairs = [(sid, r, arr[i:i + 1])
                      for i, (sid, r, fin, gen) in enumerate(snapshot)
                      if fin and r.gen == gen]
-        elif kind == "pchunk":
+        else:  # "pchunk": rows of the compacted live batch
             pairs = [(sid, r, arr[j]) for j, (sid, r, gen)
                      in enumerate(snapshot) if r.gen == gen]
-        else:  # "chunk": monolithic full-width rows, indexed by slot id
-            pairs = [(sid, r, arr[sid]) for sid, r in snapshot]
         for sid, r, row in pairs:
             if r.finished:
                 continue  # tokens from a chunk dispatched before retirement
@@ -1322,10 +1139,7 @@ class ContinuousBatchingEngine:
                 # the next boundary instead of decoding to full budget.
                 self._cancel(r)
                 if self._slots[sid] is r:
-                    if self._paged:
-                        self._retire_slot(sid)
-                    else:
-                        self._slots[sid] = None
+                    self._retire_slot(sid)
                 continue
             first = r.span is not None \
                 and "first_token" not in r.span.marks
@@ -1355,8 +1169,7 @@ class ContinuousBatchingEngine:
                             for cause, v in causes.items():
                                 self._stall_counter(cause).inc(v)
             # Retire on EOS exactly as generate fills: the EOS token is
-            # kept, the remainder of the budget fills with EOS — the
-            # static engine returned that fill too, so replies match.
+            # kept, the remainder of the budget fills with EOS.
             if r.eos_id is not None and r.eos_id in r.tokens:
                 first = r.tokens.index(r.eos_id)
                 r.tokens = r.tokens[:first + 1]
@@ -1394,10 +1207,7 @@ class ContinuousBatchingEngine:
                                 / self._wf_decode_total)
                     self._emit_span(r.span)
                 if self._slots[sid] is r:
-                    if self._paged:
-                        self._retire_slot(sid)
-                    else:
-                        self._slots[sid] = None
+                    self._retire_slot(sid)
                 r.done.set()
         self._m_slots.set(self.max_slots - len(self._free_slots()))
 
@@ -1406,8 +1216,8 @@ class ContinuousBatchingEngine:
     def _slot_census(self) -> tuple:
         """(decoding, prefilling, free, other) over the slot table; the
         four sum to ``max_slots``. ``other``: cancelled by a timed-out
-        submitter and not yet retired (monolithic: or finished). A
-        request released at dispatch holds no slot and is not counted."""
+        submitter and not yet retired. A request released at dispatch
+        holds no slot and is not counted."""
         dec = pre = free = 0
         for r in self._slots:
             if r is None:
@@ -1532,43 +1342,27 @@ class ContinuousBatchingEngine:
         try:
             with annotate("sched.admit"):
                 if staged:
-                    if self._paged:
-                        # Paged admission only allocates pages + a slot;
-                        # the compute happens in the prefill step below.
-                        with goodput.phase("admit"):
-                            admitted = self._admit_paged(staged)
-                    else:
-                        fut = self._admit(staged)
-                        if fut is not None:
-                            futures.append(fut)
-                            sent.append(fut)
+                    # Admission only allocates pages + a slot; the
+                    # compute happens in the prefill step below.
+                    with goodput.phase("admit"):
+                        admitted = self._admit_paged(staged)
             if on:
                 ts.append(time.perf_counter())
                 census = self._slot_census()
                 queued = len(staged) + self._q.qsize()
-            if self._paged:
-                with annotate("sched.prefill"):
-                    pre = self._prefill_steps()
-                    futures.extend(pre)
-                    sent.extend(pre)
-                if on:
-                    ts.append(time.perf_counter())
-                with annotate("sched.decode"):
-                    fut = self._decode_step_paged(staged)
-                    if fut is not None:
-                        futures.append(fut)
-                        sent.append(fut)
-                self._m_kv_in_use.set(self._pool.used_blocks)
-                self._maybe_resolve_kv_alert()
-            else:
-                if on:
-                    ts.append(ts[-1])  # its admit is its prefill
-                if any(r is not None and not r.finished
-                       for r in self._slots):
-                    with annotate("sched.decode"):
-                        fut = self._decode_step()
+            with annotate("sched.prefill"):
+                pre = self._prefill_steps()
+                futures.extend(pre)
+                sent.extend(pre)
+            if on:
+                ts.append(time.perf_counter())
+            with annotate("sched.decode"):
+                fut = self._decode_step_paged(staged)
+                if fut is not None:
                     futures.append(fut)
                     sent.append(fut)
+            self._m_kv_in_use.set(self._pool.used_blocks)
+            self._maybe_resolve_kv_alert()
             if on:
                 ts.append(time.perf_counter())
             if sent or admitted:
@@ -1602,23 +1396,22 @@ class ContinuousBatchingEngine:
             futures.clear()
             staged.clear()
             self._slots[:] = [None] * self.max_slots
-            if self._paged:
-                # Rebuild the allocator with the device state: a
-                # poisoned pool's tables point at freed pages.
-                self._pool = BlockPool(self._pool.num_blocks, self._ps)
-                if self._trie is not None:
-                    self._trie = PrefixTrie(
-                        self._pool, max_blocks=self._trie.max_blocks,
-                        hit_window=self.kv.prefix_hit_window)
-                self._tbl[:] = self._pool.sentinel
-                self._slot_pages = [[] for _ in range(self.max_slots)]
-                self._pending_cow.clear()
+            # Rebuild the allocator with the device state: a poisoned
+            # pool's tables point at freed pages.
+            self._pool = BlockPool(self._pool.num_blocks, self._ps)
+            if self._trie is not None:
+                self._trie = PrefixTrie(
+                    self._pool, max_blocks=self._trie.max_blocks,
+                    hit_window=self.kv.prefix_hit_window)
+            self._tbl[:] = self._pool.sentinel
+            self._slot_pages = [[] for _ in range(self.max_slots)]
+            self._pending_cow.clear()
             self._state = self._init_state()
 
-    # -- stats / warm / stop ----------------------------------------------
+    # -- stats / warm-up / stop --------------------------------------------
 
-    def kv_stats(self) -> Optional[dict]:
-        """Paged-pool pressure for the serving wire's admin ping: the
+    def kv_stats(self) -> dict:
+        """KV pool pressure for the serving wire's admin ping: the
         router's least-loaded picking and brownout shedding read this
         (memory pressure, not just queue depth). ``prefix_hit_rate`` is
         WINDOWED over the last ``kv.prefix_hit_window`` lookups (round
@@ -1626,8 +1419,6 @@ class ContinuousBatchingEngine:
         along for dashboards. ``prefix_digest`` carries the resident-
         prefix chain hashes the router's fleet-wide redundancy
         accounting intersects against."""
-        if not self._paged:
-            return None
         total = self._pool.num_blocks
         lookups = self._trie.lookups if self._trie is not None else 0
         hits = self._trie.hits if self._trie is not None else 0
@@ -1648,29 +1439,21 @@ class ContinuousBatchingEngine:
         return out
 
     def warm_shapes(self, workloads, batch_sizes=None) -> int:
-        """Deterministically pre-compile every paged compile bucket the
-        given workloads can touch, WITHOUT traffic: each reachable
+        """Deterministically pre-compile every compile bucket the given
+        workloads can touch, WITHOUT traffic: each reachable
         (nb, T, W) prefill jit and (nb, W) decode jit is invoked once on
         throwaway donated state (all-sentinel tables, padded slot ids —
         every write drops), so a measured window pays zero XLA compiles
-        no matter how arrivals happen to batch. Traffic-based warmup
-        alone was timing-dependent: a bucket the warm leg's Poisson
-        coincidences missed cost the measured p99 a multi-second compile
-        (the first serve_kv bench flaked exactly this way).
+        no matter how arrivals happen to batch (warming by traffic
+        compiles only the buckets its arrivals happen to form).
 
         ``workloads``: iterable of (prompt_len, max_new) pairs — the
         request shapes the measured traffic will carry. ``batch_sizes``
         defaults to every admit-bucket representative up to
-        ``max_slots``. Monolithic mode delegates to the submit-based
-        :meth:`warm` per workload (its bucket space is tiny). Returns
-        the number of buckets compiled."""
+        ``max_slots``. Returns the number of buckets compiled."""
         if batch_sizes is None:
             batch_sizes = range(1, self.max_slots + 1)
         workloads = [(int(L), int(new)) for L, new in workloads]
-        if not self._paged:
-            for L, new in workloads:
-                self.warm(L, new, batch_sizes=tuple(batch_sizes))
-            return 0
         ps = self._ps
         nbs = sorted({_bucket(min(n, self.max_slots), floor=1)
                       for n in batch_sizes})
@@ -1723,48 +1506,6 @@ class ContinuousBatchingEngine:
                         jnp.full((nb,), sent, jnp.int32))
                     compiled += 1
         return compiled
-
-    def warm(self, prompt_len: int, max_new: int, batch_sizes=(1,),
-             temperature: float = 0.0, top_k: int = 0):
-        """Pre-compile the admit/prefill buckets + the chunk for a known
-        workload by pushing synthetic requests through the real
-        dispatcher (paged mode: the (nb, T, W) prefill buckets and
-        (nb, W) chunk buckets the workload will touch).
-
-        Each batch size admits ATOMICALLY: ``_min_admit`` gates the
-        dispatcher until all ``n`` warm requests are staged, so warm
-        deterministically compiles the admit bucket for n — without the
-        gate, admission splits were thread-arrival-timing-dependent (a
-        size-2 warm could admit as 1+1, compiling only the nb=1 bucket)
-        and the timed round could pay an XLA compile the warm was
-        supposed to absorb (ADVICE.md round 5)."""
-        del max_new  # chunk shape is workload-independent
-        for n in batch_sizes:
-            results = [None] * n
-
-            def _one(i):
-                results[i] = self.submit(
-                    [1] * prompt_len, min(2, self.chunk_size),
-                    temperature, top_k, None, 0)
-
-            self._min_admit = min(n, self.max_slots)
-            try:
-                # daemon: the join below is bounded, and a straggler warm
-                # submit must not block interpreter exit (SLT004).
-                threads = [threading.Thread(target=_one, args=(i,),
-                                            daemon=True)
-                           for i in range(n)]
-                for t in threads:
-                    t.start()
-                for t in threads:
-                    t.join(timeout=600)
-            finally:
-                self._min_admit = 1
-            bad = [r for r in results if not r or "error" in r]
-            if bad:
-                # A warm that compiled nothing must not return as if it
-                # had — the first real request would eat the compile.
-                raise RuntimeError(f"warm workload rejected: {bad[0]}")
 
     def stop(self):
         self._stop.set()

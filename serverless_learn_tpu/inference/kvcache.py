@@ -1,11 +1,10 @@
 """Paged KV-cache primitives: block pool, block tables, prefix trie.
 
-Round-13 tentpole. The continuous engine's round-5 design owned ONE
-monolithic resident KV allocation of ``max_slots`` full-length rows —
-every slot paid ``max_seq_len`` worth of HBM whether it held 3 tokens or
-3000, retired slots kept burning decode FLOPs until re-admission, and a
-long prefill stalled the whole decode batch. This module is the host
-side of the replacement:
+One resident KV allocation of ``max_slots`` full-length rows makes every
+slot pay ``max_seq_len`` worth of HBM whether it holds 3 tokens or 3000,
+keeps retired slots burning decode FLOPs until re-admission, and lets a
+long prefill stall the whole decode batch. This module is the host side
+of the serving engine's paged pool instead:
 
 * :class:`BlockPool` — a free-list allocator over ``num_blocks`` page
   ids with per-block refcounts. The device arrays it indexes into live
@@ -431,8 +430,8 @@ def paged_module(module, block_size: int, num_blocks: int):
 
 
 def sequential_table(batch: int, max_pages: int, num_blocks: int):
-    """Row-major dense block table for engines that don't share pages
-    (the static engine's per-group cache): row b owns pages
+    """Row-major dense block table for callers that don't share pages
+    (``generate`` over a paged cache): row b owns pages
     [b*max_pages, (b+1)*max_pages). Requires num_blocks >= B*max_pages."""
     import numpy as np
 

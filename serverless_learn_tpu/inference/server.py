@@ -10,16 +10,14 @@ per line and get one JSON object per line back.
     errors:   {"error": "..."}
 
 Connections are handled on per-connection threads; generation goes through
-the ``BatchingEngine`` admission queue (``inference/batching.py``), which
-coalesces concurrent compatible requests into ONE batched prefill+decode —
-N clients share a batch instead of time-slicing the chip (round-3 verdict
-#2). Unequal prompts right-pad with per-sequence cache indices, so batched
-greedy results are byte-identical to solo calls. Request lines are capped
-at MAX_LINE bytes — a newline-free stream gets an error reply and a
-dropped connection instead of unbounded buffering. Bucketed shapes reuse
-the jit cache; new buckets pay one compile. The reference has no inference
-path at all — its model was a gossiped double vector
-(`src/protos/serverless_learn.proto:81-83`).
+the ``ContinuousBatchingEngine`` (``inference/continuous.py``), whose
+dispatcher is the sole user of the device: N clients share the decode batch
+instead of time-slicing the chip, and batched greedy results are
+byte-identical to solo calls. Request lines are capped at MAX_LINE bytes — a
+newline-free stream gets an error reply and a dropped connection instead of
+unbounded buffering. Bucketed shapes reuse the jit cache; new buckets pay
+one compile. The reference has no inference path at all — its model was a
+gossiped double vector (`src/protos/serverless_learn.proto:81-83`).
 """
 
 from __future__ import annotations
@@ -40,21 +38,14 @@ class GenerationServer:
 
     def __init__(self, module, params, host: str = "127.0.0.1",
                  port: int = 0, conn_timeout_s: float = 60.0,
-                 max_batch: int = 8, batch_wait_ms: float = 3.0,
-                 engine: str = "continuous", chunk_size: int = 32,
+                 max_batch: int = 8, engine="continuous",
+                 chunk_size: int = 32,
                  registry=None, metrics_port: Optional[int] = None,
                  event_log_path: Optional[str] = None,
                  profile_dir: Optional[str] = None, kv=None,
                  waterfall=None, event_sink=None):
-        from serverless_learn_tpu.config import KVCacheConfig
         from serverless_learn_tpu.telemetry import (JsonlEventLog,
                                                     get_registry)
-
-        # Paged KV is the serving default (round 13): pass an explicit
-        # KVCacheConfig to tune it or KVCacheConfig(paged=False) for the
-        # legacy monolithic rows (the equivalence baseline).
-        if kv is None:
-            kv = KVCacheConfig()
 
         self.module = module
         self.params = params
@@ -74,8 +65,6 @@ class GenerationServer:
             # their own compute here and reuse the REAL wire server.
             self.engine = engine
         elif engine == "continuous":
-            # Slot-level scheduler (round-5): admits at chunk boundaries,
-            # retires at EOS, FIFO — no group keys, nothing starves.
             from serverless_learn_tpu.inference.continuous import (
                 ContinuousBatchingEngine)
 
@@ -83,20 +72,9 @@ class GenerationServer:
                 module, params, max_slots=max_batch, chunk_size=chunk_size,
                 registry=self.registry, event_log=self.event_log, kv=kv,
                 waterfall=waterfall)
-        elif engine == "static":
-            # Round-4 group coalescer, kept for comparison benches.
-            from serverless_learn_tpu.inference.batching import (
-                BatchingEngine)
-
-            self.engine = BatchingEngine(module, params,
-                                         max_batch=max_batch,
-                                         batch_wait_ms=batch_wait_ms,
-                                         registry=self.registry, kv=kv,
-                                         event_log=self.event_log,
-                                         waterfall=waterfall)
         else:
-            raise ValueError(f"unknown engine {engine!r}: "
-                             "expected 'continuous' or 'static'")
+            raise ValueError(f"unknown engine {engine!r}: expected "
+                             "'continuous' or an engine object")
         # Scrapeable telemetry endpoint (slt top / Prometheus). None = off;
         # 0 = auto-assign (the addr rides in self.metrics_addr).
         self._exporter = None
@@ -166,7 +144,7 @@ class GenerationServer:
         if op == "ping":
             rep = {"ok": True, "draining": self.draining,
                    "requests_served": self.requests_served}
-            # Paged engines report KV pool pressure, the windowed prefix
+            # The engine reports KV pool pressure, the windowed prefix
             # hit rate AND the resident-prefix digest so the fleet
             # router's picking/shedding can weigh MEMORY (not just queue
             # depth) and its fleetscope accounting can intersect each
@@ -178,8 +156,8 @@ class GenerationServer:
                 if kv:
                     rep["kv"] = kv
             # Weight-version identity (round 23): rides the ping (not
-            # the kv dict — monolithic engines have no kv_stats) so the
-            # router can version-tag route decisions and detect a
+            # the kv dict — an injected engine may have no kv_stats) so
+            # the router can version-tag route decisions and detect a
             # version-skewed fleet.
             ver = getattr(self.engine, "weight_version", None)
             if ver:
@@ -257,9 +235,9 @@ class GenerationServer:
                     req = json.loads(line)
                     if not isinstance(req, dict):
                         raise ValueError("request must be a JSON object")
-                    # No device lock: the BatchingEngine's dispatcher is
-                    # the sole device user; concurrent handlers just queue
-                    # (and coalesce) their requests.
+                    # No device lock: the engine's dispatcher is the
+                    # sole device user; concurrent handlers just queue
+                    # their requests.
                     rep = (self._admin(req) if "op" in req
                            else self.handle(req))
                 except Exception as e:  # any bad request -> error reply,
@@ -277,8 +255,8 @@ class GenerationServer:
             except OSError:
                 break
             # Per-connection thread: a slow or idle keepalive client blocks
-            # only its own thread; concurrent generation requests coalesce
-            # in the BatchingEngine's admission queue.
+            # only its own thread; concurrent generation requests meet
+            # in the engine's admission queue.
             t = None
             with self._conns_lock:
                 if len(self._conns) < self.max_connections:

@@ -15,7 +15,7 @@ Metric naming scheme (Prometheus conventions):
 * every metric is prefixed ``slt_``;
 * counters end in ``_total``; durations are ``_seconds``; histograms carry
   fixed buckets chosen per quantity (latency buckets below);
-* low-cardinality labels only — ``engine="continuous"|"static"``,
+* low-cardinality labels only — ``engine="continuous"``,
   ``rpc="fetch"``, ``daemon="shard-server"``. Never per-request labels.
 
 Request-level tracing rides the same module: a :class:`Span` is a set of

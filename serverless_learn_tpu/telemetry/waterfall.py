@@ -77,7 +77,7 @@ OTHER_CAUSE = "other"
 # Phase taxonomy. ``spec_verify`` is reserved for speculative decode
 # (satellite of this round; see inference/speculative.py metrics).
 PHASES = ("queue", "admit", "compile", "prefill", "decode",
-          "generate", "spec_verify")
+          "spec_verify")
 
 _EPS = 1e-9
 
@@ -330,8 +330,7 @@ class RequestWaterfall:
                       "prefill": round(prefill_s, 6)}
             phases.append({"phase": "admit", "s": round(admit_s, 6)})
             phases.append({"phase": "compile", "s": round(compile_s, 6)})
-            work = {"phase": "generate" if self.engine == "static"
-                    else "prefill",
+            work = {"phase": "prefill",
                     "t1_s": round(ft, 6), "s": round(prefill_s, 6)}
             if self.prefill_chunks:
                 work["chunks"] = [
@@ -343,7 +342,7 @@ class RequestWaterfall:
                      "stall_s": c["stall_s"]}
                     for c in self.prefill_chunks]
             phases.append(work)
-            if self.engine != "static" and done > ft + _EPS:
+            if done > ft + _EPS:
                 phases.append({"phase": "decode", "t0_s": round(ft, 6),
                                "t1_s": round(done, 6),
                                "s": round(done - ft, 6)})
@@ -695,7 +694,7 @@ def render(rep: dict, width: int = 64) -> str:
                      f"|{''.join(seg):<{width}}|{extra}")
     if rep.get("slowest"):
         lines.append("  legend: Q queue  A admit  C compile  P prefill  "
-                     "D decode  G generate  S spec_verify")
+                     "D decode  S spec_verify")
     return "\n".join(lines)
 
 
@@ -704,8 +703,8 @@ def render(rep: dict, width: int = 64) -> str:
 
 def synthetic_records() -> List[dict]:
     """Deterministic mini-fleet of records exercising every schema
-    feature (compile stall, preempt stall, hedged hop, shed hop, static-
-    engine reduced record). Doubles as the committed-fixture generator —
+    feature (compile stall, preempt stall, hedged hop, shed hop).
+    Doubles as the committed-fixture generator —
     the fixture under tests/fixtures/waterfall/ is this, dumped."""
     def span(tid, node, marks, wf):
         return {"event": "span", "span": "request", "trace_id": tid,
@@ -783,21 +782,6 @@ def synthetic_records() -> List[dict]:
                     queue_wait_s=0.0004, total_s=0.22,
                     decision_id="bbbbbbbbbbbbbbbb-2",
                     pick_reason="session_affinity"))
-    # Request C: static engine — reduced phase set, no decode trace.
-    wf_c = {
-        "v": SCHEMA_VERSION, "engine": "static",
-        "phases": [
-            {"phase": "queue", "t0_s": 0.0, "t1_s": 0.006, "s": 0.006},
-            {"phase": "admit", "s": 0.0},
-            {"phase": "compile", "s": 0.150},
-            {"phase": "generate", "t1_s": 0.256, "s": 0.1}],
-        "ttft_s": 0.256,
-        "ttft_decomp_s": {"queue": 0.006, "admit": 0.0,
-                          "compile": 0.150, "prefill": 0.1},
-        "overhead_s": 0.0001}
-    recs.append(span("cc" * 16, "node1",
-                     {"admit": 0.006, "first_token": 0.256,
-                      "done": 0.256}, wf_c))
     # Request D: shed at the router — no engine record at all.
     recs.append(hop("dd" * 16, shed=True, queue_wait_s=0.0,
                     total_s=0.0002, decision_id="dddddddddddddddd-3",
@@ -867,12 +851,4 @@ def self_check(fixture_path: Optional[str] = None) -> dict:
           "serve_itl_p99_ms" in names and any(
               "prefill_interference_frac" in r for r in rows),
           f"rows: {sorted(names)}")
-    static = [r for r in with_wf
-              if r["waterfall"].get("engine") == "static"]
-    check("static_reduced",
-          all("itl" not in r["waterfall"]
-              and not any(p["phase"] == "decode"
-                          for p in r["waterfall"]["phases"])
-              for r in static) and len(static) >= 1,
-          f"{len(static)} static record(s): no decode trace")
     return {"ok": all(c["ok"] for c in checks), "checks": checks}

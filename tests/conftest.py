@@ -213,11 +213,7 @@ SLOW_TESTS = {
     "tests/test_local_sgd.py::test_stateful_resnet_gossip_trains_and_stats_gossip",
     "tests/test_local_sgd.py::test_stateful_diloco_exact_parity_groupnorm",
     "tests/test_local_sgd.py::test_stateful_diloco_batchnorm_tolerance_documented",
-    "tests/test_serve_batching.py::test_engine_coalesces_and_is_exact",
-    "tests/test_serve_batching.py::test_engine_groups_by_sampling_params",
-    "tests/test_serve_batching.py::test_engine_mixed_max_new_truncates_exactly",
-    "tests/test_serve_batching.py::test_server_concurrent_clients_share_batches",
-    "tests/test_serve_batching.py::test_padded_batch_generate_matches_solo",
+    "tests/test_continuous.py::test_padded_batch_generate_matches_solo",
     "tests/test_parallel_ingest.py::test_resnet50_device_augment_trains",
     "tests/test_tokenizer.py::test_packed_batches_train_llama_and_bert",
     "tests/test_flash_masks.py::test_dispatcher_honors_kv_lengths_alone",
@@ -252,17 +248,15 @@ SLOW_TESTS = {
     # allocator/trie/doctor units stay fast)
     "tests/test_kvcache.py::test_paged_generate_matches_monolithic",
     "tests/test_kvcache.py::test_paged_engine_greedy_exact_with_chunked_prefill",
-    "tests/test_kvcache.py::test_paged_engine_seeded_sampling_matches_monolithic",
+    "tests/test_kvcache.py::test_seeded_sampling_is_blind_to_page_and_chunk_size",
     "tests/test_kvcache.py::test_paged_engine_eos_retires_and_frees_blocks",
     "tests/test_kvcache.py::test_shared_prefix_reuse_hits_and_stays_exact",
     "tests/test_kvcache.py::test_exhaustion_backpressure_and_preemption_stay_exact",
     "tests/test_kvcache.py::test_decode_cost_tracks_live_slots",
-    "tests/test_kvcache.py::test_static_engine_paged_matches_monolithic",
     "tests/test_kvcache.py::test_server_ping_reports_kv_and_prompt_histogram",
     # round 6 (telemetry integration; registry/endpoint/top units stay fast)
     "tests/test_telemetry.py::test_server_metrics_endpoint_scrape",
     "tests/test_telemetry.py::test_continuous_cancellation_retires_slot",
-    "tests/test_telemetry.py::test_warm_compiles_admit_buckets_deterministically",
     "tests/test_telemetry.py::test_top_once_covers_trainer_and_inference",
     # round 17 (numerics: real-trainer fingerprint runs + the cadence/
     # overhead acceptance run; the stat/detector/provenance units stay

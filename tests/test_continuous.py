@@ -1,13 +1,11 @@
-"""Continuous batching (round-5 verdict #2): slot-level scheduling must
-not change greedy results, must admit mid-stream, retire at EOS, and
-never starve a request the way the static engine's group keys could.
+"""Continuous batching: slot-level scheduling must not change greedy
+results, must admit mid-stream, retire at EOS, and never starve a
+request behind a mismatched neighbour.
 
 Exactness model: greedy continuations are byte-identical to solo
-``generate`` calls (same pin as ``tests/test_serve_batching.py``);
-sampled continuations are REPRODUCIBLE and BATCH-INVARIANT (per-slot
-``fold_in(seed, position)`` streams — a stronger property than the
-static engine's shared group stream, asserted here by re-running the
-same seed under different traffic).
+``generate`` calls; sampled continuations are REPRODUCIBLE and
+BATCH-INVARIANT (per-slot ``fold_in(seed, position)`` streams, asserted
+here by re-running the same seed under different traffic).
 """
 
 import threading
@@ -45,6 +43,25 @@ def _engine(module, params, **kw):
     kw.setdefault("max_slots", 4)
     kw.setdefault("chunk_size", 4)
     return ContinuousBatchingEngine(module, params, **kw)
+
+
+def test_padded_batch_generate_matches_solo(model):
+    """The primitive under every batched path: one call over right-padded
+    unequal prompts (``prompt_lengths`` gives each row its own cache
+    index) reproduces each solo greedy continuation exactly."""
+    module, params = model
+    prompts = [[5, 9, 11], [7, 3, 2, 8, 1, 30, 12], [4]]
+    P = max(len(p) for p in prompts)
+    padded = np.zeros((3, P), np.int32)
+    lens = np.zeros(3, np.int32)
+    for i, p in enumerate(prompts):
+        padded[i, :len(p)] = p
+        lens[i] = len(p)
+    toks = generate(module, params, jnp.asarray(padded), 6,
+                    prompt_lengths=jnp.asarray(lens))
+    new = np.asarray(jax.device_get(toks))[:, P:]
+    for i, p in enumerate(prompts):
+        assert new[i].tolist() == _solo(module, params, p, 6), f"row {i}"
 
 
 def test_concurrent_greedy_exact(model):
@@ -260,6 +277,25 @@ def test_validation_errors(model):
         # The engine still serves after rejections.
         r = eng.submit([5, 9], 3, 0.0, 0, None, 0)
         assert r["new_tokens"] == _solo(module, params, [5, 9], 3)
+    finally:
+        eng.stop()
+
+
+def test_long_prompt_near_window_still_serves(model):
+    """Padding must never push a valid request past ``max_seq_len``.
+    llama_tiny's window here is 64 tokens = 4 pages of 16: 40 + 8 tokens
+    need 3 pages, which ``_wbucket`` rounds to 4, and 61 + 3 sit exactly
+    at the window, where the last chunk's page demand and the table
+    window are both capped by the window's own page count."""
+    module, params = model
+    eng = _engine(module, params)
+    try:
+        for n_prompt, n_new in ((40, 8), (61, 3)):
+            prompt = [(i % 37) + 1 for i in range(n_prompt)]
+            r = eng.submit(prompt, n_new, temperature=0.0, top_k=0,
+                           eos_id=None, seed=0)
+            assert "error" not in r, r
+            assert r["new_tokens"] == _solo(module, params, prompt, n_new)
     finally:
         eng.stop()
 

@@ -224,6 +224,58 @@ def test_wire_ab_parity_at_256_under_churn():
     assert rep["final_eval_loss"]["quant"] < init - 0.2
 
 
+def test_error_feedback_carry_telescopes():
+    """The herd's in-graph error-feedback carry, pinned exactly where
+    `--wire-ab`'s one-seed loss gap reads noise (ROADMAP D11): over the
+    rounds a worker delivered, what its receivers were sent and what it
+    meant differ by no more than the residual it still carries
+    (sum(wired - delta) == -residual), so the wire's error does not
+    grow with the rounds; a dead round absorbs nothing; and the
+    no-feedback control's error does grow. The f32 kernel on the same
+    anchor, optimizer state and keys gives the deltas that were meant."""
+    import jax
+    import numpy as np
+
+    from serverless_learn_tpu.training.herd import _kernels
+
+    n, rounds = 8, 6
+    quant, f32 = wire_parity_specs(n, 0.8)
+    noef = dataclasses.replace(quant, error_feedback=False)
+    k32, kq, kn = _kernels(f32), _kernels(quant), _kernels(noef)
+    anchor, _, opt, proj, shifts, key = k32["init"](3)
+    tmap = jax.tree_util.tree_map
+    zeros = tmap(lambda p: np.zeros((n,) + p.shape, np.float32), anchor)
+    scale, reset = np.ones(n, np.float32), np.zeros(n, np.bool_)
+
+    def accumulate(kernels):
+        residual, err = zeros, zeros
+        for r in range(rounds):
+            alive = np.ones(n, np.bool_)
+            alive[5] = r != 2          # worker 5 is dead in round 2
+            args = (anchor, opt, shifts, proj, key, scale, alive, reset, r)
+            meant = k32["inner"](*args, zeros)[0]
+            wired, *_, residual = kernels["inner"](*args, residual)
+            err = tmap(lambda e, w, m: e + np.where(
+                alive.reshape((n,) + (1,) * (w.ndim - 1)),
+                np.asarray(w) - np.asarray(m), 0.0), err, wired, meant)
+        return err, tmap(np.asarray, residual)
+
+    err, residual = accumulate(kq)
+    for e, r in zip(jax.tree_util.tree_leaves(err),
+                    jax.tree_util.tree_leaves(residual)):
+        np.testing.assert_allclose(e, -r, atol=1e-6)
+        assert np.abs(r).max() > 0
+    err_noef, residual_noef = accumulate(kn)
+    assert not any(np.asarray(r).any()
+                   for r in jax.tree_util.tree_leaves(residual_noef))
+
+    def norm(tree):
+        return float(np.sqrt(sum((np.asarray(x) ** 2).sum()
+                                 for x in jax.tree_util.tree_leaves(tree))))
+
+    assert norm(err_noef) > 1.5 * norm(err)
+
+
 def test_quantized_herd_deterministic_and_poison_still_quarantined(
         tmp_path):
     """The quantizer under vmap keeps the determinism contract

@@ -594,7 +594,7 @@ def test_self_check_passes():
 def test_cli_jit_replay_exit_codes(tmp_path, capsys):
     from serverless_learn_tpu.cli import main
 
-    site = "serverless_learn_tpu/inference/continuous.py:_admit_jit"
+    site = "serverless_learn_tpu/inference/continuous.py:_paged_chunk_jit"
     bad = tmp_path / "bad.jsonl"
     bad.write_text("".join(json.dumps(e) + "\n" for e in [
         {"ev": "declare", "site": site, "budget": 1},

@@ -1,11 +1,12 @@
-"""Paged KV cache (round 13): allocator/trie primitives, block-table
-padding semantics, and the token-identical paged-vs-monolithic
-equivalence suite (greedy + seeded, mixed slot configs, chunked prefill,
-shared prefixes, exhaustion backpressure, preemption).
+"""Paged KV cache: allocator/trie primitives, block-table padding
+semantics, and the engine's exactness suite (greedy + seeded, mixed slot
+configs, chunked prefill, shared prefixes, exhaustion backpressure,
+preemption).
 
-The exactness bar: the paged continuous engine must be byte-identical to
-solo ``generate`` (greedy) and to the monolithic engine (seeded
-sampling) — paging changes WHERE K/V live, never what attention reads.
+The exactness bar: the engine must be byte-identical to solo ``generate``
+(greedy), its seeded streams blind to page and chunk size, and a paged
+``generate`` identical to the model's monolithic cache — paging changes
+WHERE K/V live, never what attention reads.
 """
 
 import json
@@ -100,12 +101,19 @@ def test_trie_eviction_respects_live_refs():
 def test_kv_config_roundtrip():
     cfg = ExperimentConfig.from_json(json.dumps({
         "model": "llama_tiny",
-        "kv": {"paged": True, "block_size": 8, "num_blocks": 64,
+        "kv": {"block_size": 8, "num_blocks": 64,
                "prefill_chunk": 16, "prefix_cache": False}}))
     assert cfg.kv.block_size == 8 and cfg.kv.num_blocks == 64
     assert not cfg.kv.prefix_cache
     back = json.loads(cfg.to_json())
     assert back["kv"]["prefill_chunk"] == 16
+
+
+def test_kv_config_refuses_the_removed_layout_switch():
+    """A config file written when ``kv.paged`` chose between two layouts
+    must not be silently reinterpreted: the field is refused by name."""
+    with pytest.raises(TypeError, match="paged"):
+        ExperimentConfig.from_dict({"kv": {"paged": False}})
 
 
 def test_doctor_names_kv_pressure(tmp_path):
@@ -162,6 +170,27 @@ def _paged_engine(module, params, **kw):
     kw.setdefault("chunk_size", 4)
     kw.setdefault("registry", MetricsRegistry())
     return ContinuousBatchingEngine(module, params, kv=kv, **kw)
+
+
+def test_engine_without_kv_is_the_default_paged_pool(model):
+    """``kv=None`` means ``KVCacheConfig()``, as ``GenerationServer`` has
+    it: the engine has one KV layout and no argument selects another."""
+    module, params = model
+    eng = ContinuousBatchingEngine(module, params, max_slots=2,
+                                   registry=MetricsRegistry())
+    try:
+        kv = KVCacheConfig()
+        assert eng.kv == kv
+        pages = pages_for(module.cfg.max_seq_len, kv.block_size)
+        st = eng.kv_stats()
+        assert isinstance(st, dict) and st["block_size"] == kv.block_size
+        # max_slots rows of the window plus one row of slack for the trie.
+        assert st["blocks_total"] == eng._pool.num_blocks == 3 * pages
+        assert st["blocks_free"] == st["blocks_total"]
+        assert eng._trie is not None
+        assert eng.prefill_chunk == kv.prefill_chunk
+    finally:
+        eng.stop()
 
 
 def test_paged_generate_matches_monolithic(model):
@@ -328,16 +357,15 @@ def test_refused_pages_sit_out_the_iteration(model):
     assert all(r.prefilling for r in eng._slots[:2])
 
 
-def test_paged_engine_seeded_sampling_matches_monolithic(model):
-    """Seeded sampling: identical tokens from the paged and monolithic
-    engines (the fold_in(seed, position) streams are layout-blind)."""
+def test_seeded_sampling_is_blind_to_page_and_chunk_size(model):
+    """Seeded sampling: identical tokens whatever the pool's block size
+    and the prefill chunk (the fold_in(seed, position) streams see
+    positions, not pages), beside a greedy neighbour."""
     module, params = model
     req = dict(prompt=[7, 3, 2, 9, 1, 4], max_new=6, temperature=0.9,
                top_k=8, eos_id=None, seed=42)
 
-    def run(paged):
-        kv = (KVCacheConfig(block_size=4, prefill_chunk=4) if paged
-              else KVCacheConfig(paged=False))
+    def run(kv):
         eng = ContinuousBatchingEngine(module, params, max_slots=3,
                                        chunk_size=2, kv=kv,
                                        registry=MetricsRegistry())
@@ -361,8 +389,9 @@ def test_paged_engine_seeded_sampling_matches_monolithic(model):
         finally:
             eng.stop()
 
-    assert run(paged=True) == run(paged=False), \
-        "paged seeded sampling diverged from the monolithic engine"
+    assert run(KVCacheConfig(block_size=4, prefill_chunk=4)) \
+        == run(KVCacheConfig()), \
+        "seeded sampling depends on the page or the chunk size"
 
 
 def test_paged_engine_eos_retires_and_frees_blocks(model):
@@ -764,41 +793,6 @@ def test_block_table_write_padding_drops(model):
     assert np.asarray(leaf[0][:3]).any()
 
 
-def test_static_engine_paged_matches_monolithic(model):
-    """The static engine shares the pool abstraction: paged groups are
-    byte-identical to the monolithic groups."""
-    from serverless_learn_tpu.inference.batching import BatchingEngine
-
-    module, params = model
-
-    def run(kv):
-        eng = BatchingEngine(module, params, max_batch=4,
-                             registry=MetricsRegistry(), kv=kv)
-        try:
-            prompts = [[5, 9, 11], [7, 3, 2, 8], [4, 4]]
-            results = [None] * 3
-
-            def client(i):
-                results[i] = eng.submit(prompts[i], 4, 0.0, 0, None, 0)
-
-            ts = [threading.Thread(target=client, args=(i,))
-                  for i in range(3)]
-            for t in ts:
-                t.start()
-            for t in ts:
-                t.join(timeout=300)
-            return results
-        finally:
-            eng.stop()
-
-    mono = run(None)
-    paged = run(KVCacheConfig(block_size=8))
-    for i, (m, p) in enumerate(zip(mono, paged)):
-        assert "error" not in m and "error" not in p, (m, p)
-        assert m["new_tokens"] == p["new_tokens"], \
-            f"static paged group diverged on request {i}"
-
-
 def test_server_ping_reports_kv_and_prompt_histogram(model):
     """The serving wire's admin ping carries paged-pool pressure (the
     router's memory-aware picking input) and submit() feeds the
@@ -825,27 +819,3 @@ def test_server_ping_reports_kv_and_prompt_histogram(model):
         assert fam and sum(s["count"] for s in fam["series"]) >= 1
     finally:
         srv.stop()
-
-
-@pytest.mark.slow
-def test_kv_smoke_paged_beats_monolithic(tmp_path):
-    """The round-13 acceptance, measured: on the seeded shared-prefix +
-    long-prompt workload at equal offered load, the paged engine shows
-    lower short-class p99 AND higher decode goodput share than the
-    monolithic engine, recorded as gated rows in bench_history."""
-    from serverless_learn_tpu.fleet.loadgen import run_kv_smoke
-
-    history = tmp_path / "bench_history.json"
-    rep = run_kv_smoke(seed=3, rate_rps=8.0, duration_s=4.0,
-                       warmup_s=3.0, history_path=str(history))
-    assert rep["monolithic"]["hard_failures"] == 0
-    assert rep["paged"]["hard_failures"] == 0
-    assert rep["improved"], (rep["monolithic"], rep["paged"])
-    rows = json.loads(history.read_text())
-    names = {r["metric"] for r in rows}
-    assert any("serve_kv_paged" in n and "p99" in n for n in names)
-    # The recorded rows pass the gate they will be held by.
-    from serverless_learn_tpu.telemetry import benchgate
-
-    gate = benchgate.run_gate(str(history), metric="serve_kv")
-    assert gate.get("ok"), gate

@@ -5,7 +5,8 @@ sink beside the request spans.
 CPU, tiny model, a list sink. The pool is large enough that nothing is
 preempted and no request has an EOS, so every sum below is exact.
 
-Scenarios ``unique``, ``shared_prefix`` and ``monolithic`` run under an
+Scenarios ``unique``, ``shared_prefix`` and ``no_prefix_cache`` (the
+``unique`` requests on a pool without a prefix trie) run under an
 explicit ``prefill_budget`` (a cap on an iteration's prompt tokens);
 ``derived`` runs the default quota: every mid-prefill slot fed every
 iteration, ``chunk_size // 2`` programs at most while a slot decodes.
@@ -60,11 +61,10 @@ def model(devices):
     return bundle.module, params
 
 
-def _engine(module, params, paged: bool = True, budget: int = BUDGET,
-            **kw):
-    kv = (KVCacheConfig(block_size=4, prefill_chunk=4,
-                        prefill_budget=budget)
-          if paged else KVCacheConfig(paged=False))
+def _engine(module, params, budget: int = BUDGET,
+            prefix_cache: bool = True, **kw):
+    kv = KVCacheConfig(block_size=4, prefill_chunk=4,
+                       prefill_budget=budget, prefix_cache=prefix_cache)
     return ContinuousBatchingEngine(
         module, params, max_slots=MAX_SLOTS, chunk_size=CHUNK, kv=kv,
         registry=MetricsRegistry(), **kw)
@@ -113,10 +113,10 @@ def _run_scenario(model, name: str) -> dict:
     module, params = model
     requests = {"shared_prefix": SHARED, "derived": LONG}.get(name, UNIQUE)
     sink = ListSink()
-    eng = _engine(module, params, paged=name != "monolithic",
+    eng = _engine(module, params,
                   budget=0 if name == "derived" else BUDGET,
-                  event_log=sink)
-    programs = _count_prefill_programs(eng) if name != "monolithic" else []
+                  prefix_cache=name != "no_prefix_cache", event_log=sink)
+    programs = _count_prefill_programs(eng)
     ring0 = len(flight.events())
     try:
         replies = _drive(eng, requests,
@@ -145,7 +145,7 @@ def scenario(model):
 
 
 @pytest.fixture(scope="module", params=["unique", "shared_prefix",
-                                        "monolithic", "derived"])
+                                        "no_prefix_cache", "derived"])
 def run(request, scenario):
     return scenario(request.param)
 
@@ -178,7 +178,7 @@ def test_census_sums_to_max_slots(run):
         assert r["queued"] >= 0
     # More requests than slots: some iteration saw a queue, and some saw
     # every slot taken.
-    if run["scenario"] in ("unique", "derived"):
+    if run["scenario"] in ("unique", "no_prefix_cache", "derived"):
         assert max(r["queued"] for r in run["iters"]) > 0
         assert min(r["slots_free"] for r in run["iters"]) == 0
 
@@ -187,11 +187,9 @@ def test_prompt_tokens_are_prefilled_or_hit_exactly_once(run):
     prompt_tokens = sum(len(p) for p, _ in run["requests"])
     sent, hit = _total(run, "prefill_tokens"), _total(run,
                                                       "prefill_hit_tokens")
-    if run["scenario"] == "monolithic":
-        # Its admit program prefills whole prompts: the fields stay 0.
-        assert (sent, hit, _total(run, "prefill_rows")) == (0, 0, 0)
-        return
     assert sent + hit == prompt_tokens
+    assert (run["engine"]._trie is None) \
+        == (run["scenario"] == "no_prefix_cache")
     if run["scenario"] == "shared_prefix":
         # Every later request skips the system prompt's whole blocks.
         assert hit >= (len(SHARED) - 1) * 8
@@ -219,9 +217,6 @@ def test_prefill_steps_are_the_programs_dispatched(run):
     slots they fed; row-chunks are the engine's ``prefill_chunks_run``."""
     eng = run["engine"]
     assert _total(run, "prefill_steps") == len(run["programs"])
-    if run["scenario"] == "monolithic":
-        assert eng.prefill_chunks_run == 0
-        return
     assert len(run["programs"]) > 0
     assert _row_chunks(run) == eng.prefill_chunks_run
     assert (_total(run, "prefill_steps") <= eng.prefill_chunks_run
@@ -276,19 +271,14 @@ def test_decode_rows_match_the_engines_counters(run):
 
 def test_slots_released_are_the_requests_that_ended_by_budget(run):
     """No request has an EOS id, so every reply ends by its budget: the
-    paged engine releases each slot at the dispatch that exhausts it,
-    and so dispatches exactly the row-chunks the replies owe. The
-    monolithic engine finds every finished row at harvest."""
+    engine releases each slot at the dispatch that exhausts it, and so
+    dispatches exactly the row-chunks the replies owe."""
     eng = run["engine"]
     assert _total(run, "slots_released") == eng.slots_released_total
     for r in run["iters"]:
         # At most the rows of its chunk and its finishing prefill rows.
         assert 0 <= r["slots_released"] <= 2 * MAX_SLOTS
     owed = sum(-(-(n - 1) // CHUNK) for _, n in run["requests"])
-    if run["scenario"] == "monolithic":
-        assert eng.slots_released_total == 0
-        assert _total(run, "decode_rows") >= owed
-        return
     assert _total(run, "slots_released") == len(run["requests"]) \
         == _total(run, "requests_finished")
     assert _total(run, "decode_rows") == owed
@@ -326,8 +316,6 @@ def test_phases_fit_inside_the_iteration(run):
         assert set(r["phases_s"]) == names
         assert min(r["phases_s"].values()) >= -1e-9, r["phases_s"]
         assert sum(r["phases_s"].values()) <= r["dur_s"] + 1e-9
-    if run["scenario"] == "monolithic":
-        assert all(r["phases_s"]["prefill"] == 0.0 for r in run["iters"])
 
 
 def test_records_stay_out_of_the_span_readers_way_and_the_ring(run):

@@ -442,24 +442,6 @@ def test_continuous_cancellation_retires_slot(model):
         eng.stop()
 
 
-def test_warm_compiles_admit_buckets_deterministically(model):
-    """ADVICE round 5 (gen_bench warmup hazard): warm(batch_sizes=[1,2,4])
-    must compile the admit bucket for EVERY size — admission may not split
-    on thread-arrival timing."""
-    from serverless_learn_tpu.inference.continuous import (
-        ContinuousBatchingEngine)
-
-    module, params = model
-    eng = ContinuousBatchingEngine(module, params, max_slots=4,
-                                   chunk_size=4, registry=MetricsRegistry())
-    try:
-        eng.warm(8, 4, batch_sizes=[1, 2, 4])
-        compiled_nb = {k[0] for k in eng._admit_jits}
-        assert {1, 2, 4} <= compiled_nb, compiled_nb
-    finally:
-        eng.stop()
-
-
 def test_top_once_covers_trainer_and_inference(model, capsys):
     """Acceptance: `slt top --once` renders a one-shot cluster snapshot
     spanning one trainer and one inference server."""

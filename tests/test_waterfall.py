@@ -1,6 +1,6 @@
 """Request waterfalls (round 21): the per-request lifecycle ledger, its
-attribution contract, the `slt waterfall` merge/decomposition pipeline,
-router hop provenance, and the static engine's reduced ledger.
+attribution contract, the `slt waterfall` merge/decomposition pipeline
+and router hop provenance.
 
 The attribution contract under test: interval causes (compile,
 harvest_drain) claim their measured overlap with a stalled gap (scaled
@@ -17,13 +17,10 @@ import os
 import threading
 import time
 
-import jax
-import jax.numpy as jnp
 import pytest
 
 from serverless_learn_tpu.telemetry import waterfall
-from serverless_learn_tpu.telemetry.registry import (
-    JsonlEventLog, MetricsRegistry, Span)
+from serverless_learn_tpu.telemetry.registry import MetricsRegistry, Span
 from serverless_learn_tpu.telemetry.waterfall import (
     BoundaryEvents, RequestWaterfall)
 
@@ -181,65 +178,6 @@ def test_render_shows_phases_and_stall_causes():
     out = waterfall.render(waterfall.report([FIXTURE]))
     for needle in ("TTFT", "ITL", "stall", "queue", "prefill"):
         assert needle in out, needle
-
-
-# -- static engine: reduced ledger, TTFT == latency --------------------------
-
-
-def test_static_engine_ttft_is_latency_with_reduced_waterfall(tmp_path):
-    """Run-to-completion groups deliver first and last token together,
-    so the static engine's TTFT histogram IS its latency histogram, and
-    its waterfall is the reduced set: queue/admit/compile/generate with
-    no decode phase and no decode trace."""
-    from serverless_learn_tpu.inference.batching import BatchingEngine
-    from serverless_learn_tpu.models.registry import get_model
-
-    bundle = get_model("llama_tiny", dtype=jnp.float32,
-                       param_dtype=jnp.float32, max_seq_len=64)
-    params = bundle.module.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
-    events = tmp_path / "events.jsonl"
-    log = JsonlEventLog(str(events))
-    reg = MetricsRegistry()
-    eng = BatchingEngine(bundle.module, params, registry=reg,
-                         event_log=log)
-    try:
-        for _ in range(2):                # cold group, then warm
-            rep = eng.submit([3, 5, 7, 9], max_new=4, temperature=0.0,
-                             top_k=0, eos_id=None, seed=0)
-            assert "new_tokens" in rep, rep
-    finally:
-        eng.stop()
-        log.close()
-    snap = reg.snapshot()
-
-    def hist(name):
-        s = snap[name]["series"][0]
-        return s["count"], s["sum"]
-
-    ttft_n, ttft_sum = hist("slt_request_ttft_seconds")
-    lat_n, lat_sum = hist("slt_request_latency_seconds")
-    assert ttft_n == lat_n == 2
-    assert ttft_sum == pytest.approx(lat_sum)
-
-    recs = [r for r in waterfall.read_records([str(events)])
-            if isinstance(r.get("waterfall"), dict)]
-    assert len(recs) == 2
-    cold, warm = sorted(recs, key=lambda r: r["t0_unix_s"])
-    for r in (cold, warm):
-        wf = r["waterfall"]
-        names = [p["phase"] for p in wf["phases"]]
-        assert names == ["queue", "admit", "compile", "generate"]
-        assert "itl" not in wf and "gaps" not in wf and "stalls" not in wf
-        d = wf["ttft_decomp_s"]
-        assert sum(d.values()) == pytest.approx(wf["ttft_s"], abs=5e-6)
-    # The cold group charges the jit wall to compile; the warm one not.
-    assert cold["waterfall"]["ttft_decomp_s"]["compile"] > 0.0
-    assert warm["waterfall"]["ttft_decomp_s"]["compile"] == 0.0
-    # `slt waterfall` accepts a pure-static log (no decode trace at all).
-    s = waterfall.report([str(events)])["summary"]
-    assert s["requests"] == 2
-    assert s["invariants"]["ttft_decomp_bad"] == 0
 
 
 # -- router hop provenance ---------------------------------------------------

@@ -217,16 +217,19 @@ def test_tiny_train_attribution_agrees_with_ledger(tmp_path):
     batch = trainer.shard_batch(next(src))
     ledger = PhaseLedger(emit=False)
     ledger.ensure_started()
+    # Wait for the whole step, not only its loss: the update's last ops
+    # on a slow device thread otherwise land inside the capture (or fall
+    # out of its end) when the host is loaded, and tear a step.
     with ledger.phase("compile"):
         state, m = trainer.step(state, batch)
-        float(jax.device_get(m["loss"]))
+        jax.block_until_ready((state, m))
     n_steps = 4
     out = str(tmp_path / "capture")
     with profiler.capture_session(out):
         for _ in range(n_steps):
             with ledger.phase("step"):
                 state, m = trainer.step(state, batch)
-                float(jax.device_get(m["loss"]))
+                jax.block_until_ready((state, m))
     s = xray.analyze_dir(out, n_devices=n_dev)
     assert s["coverage_frac"] >= 0.95, s["classes"]
     assert s["steps"]["n"] == n_steps
