@@ -6,7 +6,15 @@ Forward: Q is blocked over the grid, K/V stream through VMEM in ``block_k``
 tiles with online-softmax accumulation in fp32, so the [T, S] score matrix
 never hits HBM — the HBM-bandwidth win flash attention exists for.
 Scores/accumulation run on the MXU via ``dot_general`` with
-``preferred_element_type=float32``.
+``preferred_element_type=float32``. A block does per score element only
+what it needs (PR 32): one that no mask can touch (``_block_kind``:
+*interior*) takes a path with no iota, compare or select, in all three
+kernels; the forward's running maximum and sum never leave the
+[block_q, 128] layout of their scratch (a [block_q] vector and the
+[block_q, 1] column the scores need are different layouts, and changing
+between them some eight times a block was 2.4 ms of the forward's 6.5 at
+the training cell's shape); and a block above the causal diagonal fetches
+no K or V.
 
 Backward: two Pallas kernels recomputing scores from the saved logsumexp —
 ``dq`` (grid over Q blocks, K/V streaming) and ``dkv`` (grid over K/V
@@ -42,12 +50,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 _NEG = -1e30
-# Measured on a v5e chip (T=8192 causal fwd+bwd, B=1 H=8 D=64 bf16):
-# 128-blocks 41 ms, 256 26 ms, 512 16 ms, 1024 15 ms — grid-step overhead
-# dominates small blocks. 512 is the default ceiling (1024 is marginal and
-# doubles VMEM pressure); shorter sequences drop to the largest divisor.
-# A later same-shape run with these defaults measured 13.9 ms — chip-load
-# variance of a few ms between runs is normal; treat 14-16 ms as the band.
+# The largest candidate that divides the length is the block, for Q and
+# for K alike. Measured on one TPU v5 lite at the training cell's shape
+# (B 2, H 32 over K 8, T = S = 4,096, D 128, bf16, causal; one call, host
+# clock over 20 calls; my chip runs, PR 32), forward | dq + dk/dv:
+# 128 blocks 24.5 | 43.5 ms, 256 8.08 | 19.6, 512 3.61 | 9.67 (least times
+# 1.40 | 3.49). A grid step costs some 0.5 us whether its block computes
+# or is skipped (4,096 empty steps: 2.05 ms), which is what small blocks
+# pay. Not candidates: 1,024 x 1,024 read 3.18 | 8.52 and 512 x 1,024
+# 3.53 | 8.85, a tenth less, at four times and twice the score tile in
+# VMEM and with half the causal blocks on the masked path.
 _BLOCK_CANDIDATES = (512, 256, 128)
 
 # The kernels' names are an interface: ``pallas_call(name=)`` puts them
@@ -67,51 +79,137 @@ def _pick_block(n: int):
     return None
 
 
-def _masked_exp(s, ref):
-    """exp(s - ref) that treats mask-floor scores as exactly zero
+def _block_kind(i, j, block_q, block_k, causal, mask_mode, vlen):
+    """(active, interior) of the block of Q block ``i`` and K block ``j``.
+
+    *Active*: some score of the block can be unmasked, so it is computed;
+    the others are skipped. *Interior*: no score of it can be masked, so
+    it takes the path with no iota, compare or select. Decided from the
+    block's position, the causal flag, the mode and the valid length
+    alone, with operators that Python ints, numpy arrays
+    (``block_census``) and the kernels' traced scalars all take: this is
+    the one definition all three kernels and the census share. A "rows"
+    block is never interior (its mask is data); a padded Q block
+    (``i * block_q >= vlen``) is skipped in the "len" mode only, where q
+    and k share positions (see ``_fwd_kernel``)."""
+    active = interior = True
+    if causal:
+        active = j * block_k <= (i + 1) * block_q - 1
+        interior = (j + 1) * block_k - 1 <= i * block_q
+    if vlen is not None:
+        active = active & (j * block_k < vlen)
+        interior = interior & ((j + 1) * block_k <= vlen)
+        if mask_mode == "len":
+            active = active & (i * block_q < vlen)
+    # A plain False, so that ``_per_kind`` emits no unmasked body at all.
+    return active, interior if mask_mode != "rows" else False
+
+
+def block_census(T, S, block_q, block_k, causal, vlen=None, mask_mode=None):
+    """{"skipped", "interior", "masked"}: how many of the
+    ``(T // block_q) x (S // block_k)`` blocks of one (batch row, head)
+    the kernels skip, run on the unmasked path and run on the masked
+    path. Shapes decide it, so nothing counts at run time. ``vlen`` is
+    that batch row's valid length; ``mask_mode`` defaults to "len" with
+    a length and "none" without."""
+    import numpy as np
+
+    if mask_mode is None:
+        mask_mode = "none" if vlen is None else "len"
+    i = np.arange(T // block_q)[:, None]
+    j = np.arange(S // block_k)[None, :]
+    active, interior = _block_kind(i, j, block_q, block_k, causal, mask_mode,
+                                   vlen)
+    active = np.broadcast_to(active, (i.size, j.size))
+    interior = np.broadcast_to(interior, (i.size, j.size)) & active
+    return {"skipped": int((~active).sum()), "interior": int(interior.sum()),
+            "masked": int((active & ~interior).sum())}
+
+
+def _per_kind(active, interior, compute):
+    """Run ``compute(masked)`` on an active block: ``masked=False`` where
+    the block is interior. A kind that the call's shape rules out is not
+    emitted."""
+    if interior is not False:
+        pl.when(jnp.logical_and(active, interior))(lambda: compute(False))
+    if interior is not True:
+        pl.when(jnp.logical_and(active, jnp.logical_not(interior)))(
+            lambda: compute(True))
+
+
+def _probs(s, ref, masked):
+    """exp(s - ref); on the masked path mask-floor scores are exactly zero
     probability (see numerics note in the module docstring)."""
-    return jnp.where(s <= _NEG * 0.5, 0.0, jnp.exp(s - ref))
+    p = jnp.exp(s - ref)
+    return jnp.where(s <= _NEG * 0.5, 0.0, p) if masked else p
 
 
-def _score_block(q, k, scale, i, j, block_q, block_k, causal, mask_ref,
-                 vlen=None):
-    """[block_q, block_k] fp32 scores with causal/padding masking applied.
+def _scores(q, k, scale, masked, *, q0=0, k0=0, causal=False, vlen=None,
+            valid=None):
+    """[rows of q, rows of k] fp32 scores. ``masked``: the causal and
+    padding masks are applied (``q0`` / ``k0`` are the positions of q's
+    and k's first rows); an interior block (``_block_kind``) passes False
+    and gets ``qk * scale`` alone, the same bits the masked path gives it.
 
-    Two padding-mask mechanisms, measured on a v5e chip:
+    Two padding-mask mechanisms:
     * ``vlen`` (suffix padding, the common case): a per-row valid length
-      read from SMEM — masking is the same iota-compare as causal, nearly
-      free, and the caller skips fully-padded blocks outright.
-    * ``mask_ref`` (arbitrary [B, S] masks): this batch row's ENTIRE mask
-      as [1, n_k, block_k] (index map (b, 0, 0), revisited so the DMA only
-      fires when b advances). The per-block dynamic-sublane row read costs
-      ~1.7x end to end — other layouts were worse: a (1, 1, block_k) tile
-      re-DMAs 2 KB every innermost step (latency-bound), and a
-      [B, block_k] tile forces a dynamic-sublane gather.
+      read from SMEM — masking is the same iota-compare as causal, and
+      the caller skips fully-padded blocks outright.
+    * ``valid`` (arbitrary [B, S] masks): this block's row of the batch
+      row's mask, which the kernel holds ENTIRE as [1, n_k, block_k]
+      (index map (b, 0, 0), revisited so the DMA only fires when b
+      advances; a (1, 1, block_k) tile re-DMAs 2 KB every innermost
+      step, and a [B, block_k] tile forces a dynamic-sublane gather). It
+      is ADDED as a [1, block_k] row of 0 and ``_NEG`` (``s + _NEG`` is
+      ``_NEG`` to the bit for any score that is one), not selected by: a
+      select on a mask row broadcast down the sublanes made the forward
+      2.0-2.6x the unmasked call on this chip, the added row 1.01-1.02x
+      (TPU v5 lite, forward alone, PR 32: B 16 H 12 T 512 D 64 1.16 ms
+      selected, 0.452 added, 0.442 unmasked; B 2 H 32/8 T 4,096 D 128
+      21.4, 4.63, 4.57).
     """
     # q/k stay in storage dtype (bf16 on TPU): the MXU runs bf16 inputs at
     # full rate with fp32 accumulation; upcasting first would halve it.
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
+    if not masked:
+        return s
+    if valid is not None:
+        s = s + jnp.where(valid, 0.0, _NEG)[None, :]
     if causal:
-        q_pos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos <= q_pos, s, _NEG)
     if vlen is not None:
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos < vlen, s, _NEG)
-    if mask_ref is not None:
-        valid = mask_ref[0, j, :] != 0  # [block_k] padding row
-        s = jnp.where(valid[None, :], s, _NEG)
     return s
+
+
+def _mask_row(mask_ref, j):
+    """K block ``j``'s [block_k] row of a "rows" call's mask, as bools."""
+    return None if mask_ref is None else mask_ref[0, j, :] != 0
+
+
+def _lanes(x, n: int):
+    """A [rows, 128] array whose lanes all hold the row's value, as
+    [rows, n]."""
+    if n <= x.shape[1]:
+        return x[:, :n]
+    if n % x.shape[1] == 0:
+        return jnp.tile(x, (1, n // x.shape[1]))
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+
+
+def _causal_last_j(i, block_q, block_k):
+    """Last K block that Q block ``i`` attends to under the causal mask."""
+    return ((i + 1) * block_q - 1) // block_k
 
 
 def _fwd_kernel(*refs, scale: float, causal: bool, mask_mode: str):
@@ -132,27 +230,24 @@ def _fwd_kernel(*refs, scale: float, causal: bool, mask_mode: str):
     n_k = pl.num_programs(3)
     block_q = q_ref.shape[2]
     block_k = k_ref.shape[2]
-    # Last K/V block this Q block attends to (blocks fully above the causal
-    # diagonal are skipped — compute and final write both key off last_j).
+    D = q_ref.shape[3]
+    # Last K/V block this Q block attends to: the final write keys off it
+    # (blocks fully above the causal diagonal are skipped, ``_block_kind``).
     if causal:
-        last_j = jnp.minimum(n_k - 1, ((i + 1) * block_q - 1) // block_k)
+        last_j = jnp.minimum(n_k - 1, _causal_last_j(i, block_q, block_k))
     else:
         last_j = n_k - 1
     vlen = vlen_ref[pl.program_id(0)] if vlen_ref is not None else None
-    active = j <= last_j
-    if vlen is not None:
-        # Fully-padded K blocks contribute nothing — skip them (this is
-        # where suffix padding becomes FREE, not just correct).
-        active = jnp.logical_and(active, j * block_k < vlen)
-    if vlen is not None and mask_mode == "len":
-        # SELF-attention only ("len"): q and kv share positions, so q rows
-        # >= vlen are padding queries whose outputs are loss-masked — skip
-        # their blocks too. A skipped Q block's output is zeros via the
-        # unconditional init+finalize; its lse is garbage, which is safe
-        # ONLY because the backward kernels skip the same blocks. Ring
-        # hops use "klen": their q is a DIFFERENT sequence shard than the
-        # kv the lengths describe, so every q block computes.
-        active = jnp.logical_and(active, i * block_q < vlen)
+    # Skipped: blocks above the causal diagonal; fully-padded K blocks
+    # (this is where suffix padding becomes FREE, not just correct); and,
+    # in SELF-attention only ("len": q and kv share positions), Q blocks
+    # of padding queries, whose outputs are loss-masked. A skipped Q
+    # block's output is zeros via the unconditional init+finalize; its lse
+    # is garbage, which is safe ONLY because the backward kernels skip the
+    # same blocks. Ring hops use "klen": their q is a DIFFERENT sequence
+    # shard than the kv the lengths describe, so every q block computes.
+    active, interior = _block_kind(i, j, block_q, block_k, causal, mask_mode,
+                                   vlen)
 
     @pl.when(j == 0)
     def _init():
@@ -160,33 +255,46 @@ def _fwd_kernel(*refs, scale: float, causal: bool, mask_mode: str):
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(active)
-    def _compute():
+    # m and l live as [block_q, 128] and are never read as one column: m
+    # holds the row's maximum in every lane, l 128 partial sums per row
+    # that ``_finalize`` adds up, so the row sum costs no reduction across
+    # lanes per block, and no statistic changes layout between a
+    # [block_q] vector and the [block_q, 1] column the scores need.
+    lanes = l_ref.shape[1]
+
+    def _compute(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
-        s = _score_block(q, k, scale, i, j, block_q, block_k, causal,
-                         mask_ref, vlen)
-        m_prev = m_ref[:, 0]
-        l_prev = l_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = _masked_exp(s, m_new[:, None])
-        alpha = jnp.exp(jnp.maximum(m_prev - m_new, _NEG))
         v = v_ref[0, 0]
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        s = _scores(q, k, scale, masked, q0=i * block_q, k0=j * block_k,
+                    causal=causal, vlen=vlen,
+                    valid=_mask_row(mask_ref, j))
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = _probs(s, _lanes(m_new, block_k), masked)
+        alpha = jnp.exp(jnp.maximum(m_prev - m_new, _NEG))
+        if block_k % lanes == 0:
+            l_add = functools.reduce(jnp.add, (
+                p[:, c:c + lanes] for c in range(0, block_k, lanes)))
+        else:  # a block that is no whole number of lanes: lane 0 sums
+            l_add = jnp.pad(p.sum(axis=-1, keepdims=True),
+                            ((0, 0), (0, lanes - 1)))
+        l_ref[...] = l_ref[...] * alpha + l_add
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, D) + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(
-            (l_prev * alpha + p.sum(axis=-1))[:, None], l_ref.shape)
+        m_ref[...] = m_new
+
+    _per_kind(active, interior, _compute)
 
     @pl.when(j == last_j)
     def _finalize():
-        l = jnp.maximum(l_ref[:, 0], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...].sum(axis=-1, keepdims=True), 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         # lse is laid out [1, 1, n_q, block_q] (whole (n_q, block_q) tail —
         # Mosaic rejects (1, block_q) tails, and a dynamic LANE offset
         # store is unimplemented; a dynamic SUBLANE index is fine).
-        lse_ref[0, 0, i, :] = m_ref[:, 0] + jnp.log(l)
+        lse_ref[0, 0, i, :] = (m_ref[:, :1] + jnp.log(l))[:, 0]
 
 
 def _mask_operand(mask_arg, mask_mode, B, S, block_k):
@@ -203,6 +311,19 @@ def _mask_operand(mask_arg, mask_mode, B, S, block_k):
     return [], [], [], []
 
 
+# Forward and backward are jitted so that a program which calls them from
+# every layer traces and lowers each once: a model whose layers are unrolled
+# otherwise traces the kernels' bodies and serialises them again at every
+# call site, on every start, compile cache or not (the training cell's 63
+# call sites: 2.5 s of lowering, 0.2 s so; a second body per kernel, the
+# interior path, had made it 3.4-4.3 s). The kernels' instruction names
+# in the compiled program are what they were (``flash_fwd.<n>``).
+_kernel_jit = functools.partial(
+    jax.jit, static_argnums=(4,),
+    static_argnames=("causal", "block_q", "block_k", "interpret"))
+
+
+@_kernel_jit
 def _flash_fwd_bhsd(q, k, v, mask_arg, mask_mode, *, causal: bool,
                     block_q: int, block_k: int, interpret: bool):
     """q [B,H,T,D]; k,v [B,K,S,D] with H % K == 0 (GQA via index map).
@@ -221,21 +342,24 @@ def _flash_fwd_bhsd(q, k, v, mask_arg, mask_mode, *, causal: bool,
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                mask_mode=mask_mode)
     sf, sb, af, ab = _mask_operand(mask_arg, mask_mode, B, S, block_k)
-    in_specs = sf + [
-        pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, i, j: (b, h // group, j, 0)),
-        pl.BlockSpec((1, 1, block_k, D),
-                     lambda b, h, i, j: (b, h // group, j, 0)),
-    ] + sb
-    args = af + [q, k, v] + ab
+
+    def kv_index(b, h, i, j):
+        # A block above the causal diagonal is skipped; naming the row's
+        # last active block again makes the pipeline see an unchanged
+        # block index and fetch nothing for it.
+        if causal:
+            j = jnp.minimum(j, _causal_last_j(i, block_q, block_k))
+        return (b, h // group, j, 0)
+
+    qspec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    kspec = pl.BlockSpec((1, 1, block_k, D), kv_index)
     out, lse = pl.pallas_call(
         kernel,
         name=FWD_KERNEL,
         grid=grid,
-        in_specs=in_specs,
+        in_specs=sf + [qspec, kspec, kspec] + sb,
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
+            qspec,
             pl.BlockSpec((1, 1, T // block_q, block_q),
                          lambda b, h, i, j: (b, h, 0, 0)),
         ],
@@ -245,11 +369,11 @@ def _flash_fwd_bhsd(q, k, v, mask_arg, mask_mode, *, causal: bool,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),    # acc
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running max (lanes bcast)
-            pltpu.VMEM((block_q, 128), jnp.float32),  # running sum
+            pltpu.VMEM((block_q, 128), jnp.float32),  # running max, every lane
+            pltpu.VMEM((block_q, 128), jnp.float32),  # running sum, per lane
         ],
         interpret=interpret,
-    )(*args)
+    )(*af, q, k, v, *ab)
     return out, lse
 
 
@@ -275,39 +399,38 @@ def _bwd_dq_kernel(*refs, scale: float, causal: bool, mask_mode: str):
     block_q = q_ref.shape[2]
     block_k = k_ref.shape[2]
     if causal:
-        last_j = jnp.minimum(n_k - 1, ((i + 1) * block_q - 1) // block_k)
+        last_j = jnp.minimum(n_k - 1, _causal_last_j(i, block_q, block_k))
     else:
         last_j = n_k - 1
     vlen = vlen_ref[pl.program_id(0)] if vlen_ref is not None else None
-    active = j <= last_j
-    if vlen is not None:
-        # Mirror the forward's K skips.
-        active = jnp.logical_and(active, j * block_k < vlen)
-    if vlen is not None and mask_mode == "len":
-        # Self-attention only: padded Q rows get dq = 0 (see _fwd_kernel).
-        active = jnp.logical_and(active, i * block_q < vlen)
+    # The forward's skips, mirrored: padded Q rows get dq = 0 (see
+    # _fwd_kernel).
+    active, interior = _block_kind(i, j, block_q, block_k, causal, mask_mode,
+                                   vlen)
 
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(active)
-    def _compute():
+    def _compute(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         g = g_ref[0, 0]
-        s = _score_block(q, k, scale, i, j, block_q, block_k, causal,
-                         mask_ref, vlen)
+        s = _scores(q, k, scale, masked, q0=i * block_q, k0=j * block_k,
+                    causal=causal, vlen=vlen,
+                    valid=_mask_row(mask_ref, j))
         lse = lse_ref[0, 0, i, :]
         delta = delta_ref[0, 0, i, :]
-        p = _masked_exp(s, lse[:, None])
+        p = _probs(s, lse[:, None], masked)
         dp = jax.lax.dot_general(g, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = (p * (dp - delta[:, None]) * scale).astype(k.dtype)
         acc_ref[...] += jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    _per_kind(active, interior, _compute)
 
     @pl.when(j == last_j)
     def _fin():
@@ -332,36 +455,31 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, mask_mode: str):
     n_q = pl.num_programs(3)
     block_q = q_ref.shape[2]
     block_k = k_ref.shape[2]
-    # First Q block at or below the causal diagonal for this K block.
-    first_i = (j * block_k) // block_q if causal else 0
     vlen = vlen_ref[pl.program_id(0)] if vlen_ref is not None else None
-    active = i >= first_i
-    if vlen is not None:
-        # A fully-padded K block receives zero gradient.
-        active = jnp.logical_and(active, j * block_k < vlen)
-    if vlen is not None and mask_mode == "len":
-        # Self-attention only: a fully-padded Q block MUST be skipped —
-        # the forward skipped it, so its saved lse is garbage and
-        # exp(s - lse) would be inf (NaN through 0*inf). "klen" (ring
-        # hops) computes every q block, and its forward wrote real lse.
-        active = jnp.logical_and(active, i * block_q < vlen)
+    # The forward's skips, mirrored. A fully-padded K block receives zero
+    # gradient. In self-attention ("len") a fully-padded Q block MUST be
+    # skipped — the forward skipped it, so its saved lse is garbage and
+    # exp(s - lse) would be inf (NaN through 0*inf). "klen" (ring hops)
+    # computes every q block, and its forward wrote real lse.
+    active, interior = _block_kind(i, j, block_q, block_k, causal, mask_mode,
+                                   vlen)
 
     @pl.when(i == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(active)
-    def _compute():
+    def _compute(masked):
         q = q_ref[0, 0]
         k = k_ref[0, 0]
         v = v_ref[0, 0]
         g = g_ref[0, 0]
-        s = _score_block(q, k, scale, i, j, block_q, block_k, causal,
-                         mask_ref, vlen)
+        s = _scores(q, k, scale, masked, q0=i * block_q, k0=j * block_k,
+                    causal=causal, vlen=vlen,
+                    valid=_mask_row(mask_ref, j))
         lse = lse_ref[0, 0, i, :]
         delta = delta_ref[0, 0, i, :]
-        p = _masked_exp(s, lse[:, None])  # [block_q, block_k]
+        p = _probs(s, lse[:, None], masked)  # [block_q, block_k]
         dv_acc[...] += jax.lax.dot_general(
             p.astype(g.dtype), g, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -372,12 +490,15 @@ def _bwd_dkv_kernel(*refs, scale: float, causal: bool, mask_mode: str):
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    _per_kind(active, interior, _compute)
+
     @pl.when(i == n_q - 1)
     def _fin():
         dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+@_kernel_jit
 def _flash_bwd_bhsd(q, k, v, mask_arg, mask_mode, lse, g, out, *,
                     causal: bool, block_q: int, block_k: int,
                     interpret: bool, g_lse=None):
@@ -623,8 +744,8 @@ def flash_attention(
       The CALLER asserts suffix-ness; a non-suffix mask squeezed into
       lengths would be silently wrong.
     * ``mask`` [B, S] or [B, 1, 1, S] (nonzero = attend) — arbitrary
-      per-key validity, runs in-kernel at ~1.7x the unmasked cost
-      (measured; the per-block mask row is a dynamic-sublane read).
+      per-key validity, in-kernel at 1.01-1.02x the unmasked forward
+      (``_scores`` has the readings), with no block skipped.
     * other mask forms, and shapes the kernels can't tile, raise
       ``ValueError`` (see ``untileable_reason``).
 

@@ -150,3 +150,88 @@ def test_transformer_with_flash_impl():
     l_dense, _ = b_dense.loss_fn(params, batch)
     l_flash, _ = b_flash.loss_fn(params, batch)
     np.testing.assert_allclose(float(l_dense), float(l_flash), rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# PR 32: which blocks run which path
+# ---------------------------------------------------------------------------
+
+
+def _dense_census(T, S, bq, bk, causal, vlen, mode):
+    """Skipped / interior / masked blocks counted from the dense mask."""
+    keys = np.ones((T, S), bool)
+    if causal:
+        keys &= np.arange(S)[None, :] <= np.arange(T)[:, None]
+    if vlen is not None:
+        keys &= np.arange(S)[None, :] < vlen
+    live = keys.copy()
+    if mode == "len":  # self-attention: padding queries are not computed
+        live &= np.arange(T)[:, None] < vlen
+    out = {"skipped": 0, "interior": 0, "masked": 0}
+    for i in range(T // bq):
+        for j in range(S // bk):
+            blk = np.s_[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+            if not live[blk].any():
+                out["skipped"] += 1
+            elif mode != "rows" and keys[blk].all():
+                out["interior"] += 1
+            else:
+                out["masked"] += 1
+    return out
+
+
+@pytest.mark.parametrize("T,S,bq,bk", [
+    (512, 512, 128, 256), (512, 512, 256, 128), (512, 1024, 128, 128),
+    (1024, 512, 512, 512)])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("vlen,mode", [
+    (None, "none"), (None, "rows"), (300, "len"), (256, "len"), (100, "len"),
+    (0, "len"), (300, "klen"), (512, "klen")])
+def test_block_census_counts_what_the_dense_mask_holds(T, S, bq, bk, causal,
+                                                       vlen, mode):
+    from serverless_learn_tpu.ops.pallas.flash_attention import block_census
+
+    got = block_census(T, S, bq, bk, causal, vlen, mask_mode=mode)
+    assert got == _dense_census(T, S, bq, bk, causal, vlen, mode)
+    assert sum(got.values()) == (T // bq) * (S // bk)
+
+
+def test_block_census_of_the_training_cell():
+    """mistral7b-lora-train-4k: T = S = 4,096 causal in the blocks
+    ``_pick_block`` gives. Of 64 blocks a (batch row, head) skips 28, runs
+    28 with no mask and 8, the diagonal, with it."""
+    from serverless_learn_tpu.ops.pallas.flash_attention import (
+        _pick_block, block_census)
+
+    b = _pick_block(4096)
+    assert b == 512
+    assert block_census(4096, 4096, b, b, True) == {
+        "skipped": 28, "interior": 28, "masked": 8}
+    assert block_census(4096, 4096, b, b, False) == {
+        "skipped": 0, "interior": 64, "masked": 0}
+
+
+@pytest.mark.parametrize("causal,vlen", [(True, None), (False, 700),
+                                         (True, 700)])
+def test_interior_scores_equal_masked_scores_to_the_bit(causal, vlen):
+    """On a block that ``_block_kind`` calls interior, the path without
+    iota, compare and select returns the bits of the path with them:
+    scores and probabilities."""
+    from serverless_learn_tpu.ops.pallas import flash_attention as fa
+
+    bq, bk, i, j = 128, 256, 2, 0
+    active, interior = fa._block_kind(i, j, bq, bk, causal, "len" if vlen
+                                      else "none", vlen)
+    assert active and interior
+    rng = jax.random.PRNGKey(5)
+    q = jax.random.normal(rng, (bq, 64), jnp.bfloat16)
+    k = jax.random.normal(jax.random.fold_in(rng, 1), (bk, 64), jnp.bfloat16)
+    ref = jax.random.normal(jax.random.fold_in(rng, 2), (bq, 1))
+    kw = dict(q0=i * bq, k0=j * bk, causal=causal, vlen=vlen)
+    s_int = fa._scores(q, k, 0.125, False, **kw)
+    s_msk = fa._scores(q, k, 0.125, True, **kw)
+    np.testing.assert_array_equal(np.asarray(s_int), np.asarray(s_msk))
+    np.testing.assert_array_equal(np.asarray(fa._probs(s_int, ref, False)),
+                                  np.asarray(fa._probs(s_msk, ref, True)))
+    # and one block to the right the causal mask bites: not interior
+    assert not fa._block_kind(0, 0, bq, bk, True, "none", None)[1]

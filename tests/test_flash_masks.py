@@ -194,3 +194,115 @@ def test_fully_padded_row_is_nan_free(qkv):
         *a, kv_lengths=jnp.asarray(lens, jnp.int32)) * w).sum(),
         (0, 1, 2))(q, k, v)
     assert not any(bool(jnp.isnan(x).any()) for x in g)
+
+
+# ---------------------------------------------------------------------------
+# PR 32: skipped, interior and masked blocks in one call, block_q != block_k
+# ---------------------------------------------------------------------------
+
+_T, _BQ, _BK = 512, 128, 256  # 4 x 2 blocks: all three kinds under causal
+
+
+def _mixed_qkv(seed=11, B=2, H=4, K=1, D=128):
+    rng = np.random.default_rng(seed)
+    return (_rand(rng, B, _T, H, D), _rand(rng, B, _T, K, D),
+            _rand(rng, B, _T, K, D))
+
+
+# second row's valid length: inside a K block, on a K block's edge, and
+# shorter than one K block (so the row's only active block is masked).
+_VLENS = {"inside": 300, "edge": 256, "short": 100}
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("mode,vlen", [
+    ("none", None), ("rows", "inside"), ("len", "inside"), ("len", "edge"),
+    ("len", "short")])
+def test_mixed_blocks_forward_and_grads(mode, vlen, causal):
+    """Forward and all three gradients against dense attention where the
+    grid holds skipped, interior and masked blocks at once, with GQA 4:1,
+    D 128 and block_q != block_k."""
+    q, k, v = _mixed_qkv()
+    lens = [_T, _VLENS[vlen]] if vlen else [_T, _T]
+    mask2 = _suffix_mask(lens, _T)
+    m4 = jnp.asarray(mask2)[:, None, None, :]
+    w = jnp.asarray(mask2)[:, :, None, None]
+    kwargs = dict(causal=causal, block_q=_BQ, block_k=_BK)
+    if mode == "rows":
+        kwargs["mask"] = m4
+    elif mode == "len":
+        kwargs["kv_lengths"] = jnp.asarray(lens, jnp.int32)
+    dense = dict(causal=causal, mask=None if mode == "none" else m4)
+    o_f = flash_attention(q, k, v, **kwargs)
+    o_x = xla_attention(q, k, v, **dense)
+    assert float(jnp.abs((o_f - o_x) * w).max()) < 2e-5
+    _check_grads(lambda *a: flash_attention(*a, **kwargs),
+                 lambda *a: xla_attention(*a, **dense), (q, k, v), w,
+                 tol=5e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("vlen", ["inside", "edge", "short"])
+def test_mixed_blocks_klen_with_lse(vlen, causal):
+    """The ring hop's entry ("klen": the lengths describe the keys alone,
+    every Q block computes), output and logsumexp and the gradients
+    through both, against dense attention."""
+    from serverless_learn_tpu.ops.pallas.flash_attention import (
+        flash_with_lse_bhsd)
+
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in _mixed_qkv(B=1))
+    n = _VLENS[vlen]
+    lens = jnp.asarray([n], jnp.int32)
+    keep = jnp.arange(_T)[None, :] < n
+    if causal:
+        keep = keep & (jnp.arange(_T)[None, :] <= jnp.arange(_T)[:, None])
+    keep = jnp.broadcast_to(keep, (_T, _T))
+
+    def dense(q, k, v):
+        kk, vv = (jnp.repeat(x, q.shape[1] // x.shape[1], 1) for x in (k, v))
+        s = jnp.einsum("bhtd,bhsd->bhts", q, kk) * q.shape[-1] ** -0.5
+        s = jnp.where(keep, s, -jnp.inf)
+        lse = jax.nn.logsumexp(s, -1)
+        return jnp.einsum("bhts,bhsd->bhtd", jnp.exp(s - lse[..., None]),
+                          vv), lse
+
+    def flash(q, k, v):
+        return flash_with_lse_bhsd(q, k, v, lens, "klen", causal, _BQ, _BK,
+                                   True)
+
+    (o_f, l_f), (o_x, l_x) = flash(q, k, v), dense(q, k, v)
+    assert float(jnp.abs(o_f - o_x).max()) < 2e-5
+    assert float(jnp.abs(l_f - l_x).max()) < 2e-5
+
+    def scalar(f):
+        return lambda *a: sum((x ** 2).sum() for x in f(*a))
+
+    gf = jax.grad(scalar(flash), (0, 1, 2))(q, k, v)
+    gx = jax.grad(scalar(dense), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gx):
+        assert float(jnp.abs(a - b).max()) < 5e-4, name
+
+
+def test_interior_and_masked_kernels_agree():
+    """A call whose every block is interior (lengths = the whole row) and
+    the same call on the masked path (a "rows" mask of ones) agree to
+    rounding, forward and backward: what the interior path leaves out
+    changes nothing where nothing can be masked. (The two paths' scores
+    and probabilities are the same bits, which
+    ``test_interior_scores_equal_masked_scores_to_the_bit`` pins op by op;
+    whole kernels differ in the last place on the CPU, whose compiler
+    contracts ``qk * scale - m`` into one rounding on the path that has no
+    select in between.)"""
+    q, k, v = _mixed_qkv(B=1)
+    ones = jnp.ones((1, _T), jnp.int32)
+    full = jnp.asarray([_T], jnp.int32)
+    kw = dict(block_q=_BQ, block_k=_BK)
+
+    def run(**how):
+        f = lambda *a: flash_attention(*a, **kw, **how)
+        return (f(q, k, v),) + jax.grad(
+            lambda *a: (f(*a) ** 2).sum(), (0, 1, 2))(q, k, v)
+
+    for a, b in zip(run(kv_lengths=full), run(mask=ones)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=2e-6)
