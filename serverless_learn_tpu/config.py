@@ -424,8 +424,10 @@ class KVCacheConfig:
     prompt prefixes map to refcounted read-only blocks, copy-on-write at
     the first divergent block) and chunked prefill (``prefill_chunk``:
     long prompts split into chunks the scheduler interleaves between
-    decode chunks; how many an iteration dispatches the engine derives
-    from its own slots unless ``prefill_budget`` caps it).
+    decode chunks; a prefill program carries up to ``max_slots``
+    consecutive chunks, of one prompt or of several; how many programs
+    an iteration dispatches the engine derives from its own slots
+    unless ``prefill_budget`` caps it).
     """
 
     block_size: int = 16          # tokens per KV block (page)
@@ -434,14 +436,20 @@ class KVCacheConfig:
     # no-overcommit default; size it DOWN to overcommit memory (admission
     # backpressure + preemption keep it correct).
     num_blocks: int = 0
-    prefill_chunk: int = 32       # prompt tokens per prefill chunk (0 = whole)
+    # Prompt tokens per prefill chunk (0 = whole prompt): one ROW of a
+    # prefill program. A program's rows are consecutive chunks of the
+    # prompts that are mid-prefill, so it moves up to max_slots *
+    # prefill_chunk tokens whatever this is; a wider chunk buys nothing
+    # but more programs to compile.
+    prefill_chunk: int = 32
     # Bounds how long the rows that are decoding wait for their next
-    # chunk while prompts prefill. 0 = derived: every scheduler iteration
-    # feeds every prefilling slot, in at most chunk_size // 2 prefill
-    # programs while a slot decodes (about a third of the iteration) and
-    # unbounded while none does. > 0 = cap on the prompt tokens one
-    # iteration dispatches across all prefilling slots (an interactive
-    # deployment that wants a tighter stall).
+    # chunk while prompts prefill. 0 = derived: a scheduler iteration
+    # feeds the prefilling slots, oldest first, in at most
+    # chunk_size // 2 prefill programs while a slot decodes (at most
+    # about half of the iteration) and unbounded while none does. > 0 =
+    # cap on the prompt tokens one iteration dispatches across all
+    # prefilling slots (an interactive deployment that wants a tighter
+    # stall).
     prefill_budget: int = 0
     prefix_cache: bool = True     # shared-prefix block reuse (trie)
     # Max blocks the prefix trie may pin after their owners retire

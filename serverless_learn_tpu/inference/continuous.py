@@ -24,12 +24,21 @@ The slots' keys and values live in a **paged KV pool**
   absolute RoPE positions.
 * **Chunked prefill** (``prefill_chunk``): long prompts admit in chunks
   the scheduler interleaves between decode boundaries, so a 4k-token
-  prompt no longer stalls the decode batch for one giant admit. Every
-  iteration feeds EVERY mid-prefill slot, program after program, up to a
-  quota the engine derives from its own slots (``_prefill_steps``:
-  ``chunk_size // 2`` programs while a slot decodes, no bound while none
-  does; an explicit ``prefill_budget`` caps the iteration's prompt
-  tokens instead). Admission under pool pressure is TYPED
+  prompt no longer stalls the decode batch for one giant admit. A
+  prefill program carries up to ``max_slots`` rows of ``prefill_chunk``
+  tokens, and its rows are CONSECUTIVE CHUNKS of the prompts that are
+  mid-prefill, of one prompt or of several (``_prefill_step``): the
+  oldest admitted slot takes every row it has chunks and pages for,
+  then the next, so one stream of the weights moves up to ``max_slots
+  * prefill_chunk`` prompt tokens. Rows of one slot share its block
+  table and start where the row before ends; every layer writes all
+  rows' keys into the pool before any row reads its window. An
+  iteration dispatches such programs up to a quota the engine derives
+  from its own slots (``_prefill_steps``: ``chunk_size // 2`` programs
+  while a slot decodes, no bound while none does; an explicit
+  ``prefill_budget`` caps the iteration's prompt tokens instead); a
+  slot goes unfed only when older slots spent the quota or the pool
+  refused it pages. Admission under pool pressure is TYPED
   backpressure (the request stays queued, ``slt_kv_admit_blocked_total``
   counts, a ``kv.blocks_exhausted`` alert event fires for `slt doctor`);
   decode-time pressure first evicts cached prefixes, then deterministically
@@ -879,18 +888,20 @@ class ContinuousBatchingEngine:
         return admitted > 0
 
     def _prefill_steps(self) -> List[tuple]:
-        """This iteration's prefill programs: every mid-prefill slot is
-        fed, oldest admitted first, program after program, until no slot
-        is mid-prefill or the iteration's quota is spent.
+        """This iteration's prefill programs: the mid-prefill slots'
+        chunks, oldest admitted first, program after program, until no
+        slot is mid-prefill or the iteration's quota is spent.
 
         The quota bounds how long the rows that are decoding wait for
         their next chunk, in units the engine has: a prefill program
-        costs about one stream of the weights and a decode chunk
-        ``chunk_size`` of them, so with a slot decoding an iteration
-        dispatches at most ``chunk_size // 2`` programs (prefill is then
-        at most about a third of the iteration); with no slot decoding
-        there is nothing to stall and no bound. An explicit
-        ``prefill_budget`` caps the iteration's prompt tokens instead."""
+        costs one to two streams of the weights (one at a row or two;
+        at ``max_slots`` rows its matmuls take as long again) and a
+        decode chunk ``chunk_size`` of them, so with a slot decoding an
+        iteration dispatches at most ``chunk_size // 2`` programs
+        (prefill is then at most about half of the iteration); with no
+        slot decoding there is nothing to stall and no bound. An
+        explicit ``prefill_budget`` caps the iteration's prompt tokens
+        instead."""
         budget = self.prefill_budget or math.inf
         steps = math.inf
         if not self.prefill_budget and self._slot_census()[0]:
@@ -908,12 +919,19 @@ class ContinuousBatchingEngine:
 
     def _prefill_step(self, budget: float,
                       refused: set) -> Optional[tuple]:
-        """One prefill program: every mid-prefill slot advances by up to
-        ``prefill_chunk`` tokens, oldest admitted first; the rows stop at
-        the first that does not fit the token ``budget`` (``inf``: none).
-        A slot the pool refuses pages joins ``refused`` and sits out the
-        rest of the iteration (pages come back when a slot is released
-        or retired: at the decode dispatch or the harvest that follow)."""
+        """One prefill program. Its rows are ROW-CHUNKS, not slots: the
+        oldest admitted mid-prefill slot takes as many consecutive chunks
+        of its prompt as it has left (up to ``prefill_chunk`` tokens
+        each), then the next slot, until the program has ``max_slots``
+        rows, the token ``budget`` is spent (``inf``: none) or no slot is
+        left. Rows of one slot name the same block table at consecutive
+        start indices: each layer scatters every row's keys and values
+        into the pool before any row gathers its window, so a later
+        row's queries find the earlier rows' keys as if the chunks had
+        run in programs of their own. A slot the pool refuses pages
+        keeps the rows it got, joins ``refused`` and sits out the rest
+        of the iteration (pages come back when a slot is released or
+        retired: at the decode dispatch or the harvest that follow)."""
         rows = []
         for sid, r in enumerate(self._slots):
             if r is None or not r.prefilling or r.finished \
@@ -925,27 +943,39 @@ class ContinuousBatchingEngine:
                 continue
             rows.append((sid, r))
         rows.sort(key=lambda sr: sr[1].admit_seq)  # FIFO
-        batch = []
+        M = self.max_slots
+        batch = []   # (sid, r, start, tokens): the program's rows, in order
+        spent = False
         for sid, r in rows:
-            rem = len(r.prompt) - r.prefill_pos
-            tk = min(rem, self.prefill_chunk)
-            if tk > budget:
+            pos = r.prefill_pos
+            while pos < len(r.prompt) and len(batch) < M:
+                tk = min(len(r.prompt) - pos, self.prefill_chunk)
+                if tk > budget:
+                    spent = True
+                    break
+                if not self._ensure_pages(sid, pos + tk):
+                    self._note_kv_blocked()
+                    refused.add(sid)
+                    break
+                budget -= tk
+                batch.append((sid, r, pos, tk))
+                pos += tk
+            if spent or len(batch) == M:
                 break
-            if not self._ensure_pages(sid, r.prefill_pos + tk):
-                self._note_kv_blocked()
-                refused.add(sid)
-                continue
-            budget -= tk
-            batch.append((sid, r, tk))
         if not batch:
             return None
-        M = self.max_slots
         nb = _bucket(len(batch), floor=1)
-        T = min(_bucket(max(tk for _, _, tk in batch), floor=8),
+        T = min(_bucket(max(tk for _, _, _, tk in batch), floor=8),
                 _bucket(self.prefill_chunk, floor=1))
         W = min(_wbucket(max(len(self._slot_pages[sid])
-                             for sid, _, _ in batch)),
+                             for sid, _, _, _ in batch)),
                 self._max_pages)
+        # A slot's LAST row in the program carries its id (the slot's
+        # cache index is written once, from that row) and, where it ends
+        # the prompt, ``fin`` and the first token; the pending COW copy
+        # goes with its first.
+        last = [i + 1 == len(batch) or batch[i + 1][0] != sid
+                for i, (sid, _, _, _) in enumerate(batch)]
         toks = np.zeros((nb, T), np.int32)
         lens = np.zeros((nb,), np.int32)
         ci0 = np.zeros((nb,), np.int32)
@@ -959,12 +989,13 @@ class ContinuousBatchingEngine:
         cow_src = np.full((nb,), sent, np.int32)
         cow_dst = np.full((nb,), sent, np.int32)
         tbl_rows = np.full((nb, W), sent, np.int32)
-        for i, (sid, r, tk) in enumerate(batch):
-            toks[i, :tk] = r.prompt[r.prefill_pos:r.prefill_pos + tk]
+        for i, (sid, r, start, tk) in enumerate(batch):
+            toks[i, :tk] = r.prompt[start:start + tk]
             lens[i] = tk
-            ci0[i] = r.prefill_pos
-            slot_ids[i] = sid
-            fin[i] = (r.prefill_pos + tk == len(r.prompt))
+            ci0[i] = start
+            if last[i]:
+                slot_ids[i] = sid
+            fin[i] = (start + tk == len(r.prompt))
             temp[i] = r.temperature
             topk[i] = r.top_k
             eos[i] = -1 if r.eos_id is None else r.eos_id
@@ -1000,16 +1031,16 @@ class ContinuousBatchingEngine:
         else:
             self._wf_events.note("prefill_steal", t_j0)
         snapshot = []
-        for i, (sid, r, tk) in enumerate(batch):
+        for i, (sid, r, start, tk) in enumerate(batch):
             if r.wf is not None:
                 # First chunk starts at the prefix-cache hit position.
-                hit = r.prefill_pos if not r.wf.prefill_chunks else 0
+                hit = start if not r.wf.prefill_chunks else 0
                 r.wf.note_prefill_chunk(t_j0, t_j1, int(tk),
                                         prefix_hit_tokens=hit,
                                         compiled=new_bucket)
-                if new_bucket:
+                if new_bucket and last[i]:   # once a program, not a row
                     r.wf.note_compile(t_j0, t_j1)
-            r.prefill_pos += tk
+            r.prefill_pos = start + tk
             if fin[i]:
                 r.prefilling = False
                 if self._trie is not None and len(r.prompt) >= self._ps:
@@ -1023,7 +1054,7 @@ class ContinuousBatchingEngine:
             if fin[i]:
                 self._release_if_budget_dispatched(sid, r)  # max_new == 1
         self.prefill_chunks_run += len(batch)
-        self.prefill_tokens_total += sum(tk for _, _, tk in batch)
+        self.prefill_tokens_total += sum(tk for _, _, _, tk in batch)
         self._m_prefill_chunks.inc(len(batch))
         try:
             tok0.copy_to_host_async()
@@ -1260,8 +1291,10 @@ class ContinuousBatchingEngine:
             b - a for a, b in zip(c0, self._sched_counts()))
         dec, pre, free, other = census
         # ``prefill_steps``: the prefill programs it dispatched;
-        # ``prefill_rows``: the distinct slots they fed (a slot that got
-        # a chunk in each of three programs counts once).
+        # ``prefill_row_chunks``: the rows they carried (a snapshot has
+        # one entry a row); ``prefill_rows``: the distinct slots they fed
+        # (a slot that got eight rows of one program, or a row in each
+        # of three, counts once).
         pre_futs = [f for f in sent if f[0] == "prefill"]
         pre_rows = len({e[0] for f in pre_futs for e in f[2]})
         ids = dict.fromkeys(
@@ -1279,6 +1312,7 @@ class ContinuousBatchingEngine:
             "slots_prefilling": pre, "slots_free": free,
             "slots_other": other, "queued": queued,
             "prefill_steps": len(pre_futs), "prefill_rows": pre_rows,
+            "prefill_row_chunks": sum(len(f[2]) for f in pre_futs),
             "prefill_tokens": pre_toks,
             "prefill_hit_tokens": hit_toks, "decode_rows": dec_rows,
             "decode_steps": chunks * self.chunk_size,

@@ -294,10 +294,12 @@ def test_paged_engine_greedy_exact_with_every_slot_fed(model):
 
 
 def _stopped_engine_with_prefilling(module, params, prompts, seqs, kv,
-                                    max_slots=4):
+                                    max_slots=4, decoding=False):
     """A paged engine whose dispatcher has stopped, with ``prompts``
     placed mid-prefill in slots 0.. and admitted in the order ``seqs``:
-    the scheduler's prefill functions can then be called by hand."""
+    the scheduler's prefill functions can then be called by hand.
+    ``decoding``: the last slot holds a request that is past its prefill,
+    so the derived quota is ``chunk_size // 2`` programs."""
     from serverless_learn_tpu.inference.continuous import _Request
 
     eng = ContinuousBatchingEngine(module, params, max_slots=max_slots,
@@ -309,13 +311,27 @@ def _stopped_engine_with_prefilling(module, params, prompts, seqs, kv,
             prompt=np.asarray(prompt, np.int32), max_new=4,
             temperature=0.0, top_k=0, eos_id=None, seed=0, admitted=True,
             prefilling=True, admit_seq=seq)
+    if decoding:
+        eng._slots[max_slots - 1] = _Request(
+            prompt=np.asarray([1, 2], np.int32), max_new=40,
+            temperature=0.0, top_k=0, eos_id=None, seed=0, admitted=True,
+            prefill_pos=2, admit_seq=0)
     return eng
 
 
+def _rows(fut) -> list:
+    """The slots of a prefill program's rows, in row order."""
+    return [sid for sid, _, _, _ in fut[2]]
+
+
 def test_prefill_feeds_oldest_admitted_first(model):
-    """FIFO among admitted rows is by admission order, not slot order:
-    under a budget of one chunk the only row fed is the oldest; with the
-    derived quota every row is in every program, oldest first."""
+    """FIFO among admitted rows is by admission order, not slot order,
+    and a slot takes every row it can before the next slot gets one:
+    under a budget of one chunk the only row fed is the oldest slot's;
+    with the derived quota a program's rows are the oldest prompt's
+    chunks, then the next prompt's; while a slot decodes the quota of
+    ``chunk_size // 2`` programs goes to the oldest, and a slot is left
+    unfed only when older slots spent it."""
     module, params = model
     prompts = [list(range(1 + 10 * i, 9 + 10 * i)) for i in range(3)]
     kv = KVCacheConfig(block_size=4, prefill_chunk=4, prefill_budget=4,
@@ -325,7 +341,7 @@ def test_prefill_feeds_oldest_admitted_first(model):
     fed = []
     for _ in range(6):
         (fut,) = eng._prefill_steps()
-        fed.append([sid for sid, _, _, _ in fut[2]])
+        fed.append(_rows(fut))
     assert fed == [[1], [1], [2], [2], [0], [0]]
     assert eng._prefill_steps() == []
 
@@ -334,27 +350,60 @@ def test_prefill_feeds_oldest_admitted_first(model):
     eng = _stopped_engine_with_prefilling(module, params, prompts,
                                           seqs=[3, 1, 2], kv=derived)
     futs = eng._prefill_steps()   # no slot decodes: no bound
-    assert [[sid for sid, _, _, _ in f[2]] for f in futs] == [[1, 2, 0]] * 2
+    assert [_rows(f) for f in futs] == [[1, 1, 2, 2], [0, 0]]
     assert not any(r.prefilling for r in eng._slots if r is not None)
+    # Only a slot's last row carries ``fin``; here each ends its prompt.
+    assert [[fin for _, _, fin, _ in f[2]] for f in futs] \
+        == [[False, True, False, True], [False, True]]
+
+    # A slot decodes: two programs of four rows. Prompts of 5, 2 and 2
+    # chunks: the oldest takes five rows, the next two, the one left of
+    # the eight goes to the youngest, whose second chunk waits.
+    prompts = [list(range(1, 9)), list(range(11, 31)), list(range(41, 49))]
+    eng = _stopped_engine_with_prefilling(module, params, prompts,
+                                          seqs=[3, 1, 2], kv=derived,
+                                          decoding=True)
+    futs = eng._prefill_steps()
+    assert [_rows(f) for f in futs] == [[1, 1, 1, 1], [1, 2, 2, 0]]
+    assert [r.prefill_pos for r in eng._slots[:3]] == [4, 20, 8]
+    assert [r.prefilling for r in eng._slots[:3]] == [True, False, False]
+    # Seven chunks where five fit the quota: the youngest is not fed.
+    prompts = [list(range(1, 9)), list(range(11, 31)), list(range(41, 53))]
+    eng = _stopped_engine_with_prefilling(module, params, prompts,
+                                          seqs=[3, 1, 2], kv=derived,
+                                          decoding=True)
+    futs = eng._prefill_steps()
+    assert [_rows(f) for f in futs] == [[1, 1, 1, 1], [1, 2, 2, 2]]
+    assert [r.prefill_pos for r in eng._slots[:3]] == [0, 20, 12]
+    assert [_rows(f) for f in eng._prefill_steps()] == [[0, 0]]
 
 
 def test_refused_pages_sit_out_the_iteration(model):
     """Back-pressure inside an iteration of several programs: a slot the
-    pool refuses pages is counted once and sits out the rest of the
-    iteration (nothing frees pages before its decode dispatch); the loop
-    ends when every remaining row is refused."""
+    pool refuses pages keeps the rows it got pages for, is counted once
+    and sits out the rest of the iteration (nothing frees pages before
+    its decode dispatch); the slots behind it are still asked, and the
+    loop ends when every remaining slot is refused."""
     module, params = model
-    kv = KVCacheConfig(block_size=4, num_blocks=16, prefill_chunk=4,
+    kv = KVCacheConfig(block_size=4, num_blocks=18, prefill_chunk=4,
                        prefix_cache=False)
-    prompts = [list(range(1, 41)), list(range(41, 81))]  # 10 pages each
+    prompts = [list(range(1, 41)), list(range(41, 81)),   # 10 pages each
+               list(range(81, 89))]
     eng = _stopped_engine_with_prefilling(module, params, prompts,
-                                          seqs=[1, 2], kv=kv)
+                                          seqs=[1, 2, 3], kv=kv)
     futs = eng._prefill_steps()
-    assert len(futs) == 8                       # 16 pages, two a program
-    assert [r.prefill_pos for r in eng._slots[:2]] == [32, 32]
+    # 18 pages: ten to the oldest, eight to the next, whose ninth row is
+    # refused inside the fifth program; the youngest is refused too.
+    assert [_rows(f) for f in futs] == [[0] * 4, [0] * 4, [0, 0, 1, 1],
+                                        [1] * 4, [1, 1]]
+    assert [r.prefill_pos for r in eng._slots[:3]] == [40, 32, 0]
     assert eng._pool.free_blocks == 0
     assert int(eng._m_kv_blocked.value) == 2    # once a slot, not a program
-    assert all(r.prefilling for r in eng._slots[:2])
+    assert [r.prefilling for r in eng._slots[:3]] == [False, True, True]
+    assert eng.prefill_chunks_run == 18
+    # The next iteration asks again, and is refused again.
+    assert eng._prefill_steps() == []
+    assert int(eng._m_kv_blocked.value) == 4
 
 
 def test_seeded_sampling_is_blind_to_page_and_chunk_size(model):
@@ -531,14 +580,24 @@ class _ByHand:
     scheduler. Two slots, chunks of 4, pages of 4, a pool of 16 pages
     (one max-length sequence), no prefix cache."""
 
-    def __init__(self, module, params, max_slots=2, pipeline_depth=2):
+    def __init__(self, module, params, max_slots=2, pipeline_depth=2,
+                 kv=None):
         self.module, self.params = module, params
         self.eng = ContinuousBatchingEngine(
             module, params, max_slots=max_slots, chunk_size=4,
             pipeline_depth=pipeline_depth, registry=MetricsRegistry(),
-            kv=KVCacheConfig(block_size=4, num_blocks=16, prefill_chunk=4,
-                             prefix_cache=False))
+            kv=kv or KVCacheConfig(block_size=4, num_blocks=16,
+                                   prefill_chunk=4, prefix_cache=False))
         self.eng.stop()
+        self.requests = []  # everything ``put``, in order
+        self.programs = []  # every prefill program's (nb, T, W) key
+        fetch = self.eng._paged_prefill_jit
+
+        def counting(nb, T, W):
+            self.programs.append((nb, T, W))
+            return fetch(nb, T, W)
+
+        self.eng._paged_prefill_jit = counting
         self.seq = 0
         self.retired = []   # (slot, its pages) at every retirement
         real = self.eng._retire_slot
@@ -549,12 +608,15 @@ class _ByHand:
 
         self.eng._retire_slot = retire
 
-    def put(self, prompt, max_new, eos_id=None):
+    def put(self, prompt, max_new, eos_id=None, temperature=0.0, top_k=0,
+            seed=0):
         from serverless_learn_tpu.inference.continuous import _Request
 
         r = _Request(prompt=np.asarray(prompt, np.int32), max_new=max_new,
-                     temperature=0.0, top_k=0, eos_id=eos_id, seed=0)
+                     temperature=temperature, top_k=top_k, eos_id=eos_id,
+                     seed=seed)
         self.eng._q.put(r)
+        self.requests.append(r)
         return r
 
     def step(self, n=1):
@@ -580,6 +642,8 @@ class _ByHand:
         for r in requests:
             assert r.result is not None and "error" not in r.result, \
                 r.result
+            if r.temperature > 0:
+                continue    # solo generate draws from another stream
             assert r.result["new_tokens"] == _solo(
                 self.module, self.params, [int(t) for t in r.prompt],
                 r.max_new, eos_id=r.eos_id)
@@ -680,9 +744,12 @@ def test_max_new_one_is_released_at_its_last_prefill_program(model):
     h = _ByHand(*model)
     n = h.put(N_PROMPT, 30)
     h.step()
-    one = h.put(B_LONG, 1)      # three prefill programs, two a step
+    # Five chunks, two rows a program, two programs a step while the
+    # neighbour decodes: four chunks in this step, the last in the next.
+    one = h.put(list(range(20, 40)), 1)
     h.step()
     assert h.eng._slots[1] is one and one.prefilling
+    assert one.prefill_pos == 16
     h.step()
     assert h.eng._slots[1] is None and h.eng.slots_released_total == 1
     assert one.chunks_dispatched == 0
@@ -765,6 +832,117 @@ def test_stop_answers_a_released_request_in_flight(model):
     for r in (n, a, b):
         assert r.done.is_set() and r.finished
         assert r.result == {"error": "server shutting down"}
+
+
+# -- a prefill program's rows are consecutive chunks of a prompt -------------
+#
+# Served by hand like the tests above, so the schedule is the same on
+# every run. "Unpacked" is the same traffic under a ``prefill_budget`` of
+# one chunk: every row-chunk in a program of its own, which is what a
+# prompt alone in the engine got before a program's rows were row-chunks.
+
+SYSTEM = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8]
+SYSTEM16 = SYSTEM + [9, 7, 9, 3]
+LONG_A = [(5 * j + 3) % 97 + 1 for j in range(26)]     # 7 chunks of 4
+LONG_B = [(7 * j + 11) % 89 + 1 for j in range(13)]    # 4 chunks
+SAMPLED = dict(temperature=0.9, top_k=8, seed=42)
+# case -> (kv of the packed engine, waves of (prompt, max_new, sampling)).
+# A wave is queued when the wave before it has been answered.
+_NO_TRIE = dict(block_size=4, prefill_chunk=4, prefix_cache=False)
+PACKED_CASES = {
+    "alone": (_NO_TRIE, [[(LONG_A, 6, {})], [(LONG_A, 6, SAMPLED)]]),
+    "beside_a_second_prompt": (_NO_TRIE, [[(LONG_A, 6, SAMPLED),
+                                           (LONG_B, 7, {})],
+                                          [(LONG_B, 5, SAMPLED),
+                                           (LONG_A, 6, {})]]),
+    # The trie copies on write only where the prompt ENDS inside the
+    # divergent block, so chunks shorter than a block are what gives a
+    # slot a pending copy and two rows: 16 tokens shared and 3 copied,
+    # then [50, 51], [52]; beside it 16 shared and seven chunks.
+    "trie_hit_and_cow": (dict(block_size=8, prefill_chunk=2),
+                         [[(SYSTEM16 + [11, 2, 7, 9, 4, 6, 1, 3], 5, {})],
+                          [(SYSTEM16 + [11, 2, 7, 50, 51, 52], 6, {}),
+                           (SYSTEM16 + LONG_B, 6, SAMPLED)]]),
+    "prefill_budget": (dict(_NO_TRIE, prefill_budget=8),
+                       [[(LONG_A, 6, {})], [(LONG_A, 6, SAMPLED)]]),
+    "pool_grants_part_of_the_rows": (
+        dict(_NO_TRIE, num_blocks=16),
+        [[(LONG_A, 6, {})], [(LONG_A, 6, SAMPLED)]]),
+}
+
+
+def _serve_by_hand(model, kv: dict, waves, squeeze_to=None) -> _ByHand:
+    """``squeeze_to``: before each wave, ballast takes all but so many
+    pages of the pool, and gives them back after the wave's second
+    iteration: the pool then grants a prompt only part of its rows."""
+    h = _ByHand(*model, max_slots=4, kv=KVCacheConfig(**kv))
+    for wave in waves:
+        rs = [h.put(p, n, **sampling) for p, n, sampling in wave]
+        if squeeze_to is not None:
+            ballast = h.eng._pool.alloc(h.eng._pool.free_blocks
+                                        - squeeze_to)
+            h.step(2)
+            assert any(r.prefilling for r in rs), "the pool was not short"
+            h.eng._pool.decref(ballast)
+        h.run_out(*rs)
+    return h
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_packed_prefill_is_token_exact(model, case):
+    """Consecutive chunks of one prompt as the rows of one program give
+    the tokens of solo ``generate`` (greedy) and the tokens of the same
+    chunks in programs of their own (greedy and sampled at a fixed
+    seed)."""
+    kv, waves = PACKED_CASES[case]
+    squeeze = 3 if case == "pool_grants_part_of_the_rows" else None
+    packed = _serve_by_hand(model, kv, waves, squeeze_to=squeeze)
+    plain = _serve_by_hand(
+        model, dict(kv, prefill_budget=kv["prefill_chunk"]), waves)
+    packed.assert_exact(*packed.requests)
+    for a, b in zip(packed.requests, plain.requests):
+        assert a.result == b.result, (case, a.prompt.tolist())
+    eng = packed.eng
+    assert eng.prefill_chunks_run == plain.eng.prefill_chunks_run \
+        == len(plain.programs), "the same row-chunks, one a program there"
+    assert len(packed.programs) < eng.prefill_chunks_run, \
+        "no program carried two rows of a prompt"
+    assert all(nb <= 4 and T <= 8 for nb, T, _ in packed.programs)
+    if case == "alone":
+        # Seven chunks: a program of four rows and one of three.
+        assert len(packed.programs) == 2 * 2
+    if case == "prefill_budget":
+        # Two chunks an iteration, in one program of two rows.
+        assert len(packed.programs) == 2 * 4
+    if case == "trie_hit_and_cow":
+        assert eng._trie.hits == 2
+        assert int(eng._m_kv_hit_tokens.value) == (16 + 3) + 16
+        # The second wave's nine rows: the copy's slot has the first
+        # two of a program of four.
+        assert packed.programs[3:] == [(4, 2, 4), (4, 2, 4), (1, 2, 4)]
+    if case == "pool_grants_part_of_the_rows":
+        assert int(eng._m_kv_blocked.value) >= 2
+        assert eng._pool.free_blocks == 16
+
+
+def test_packed_prefill_reaches_no_program_outside_the_warmed_set(model):
+    """Dispatch creates no compile key that ``warm_shapes`` did not: a
+    program has at most ``max_slots`` rows of at most ``prefill_chunk``
+    tokens, over the table windows the prompts' page counts reach."""
+    h = _ByHand(*model, max_slots=4,
+                kv=KVCacheConfig(block_size=4, prefill_chunk=4))
+    eng = h.eng
+    work = [(LONG_A, 6), (LONG_B, 7), ([4], 3), (SYSTEM + [11, 2], 9),
+            (LONG_A[:9], 5), (SYSTEM + LONG_B, 6), ([1, 2], 12)]
+    assert eng.warm_shapes([(len(p), n) for p, n in work]) > 0
+    pre, dec = set(eng._prefill_jits), set(eng._chunk_jits)
+    rs = [h.put(p, n) for p, n in work]
+    h.run_out(*rs)
+    h.assert_exact(*rs)
+    assert set(h.programs) <= pre and len(set(h.programs)) > 3
+    assert set(eng._prefill_jits) == pre and set(eng._chunk_jits) == dec
+    assert max(nb for nb, _, _ in h.programs) == 4
+    assert all(T <= 4 or T == 8 for _, T, _ in h.programs)
 
 
 def test_block_table_write_padding_drops(model):

@@ -8,21 +8,24 @@ preempted and no request has an EOS, so every sum below is exact.
 Scenarios ``unique``, ``shared_prefix`` and ``no_prefix_cache`` (the
 ``unique`` requests on a pool without a prefix trie) run under an
 explicit ``prefill_budget`` (a cap on an iteration's prompt tokens);
-``derived`` runs the default quota: every mid-prefill slot fed every
-iteration, ``chunk_size // 2`` programs at most while a slot decodes.
+``derived`` runs the default quota: a program's rows are consecutive
+chunks of the mid-prefill prompts, oldest admitted first,
+``chunk_size // 2`` programs at most while a slot decodes. Its schedule
+is stepped by hand, so that it is the same on every run.
 """
 
 import threading
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from serverless_learn_tpu.config import KVCacheConfig
 from serverless_learn_tpu.inference.continuous import (
-    ContinuousBatchingEngine)
+    ContinuousBatchingEngine, _Request)
 from serverless_learn_tpu.models.registry import get_model
-from serverless_learn_tpu.telemetry import MetricsRegistry, flight
+from serverless_learn_tpu.telemetry import MetricsRegistry, Span, flight
 
 MAX_SLOTS, CHUNK = 4, 4
 SYSTEM = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8]   # three whole blocks of 4
@@ -35,9 +38,10 @@ SHARED = [(SYSTEM + tail, n) for tail, n in
           [([11, 2], 5), ([9, 7], 6), ([44, 45, 46], 4), ([8], 7),
            ([7, 7, 7, 7, 7], 3), ([1, 2], 5)]]
 # The derived quota's mix. The first request runs alone: its 8 chunks go
-# through an idle engine. Of the rest, six prompts have two chunks or
-# more, so whatever order the threads arrive in, one of them is admitted
-# while another slot decodes (CHUNK // 2 = 2 programs an iteration).
+# through an idle engine, in two programs. The rest are queued at once:
+# four prefill in full while no slot decodes, the others are admitted
+# beside decoding slots (CHUNK // 2 = 2 programs an iteration), and once
+# the 30-token prompt's eight chunks leave a younger slot unfed.
 LONG = ([(list(range(100, 129)), 5)] + UNIQUE
         + [(list(range(40, 10, -1)), 8), (list(range(50, 59)), 6),
            (list(range(60, 71)), 3)])
@@ -109,6 +113,32 @@ def _drive(eng, requests, first_alone: bool = False) -> list:
     return replies
 
 
+def _drive_by_hand(eng, requests) -> list:
+    """The dispatcher stopped and the scheduler stepped from here: the
+    first request alone, then the others queued at once in their order,
+    so the schedule is the same on every run. The requests are built as
+    ``submit`` builds them."""
+    eng.stop()
+
+    def put(prompt, n):
+        r = _Request(prompt=np.asarray(prompt, np.int32), max_new=n,
+                     temperature=0.0, top_k=0, eos_id=None, seed=0,
+                     span=Span("request"), wf=eng._new_waterfall())
+        eng._q.put(r)
+        return r
+
+    seq = 0
+    rs = []
+    for wave in (requests[:1], requests[1:]):
+        rs += [put(p, n) for p, n in wave]
+        while not all(r.done.is_set() for r in rs):
+            seq += 1
+            assert seq < 500, "the scheduler made no progress"
+            eng._iterate(seq)
+    assert all("error" not in r.result for r in rs), [r.result for r in rs]
+    return [r.result for r in rs]
+
+
 def _run_scenario(model, name: str) -> dict:
     module, params = model
     requests = {"shared_prefix": SHARED, "derived": LONG}.get(name, UNIQUE)
@@ -119,8 +149,11 @@ def _run_scenario(model, name: str) -> dict:
     programs = _count_prefill_programs(eng)
     ring0 = len(flight.events())
     try:
-        replies = _drive(eng, requests,
-                         first_alone=name in ("shared_prefix", "derived"))
+        if name == "derived":
+            replies = _drive_by_hand(eng, requests)
+        else:
+            replies = _drive(eng, requests,
+                             first_alone=name == "shared_prefix")
     finally:
         eng.stop()
     return {"scenario": name, "requests": requests,
@@ -213,50 +246,63 @@ def _row_chunks(run) -> int:
 
 
 def test_prefill_steps_are_the_programs_dispatched(run):
-    """``prefill_steps`` counts programs, ``prefill_rows`` the distinct
-    slots they fed; row-chunks are the engine's ``prefill_chunks_run``."""
+    """``prefill_steps`` counts programs, ``prefill_row_chunks`` the rows
+    they carried (the engine's ``prefill_chunks_run``, the requests' own
+    waterfall chunks), ``prefill_rows`` the distinct slots they fed."""
     eng = run["engine"]
     assert _total(run, "prefill_steps") == len(run["programs"])
     assert len(run["programs"]) > 0
-    assert _row_chunks(run) == eng.prefill_chunks_run
-    assert (_total(run, "prefill_steps") <= eng.prefill_chunks_run
-            <= _total(run, "prefill_steps") * MAX_SLOTS)
+    assert _total(run, "prefill_row_chunks") == eng.prefill_chunks_run \
+        == _row_chunks(run)
     assert _total(run, "prefill_rows") <= eng.prefill_chunks_run
     for r in run["iters"]:
         assert bool(r["prefill_steps"]) == bool(r["prefill_rows"]) \
-            == bool(r["prefill_tokens"])
-        assert r["prefill_rows"] <= r["prefill_steps"] * MAX_SLOTS
+            == bool(r["prefill_tokens"]) == bool(r["prefill_row_chunks"])
         # No program is wider than the programs the engine has.
-        assert r["prefill_tokens"] <= r["prefill_steps"] * MAX_SLOTS * 4
+        assert (r["prefill_steps"] <= r["prefill_row_chunks"]
+                <= r["prefill_steps"] * MAX_SLOTS)
+        assert r["prefill_rows"] <= min(r["slots_prefilling"],
+                                        r["prefill_row_chunks"])
+        assert r["prefill_tokens"] <= r["prefill_row_chunks"] * 4
     assert all(nb <= MAX_SLOTS and T <= 8 for nb, T, _ in run["programs"])
 
 
 def test_derived_quota_feeds_every_prefilling_slot(scenario):
-    """The default quota: every mid-prefill slot gets prompt tokens in
-    every iteration (the pool refuses no pages here); while a slot
-    decodes an iteration dispatches at most ``chunk_size // 2`` prefill
-    programs; while none does, every prompt finishes its prefill in that
-    iteration and joins its decode chunk."""
+    """The default quota, on a schedule stepped by hand: oldest admitted
+    first, each slot taking every row it can. A mid-prefill slot goes
+    unfed in an iteration only when the quota went to slots admitted
+    before it (the pool refuses no pages here), and then every program
+    of that iteration is full; while a slot decodes an iteration
+    dispatches at most ``chunk_size // 2`` prefill programs; while none
+    does, every prompt finishes its prefill in that iteration and joins
+    its decode chunk."""
     run = scenario("derived")
     assert int(run["engine"]._m_kv_blocked.value) == 0
-    stalled = unbounded = capped = 0
+    stalled = unbounded = capped = unfed = 0
     for r in run["iters"]:
         if not r["slots_prefilling"]:
             assert r["prefill_steps"] == 0
             continue
-        assert r["prefill_rows"] == r["slots_prefilling"], r
         if r["slots_decoding"]:
             stalled += 1
             assert 1 <= r["prefill_steps"] <= CHUNK // 2, r
             capped += r["prefill_steps"] == CHUNK // 2
+            if r["prefill_rows"] < r["slots_prefilling"]:
+                unfed += 1
+                assert r["prefill_row_chunks"] \
+                    == (CHUNK // 2) * MAX_SLOTS, r
         else:
             unbounded += 1
+            assert r["prefill_rows"] == r["slots_prefilling"], r
             assert r["decode_rows"] == r["slots_prefilling"], r
-    # Both regimes occurred, the cap was reached, and the first prompt's
-    # eight chunks went through in one iteration.
-    assert stalled and unbounded and capped
-    assert run["iters"][0]["prefill_steps"] == 8
-    assert run["iters"][0]["prefill_tokens"] == len(LONG[0][0])
+    # Both regimes occurred, the cap was reached, once with a slot left
+    # unfed, and the first prompt's eight chunks went through in one
+    # iteration, in ceil(8 / max_slots) programs.
+    assert stalled and unbounded and capped and unfed
+    first = run["iters"][0]
+    assert first["prefill_steps"] == -(-8 // MAX_SLOTS)
+    assert first["prefill_row_chunks"] == 8
+    assert first["prefill_tokens"] == len(LONG[0][0])
 
 
 def test_decode_rows_match_the_engines_counters(run):
