@@ -109,6 +109,7 @@ from serverless_learn_tpu.inference.batching import PROMPT_BUCKETS, _bucket
 from serverless_learn_tpu.inference.generate import init_cache
 from serverless_learn_tpu.inference.kvcache import (BlockPool, PrefixTrie,
                                                     pages_for)
+from serverless_learn_tpu.models.transformer import slot_leaves
 from serverless_learn_tpu.telemetry import (RATE_BUCKETS, SIZE_BUCKETS,
                                             Span, TraceContext, get_registry)
 from serverless_learn_tpu.telemetry import flight, goodput
@@ -239,9 +240,17 @@ class ContinuousBatchingEngine:
         ps = kv.block_size
         self._ps = ps
         self._max_pages = pages_for(max_seq, ps)
+        # A model with a recurrent layer keeps state per SLOT beside the
+        # pool (``kvcache.take_slots``). A page hit cannot restore that
+        # state, so such a model runs without the prefix trie; and the
+        # state is carried from program to program, not from row to row,
+        # so a prefill program feeds each slot one row-chunk
+        # (``_prefill_step``).
+        self._slot_leaves = slot_leaves(module.cfg)
+        prefix_cache = kv.prefix_cache and not self._slot_leaves
         num_blocks = kv.num_blocks or (
             max_slots * self._max_pages
-            + (self._max_pages if kv.prefix_cache else 0))
+            + (self._max_pages if prefix_cache else 0))
         if num_blocks < self._max_pages:
             raise ValueError(
                 f"kv.num_blocks ({num_blocks}) cannot hold one "
@@ -252,7 +261,7 @@ class ContinuousBatchingEngine:
             self._pool,
             max_blocks=kv.prefix_cache_blocks or num_blocks // 4,
             hit_window=kv.prefix_hit_window)
-            if kv.prefix_cache else None)
+            if prefix_cache else None)
         self._pmod = kvcache.paged_module(module, ps, num_blocks)
         self.prefill_chunk = kv.prefill_chunk or max_seq
         # 0 = derived per iteration (``_prefill_steps``). An explicit
@@ -279,6 +288,10 @@ class ContinuousBatchingEngine:
         # tokens sent to prefill programs, output tokens appended at
         # harvest, seconds blocked in harvest's device_get.
         self.prefill_tokens_total = 0
+        # Prefill rows that started a slot's recurrent state from zero
+        # (rows at position 0 of a model with slot leaves): admissions
+        # plus re-admissions after preemption.
+        self.state_resets_total = 0
         self.tokens_out_total = 0
         self.harvest_wait_s_total = 0.0
         # Decode row accounting: ``decoded_rows_total`` counts rows that
@@ -457,22 +470,33 @@ class ContinuousBatchingEngine:
         if key in self._prefill_jits:
             return self._prefill_jits[key]
         module, ktop, M = self._pmod, self.max_top_k, self.max_slots
+        per_slot = self._slot_leaves
 
         def pre(params, pages, vecs, tbl, ci0, toks, lens, slot_ids, fin,
                 temp, topk, eos, seed, cow_src, cow_dst):
             # COW: materialize the divergent-block copies before the
             # extend overwrites from the divergent offset (sentinel
-            # src/dst = no copy: gather clips, scatter drops).
+            # src/dst = no copy: gather clips, scatter drops). A model
+            # with slot leaves has no trie and so nothing to copy.
             def cp(p):
                 src = p.at[cow_src].get(mode="clip")
                 return p.at[cow_dst].set(src, mode="drop")
 
-            pages = jax.tree_util.tree_map(cp, pages)
-            cache = kvcache.with_tables(pages, tbl, ci0)
+            if not per_slot:
+                pages = jax.tree_util.tree_map(cp, pages)
+            # Slot leaves: each row's slot's state, zero where the row
+            # starts its sequence (admission, and re-admission after a
+            # preemption, which restarts from the first token).
+            cache = kvcache.take_slots(
+                kvcache.with_tables(pages, tbl, ci0), per_slot, slot_ids,
+                fresh=ci0 == 0)
             logits, upd = module.apply(
                 {"params": params, "cache": cache}, toks,
                 extend=True, mutable=["cache"], seq_lengths=lens)
-            pages, ci1 = kvcache.split_cache(upd["cache"])
+            new, ci1 = kvcache.split_cache(upd["cache"])
+            pages = kvcache.put_slots(pages, new, per_slot, slot_ids)
+            if ci1 is None:   # no attention layer keeps the index
+                ci1 = ci0 + lens
             last = jnp.take_along_axis(
                 logits, jnp.maximum(lens - 1, 0)[:, None, None],
                 axis=1)[:, 0]
@@ -514,6 +538,7 @@ class ContinuousBatchingEngine:
         if key in self._chunk_jits:
             return self._chunk_jits[key]
         module, C, ktop = self._pmod, self.chunk_size, self.max_top_k
+        per_slot = self._slot_leaves
 
         def chunk(params, pages, vecs, tbl, live):
             def take(x):
@@ -531,7 +556,8 @@ class ContinuousBatchingEngine:
                 logits, upd = module.apply(
                     {"params": params, "cache": cache}, tok[:, None],
                     decode=True, mutable=["cache"])
-                pages, ci = kvcache.split_cache(upd["cache"])
+                pages, ci1 = kvcache.split_cache(upd["cache"])
+                ci = ci + 1 if ci1 is None else ci1
                 nxt = _sample_slots(logits[:, 0], temp, topk, seed, pos,
                                     ktop)
                 # EOS contract (matches generate): finished rows keep
@@ -541,8 +567,12 @@ class ContinuousBatchingEngine:
                 done = done | ((eos >= 0) & (nxt == eos))
                 return (pages, nxt, pos + 1, done, ci), nxt
 
-            (pages, tok, pos, done, ci), toks = jax.lax.scan(
-                step, (pages, tok, pos, done, ci), None, length=C)
+            # The live slots' rows of the slot leaves ride through the
+            # steps as a compact batch and go back at the chunk's end.
+            rows = kvcache.take_slots(pages, per_slot, live)
+            (rows, tok, pos, done, ci), toks = jax.lax.scan(
+                step, (rows, tok, pos, done, ci), None, length=C)
+            pages = kvcache.put_slots(pages, rows, per_slot, live)
 
             def put(big, new):
                 return big.at[live].set(new, mode="drop")
@@ -960,6 +990,10 @@ class ContinuousBatchingEngine:
                 budget -= tk
                 batch.append((sid, r, pos, tk))
                 pos += tk
+                if self._slot_leaves:
+                    # A recurrent state is carried from program to
+                    # program, not from row to row: one row a slot.
+                    break
             if spent or len(batch) == M:
                 break
         if not batch:
@@ -1055,6 +1089,9 @@ class ContinuousBatchingEngine:
                 self._release_if_budget_dispatched(sid, r)  # max_new == 1
         self.prefill_chunks_run += len(batch)
         self.prefill_tokens_total += sum(tk for _, _, _, tk in batch)
+        if self._slot_leaves:
+            self.state_resets_total += sum(
+                1 for _, _, start, _ in batch if start == 0)
         self._m_prefill_chunks.inc(len(batch))
         try:
             tok0.copy_to_host_async()
@@ -1270,7 +1307,7 @@ class ContinuousBatchingEngine:
                 int(self._m_kv_hit_tokens.value), self.decoded_rows_total,
                 self.chunks_run, self.tokens_out_total,
                 self.requests_finished, self.slots_released_total,
-                self.harvest_wait_s_total)
+                self.harvest_wait_s_total, self.state_resets_total)
 
     def _sched_record(self, seq: int, ts: list, idle: bool, c0: tuple,
                       census: tuple, queued: int, sent: list) -> dict:
@@ -1287,7 +1324,7 @@ class ContinuousBatchingEngine:
         it dispatched. No ``marks_s`` or ``waterfall`` key: readers pick
         request spans out of the same sink by those."""
         (pre_toks, hit_toks, dec_rows, chunks, toks_out,
-         finished, released, wait_s) = (
+         finished, released, wait_s, resets) = (
             b - a for a, b in zip(c0, self._sched_counts()))
         dec, pre, free, other = census
         # ``prefill_steps``: the prefill programs it dispatched;
@@ -1313,7 +1350,7 @@ class ContinuousBatchingEngine:
             "slots_other": other, "queued": queued,
             "prefill_steps": len(pre_futs), "prefill_rows": pre_rows,
             "prefill_row_chunks": sum(len(f[2]) for f in pre_futs),
-            "prefill_tokens": pre_toks,
+            "prefill_tokens": pre_toks, "state_resets": resets,
             "prefill_hit_tokens": hit_toks, "decode_rows": dec_rows,
             "decode_steps": chunks * self.chunk_size,
             "tokens_out": toks_out, "requests_finished": finished,
@@ -1466,6 +1503,18 @@ class ContinuousBatchingEngine:
                "prefix_blocks_cached": (self._trie.blocks_held
                                         if self._trie is not None else 0),
                "preemptions": self.preemptions}
+        # What the slots hold beside the pool, and why there is or is
+        # not a prefix trie.
+        per_slot = kvcache.slot_bytes(self._state.get("pages", {}),
+                                      self._slot_leaves)
+        out.update(
+            state_slots=self.max_slots if self._slot_leaves else 0,
+            state_bytes_per_slot=per_slot,
+            state_bytes=per_slot * self.max_slots,
+            prefix_cache=(
+                "on" if self._trie is not None else
+                "off: a page hit cannot restore a recurrent layer's state"
+                if self._slot_leaves else "off: kv.prefix_cache is false"))
         if self._trie is not None:
             out["prefix_digest"] = self._trie.digest(
                 top_k=self.kv.digest_top_k,

@@ -42,6 +42,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -419,6 +420,59 @@ def split_cache(cache: dict):
 
     pages = strip(cache)
     return pages, ci_box[0]
+
+
+# A recurrent layer's state does not grow a token a step and lives in no
+# page: its cache leaves (``models/transformer.slot_leaves`` names them)
+# are SLOT leaves, ``[max_slots, ...]``, one row per slot of the engine.
+# They ride in the same tree as the pool leaves; a program gathers the
+# rows of the slots it serves, runs the module on that compact batch and
+# scatters the rows back. A sentinel slot id (== max_slots) gathers
+# clipped and scatters dropped, as a sentinel page does.
+
+def _map_slot_leaves(fn, names: tuple, tree, *rest):
+    if isinstance(tree, dict):
+        return {k: (fn(v, *(r[k] for r in rest)) if k in names
+                    else _map_slot_leaves(fn, names, v,
+                                          *(r[k] for r in rest)))
+                for k, v in tree.items()}
+    return tree
+
+
+def take_slots(tree: dict, names: tuple, ids, fresh=None) -> dict:
+    """``tree`` with each slot leaf cut to the rows ``ids`` [nb]; rows
+    where ``fresh`` [nb] holds start from zero (a sequence's first
+    tokens). Pool leaves pass through."""
+    import jax.numpy as jnp
+
+    def take(leaf):
+        rows = leaf.at[ids].get(mode="clip")
+        if fresh is None:
+            return rows
+        return jnp.where(
+            fresh.reshape((-1,) + (1,) * (rows.ndim - 1)), 0, rows)
+
+    return _map_slot_leaves(take, names, tree) if names else tree
+
+
+def put_slots(resident: dict, compact: dict, names: tuple, ids) -> dict:
+    """``compact`` (a program's tree after the module ran: its pool leaves
+    are the pool, its slot leaves hold ``ids``' rows) with each slot leaf
+    scattered back into ``resident``'s ``[max_slots, ...]`` leaf."""
+    if not names:
+        return compact
+    return _map_slot_leaves(
+        lambda rows, big: big.at[ids].set(rows.astype(big.dtype),
+                                          mode="drop"),
+        names, compact, resident)
+
+
+def slot_bytes(tree, names: tuple) -> int:
+    """Bytes ONE slot's rows of the slot leaves take (shapes suffice)."""
+    if not isinstance(tree, dict):
+        return 0
+    return sum(math.prod(v.shape[1:]) * v.dtype.itemsize if k in names
+               else slot_bytes(v, names) for k, v in tree.items())
 
 
 def paged_module(module, block_size: int, num_blocks: int):

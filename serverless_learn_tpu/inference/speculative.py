@@ -207,6 +207,11 @@ def speculative_generate(
     if max_new_tokens <= 0:
         return prompt.astype(jnp.int32), {"acceptance": 0.0, "rounds": 0}
     for m, who in ((target, "target"), (draft, "draft")):
+        if "mamba" in m.cfg.layer_types:
+            # A rejected draft rolls the cache index back; a recurrent
+            # state has consumed the rejected tokens and cannot follow.
+            raise NotImplementedError(
+                f"speculative decoding over a {who} with recurrent layers")
         if P + max_new_tokens + K > m.cfg.max_seq_len:
             raise ValueError(
                 f"prompt + max_new + K ({P}+{max_new_tokens}+{K}) exceeds "

@@ -22,7 +22,7 @@ def _bert_cfg(size: str, **overrides) -> TransformerConfig:
         "base": dict(d_model=768, n_layers=12, n_heads=12, d_ff=3072),
     }
     kw = dict(
-        vocab_size=30522, max_seq_len=512, causal=False, use_rope=False,
+        vocab_size=30522, max_seq_len=512, causal=False, position="learned",
         norm="layer", activation="gelu", tie_embeddings=False,
         # Both BERT data paths honor the suffix contract: the synthetic
         # make_batch emits all-ones masks, and the corpus pipeline's

@@ -29,7 +29,7 @@ def _llama_cfg(size: str, **overrides) -> TransformerConfig:
                    n_kv_heads=8, d_ff=14336, max_seq_len=8192,
                    rope_theta=500000.0),
     }
-    kw = dict(causal=True, use_rope=True, norm="rms", activation="swiglu")
+    kw = dict(causal=True, position="rope", norm="rms", activation="swiglu")
     kw.update(presets[size])
     kw.update(overrides)
     return TransformerConfig(**kw)

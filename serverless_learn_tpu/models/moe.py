@@ -32,7 +32,7 @@ def _moe_cfg(size: str, **overrides) -> TransformerConfig:
                              max_seq_len=8192, n_experts=8, moe_top_k=2,
                              rope_theta=1000000.0),
     }
-    kw = dict(causal=True, use_rope=True, norm="rms", activation="swiglu")
+    kw = dict(causal=True, position="rope", norm="rms", activation="swiglu")
     kw.update(presets[size])
     kw.update(overrides)
     return TransformerConfig(**kw)

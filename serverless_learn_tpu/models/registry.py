@@ -41,7 +41,7 @@ def register_model(name: str):
 def get_model(name: str, **overrides) -> ModelBundle:
     # Import model modules lazily so the registry populates on first use.
     from serverless_learn_tpu.models import (  # noqa: F401
-        mlp, resnet, bert, llama, moe)
+        mlp, resnet, bert, llama, moe, granite)
 
     if name not in _REGISTRY:
         raise KeyError(f"unknown model {name!r}; known: {sorted(_REGISTRY)}")
@@ -50,6 +50,6 @@ def get_model(name: str, **overrides) -> ModelBundle:
 
 def list_models():
     from serverless_learn_tpu.models import (  # noqa: F401
-        mlp, resnet, bert, llama, moe)
+        mlp, resnet, bert, llama, moe, granite)
 
     return sorted(_REGISTRY)

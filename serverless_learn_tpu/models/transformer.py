@@ -14,6 +14,7 @@ module runs DP / FSDP / TP / SP purely by mesh shape.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import flax.linen as nn
@@ -34,9 +35,32 @@ class TransformerConfig:
     d_ff: int = 2048
     max_seq_len: int = 512
     causal: bool = True
-    use_rope: bool = True
+    # Where a token's position enters: "rope" (rotary, on q and k),
+    # "learned" (a table added to the embedding; training only) or "none"
+    # (nowhere: a causal model whose recurrent layers carry the order).
+    position: str = "rope"
     rope_theta: float = 10000.0
     norm: str = "rms"  # "rms" | "layer"
+    rms_norm_eps: float = 1e-6
+    # The kind of each layer's mixer: a tuple of "attention" / "mamba",
+    # one entry a layer; () => every layer attends. A "mamba" layer is a
+    # Mamba-2 mixer (``MambaMixer``) of ``ssm_heads`` heads of
+    # ``ssm_head_dim`` channels over a state of ``ssm_state`` per channel.
+    layer_types: tuple = ()
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_groups: int = 1      # heads of one group share B and C
+    ssm_conv: int = 4        # taps of the short causal convolution
+    ssm_chunk: int = 256     # tokens per chunk of the chunked scan
+    # Four fixed scalars some model families publish (all 1 = absent):
+    # the embedding is multiplied by the first, both residual branches by
+    # the second, the logits DIVIDED by the third; ``attention_multiplier``
+    # replaces the softmax scale 1/sqrt(head_dim) (None: that default).
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
     activation: str = "swiglu"  # "swiglu" | "gelu"
     lora_rank: int = 0
     lora_alpha: float = 16.0
@@ -119,6 +143,23 @@ class TransformerConfig:
     # Training never reads these fields.
     kv_page_size: int = 0   # 0 => monolithic cache
     kv_pages: int = 0       # pool size; required > 0 when kv_page_size > 0
+
+    def __post_init__(self):
+        # A configuration file hands a list; the module's cfg is hashed.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.position not in ("rope", "learned", "none"):
+            raise ValueError(f"unknown position kind {self.position!r} "
+                             "(rope | learned | none)")
+        bad = set(self.layer_types) - {"attention", "mamba"}
+        if bad or (self.layer_types
+                   and len(self.layer_types) != self.n_layers):
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers "
+                f"(unknown kinds: {sorted(bad)}) for n_layers="
+                f"{self.n_layers}; kinds are attention | mamba")
+
+    def layer_kind(self, i: int) -> str:
+        return self.layer_types[i] if self.layer_types else "attention"
 
     @property
     def kv_heads(self) -> int:
@@ -276,6 +317,20 @@ def _proj(cfg: TransformerConfig, feats, name: str, n_contract: int = 1):
                            dtype=cfg.dtype, param_dtype=cfg.param_dtype)
 
 
+def _times(x, multiplier: float):
+    """``x`` times a configuration's fixed scalar; 1 adds no operation to
+    a model that publishes none."""
+    return x if multiplier == 1.0 else x * multiplier
+
+
+def _norm(cfg: TransformerConfig, name: str):
+    if cfg.norm == "rms":
+        return nn.RMSNorm(epsilon=cfg.rms_norm_eps, dtype=cfg.dtype,
+                          param_dtype=cfg.param_dtype, name=name)
+    return nn.LayerNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                        name=name)
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
 
@@ -340,7 +395,7 @@ class Attention(nn.Module):
                     pos0 = ci.value  # [B]
                     positions_bt = (pos0[:, None]
                                     + jnp.arange(T, dtype=jnp.int32))
-                    if cfg.use_rope:
+                    if cfg.position == "rope":
                         sin, cos = rope_angles(positions_bt, D,
                                                cfg.rope_theta)
                         q = apply_rope(q, sin, cos)
@@ -390,7 +445,7 @@ class Attention(nn.Module):
                 pass  # the paged branch above handled everything
             elif not is_init and prefill:
                 T = x.shape[1]
-                if cfg.use_rope:
+                if cfg.position == "rope":
                     p = jnp.broadcast_to(
                         jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
                     sin, cos = rope_angles(p, D, cfg.rope_theta)
@@ -418,7 +473,7 @@ class Attention(nn.Module):
                 pos0 = ci.value  # [B]
                 positions_bt = pos0[:, None] + jnp.arange(T,
                                                           dtype=jnp.int32)
-                if cfg.use_rope:
+                if cfg.position == "rope":
                     sin, cos = rope_angles(positions_bt, D, cfg.rope_theta)
                     q = apply_rope(q, sin, cos)
                     k = apply_rope(k, sin, cos)
@@ -439,7 +494,7 @@ class Attention(nn.Module):
                     raise ValueError(
                         f"decode feeds one token at a time, got T={x.shape[1]}")
                 pos = ci.value  # [B]
-                if cfg.use_rope:
+                if cfg.position == "rope":
                     sin, cos = rope_angles(pos[:, None], D, cfg.rope_theta)
                     q = apply_rope(q, sin, cos)
                     k = apply_rope(k, sin, cos)
@@ -455,7 +510,7 @@ class Attention(nn.Module):
                 mask = (jnp.arange(cfg.max_seq_len)[None, :]
                         <= pos[:, None])[:, None, None, :]
                 causal = False  # the index mask already encodes causality
-        elif cfg.use_rope:
+        elif cfg.position == "rope":
             if positions is None:
                 positions = jnp.arange(x.shape[1])[None, :]
             sin, cos = rope_angles(positions, D, cfg.rope_theta)
@@ -489,6 +544,8 @@ class Attention(nn.Module):
                 # suffix lengths; a suffix-padded mask's per-shard valid
                 # counts sum to exactly the global valid length.
                 kv_lengths = jax.lax.psum(kv_lengths, cfg.manual_sp_axis)
+            if cfg.attention_multiplier is not None:
+                q = q * (cfg.attention_multiplier * D ** 0.5)
             out = ring_attention_manual(q, k, v,
                                         axis_name=cfg.manual_sp_axis,
                                         causal=causal,
@@ -498,7 +555,8 @@ class Attention(nn.Module):
                 q, k, v, causal=causal, mask=mask, kv_lengths=kv_lengths,
                 impl="xla" if (decode or prefill or extend)
                 else cfg.attention_impl,
-                axis_name=cfg.sp_axis or "sp")
+                axis_name=cfg.sp_axis or "sp",
+                softmax_scale=cfg.attention_multiplier)
         y = _proj(cfg, cfg.d_model, "o_proj", n_contract=2)(out)
         if cfg.manual_tp_axis:
             # Row-parallel output projection: each tp member contracted its
@@ -527,20 +585,131 @@ class MlpBlock(nn.Module):
         return y
 
 
+def _inverse_softplus(dt):
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+class MambaMixer(nn.Module):
+    """The Mamba-2 mixer (``ops/ssm.py`` has its three parts): one input
+    projection to ``[z | xBC | dt]``, a short causal depthwise convolution
+    and SiLU over ``xBC = [x | B | C]``, the selective state-space
+    recurrence over ``x`` per head, a skip ``D x``, the gate
+    ``y * silu(z)`` and THEN a grouped RMSNorm, and the output projection.
+    No biases but the convolution's.
+
+    Under ``decode`` / ``prefill`` / ``extend`` it carries two ``cache``
+    leaves per row, both indexed by the serving engine's SLOT and not
+    through its block table (``SLOT_LEAVES``): the recurrent state
+    (float32) and the convolution's last ``ssm_conv - 1`` inputs. Each
+    call appends to what the leaves hold: a sequence's first call has to
+    find them zero (the engine zeroes a slot's rows where a prompt
+    starts; ``init_cache`` makes them zero). ``seq_lengths`` [B]: real
+    tokens of right-padded rows; the leaves are left as after those."""
+
+    cfg: TransformerConfig
+    SLOT_LEAVES = ("ssm_state", "conv_state")
+
+    @nn.compact
+    def __call__(self, u, *, decode=False, prefill=False, extend=False,
+                 seq_lengths=None):
+        from serverless_learn_tpu.ops import ssm
+
+        cfg = self.cfg
+        H, P, N, G, K = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                         cfg.ssm_groups, cfg.ssm_conv)
+        d_inner, gn = H * P, G * N
+        conv_dim = d_inner + 2 * gn
+        B_, T = u.shape[:2]
+        zxbcdt = _proj(cfg, 2 * d_inner + 2 * gn + H, "in_proj")(u)
+        z, xbc, dt = jnp.split(zxbcdt, [d_inner, d_inner + conv_dim], axis=-1)
+        pd = cfg.param_dtype
+        conv_w = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                            (K, conv_dim), pd)
+        conv_b = self.param("conv_bias", nn.initializers.zeros,
+                            (conv_dim,), pd)
+        # Mamba-2's own initial values: A in [1, 16], dt in [1e-3, 1e-1]
+        # (log-uniform) through softplus' inverse, D = 1.
+        a_log = self.param(
+            "A_log", lambda k, s, d: jnp.log(jax.random.uniform(
+                k, s, jnp.float32, 1.0, 16.0)).astype(d), (H,), pd)
+        dt_bias = self.param(
+            "dt_bias", lambda k, s, d: _inverse_softplus(jnp.exp(
+                jax.random.uniform(k, s, jnp.float32, math.log(1e-3),
+                                   math.log(1e-1)))).astype(d), (H,), pd)
+        skip = self.param("D", nn.initializers.ones, (H,), pd)
+        norm_w = self.param("norm_scale", nn.initializers.ones,
+                            (d_inner,), pd)
+        # ``carried``: this call reads and writes the two leaves (an
+        # ``init`` under one of the three modes only declares them).
+        carried = False
+        if decode or prefill or extend:
+            carried = self.has_variable("cache", "ssm_state")
+            hs = self.variable("cache", "ssm_state", jnp.zeros,
+                               (B_, H, P, N), jnp.float32)
+            cs = self.variable("cache", "conv_state", jnp.zeros,
+                               (B_, K - 1, conv_dim), cfg.dtype)
+        f32 = jnp.float32
+        A = -jnp.exp(a_log.astype(f32))
+        dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+        conv_in = cs.value if carried else jnp.zeros(
+            (B_, K - 1, conv_dim), xbc.dtype)
+        xbc, conv_out = ssm.causal_conv(xbc, conv_in, conv_w, conv_b,
+                                        seq_lengths if carried else None)
+        xbc = nn.silu(xbc).astype(cfg.dtype)
+        x, Bm, Cm = jnp.split(xbc, [d_inner, d_inner + gn], axis=-1)
+        x = x.reshape(B_, T, H, P)
+        Bm, Cm = Bm.reshape(B_, T, G, N), Cm.reshape(B_, T, G, N)
+        h0 = hs.value if carried else jnp.zeros((B_, H, P, N), f32)
+        if decode and carried:
+            if T != 1:
+                raise ValueError(
+                    f"decode feeds one token at a time, got T={T}")
+            y, h1 = ssm.ssm_step(x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                                 h0)
+            y = y[:, None]
+        else:
+            if carried and seq_lengths is not None:
+                real = jnp.arange(T)[None, :] < seq_lengths[:, None]
+                dt = jnp.where(real[:, :, None], dt, 0.0)
+            y, h1 = ssm.ssd_scan(x, dt, A, Bm, Cm, h0, cfg.ssm_chunk)
+        if carried:
+            hs.value, cs.value = h1, conv_out
+        y = y + skip.astype(f32)[:, None] * x.astype(f32)
+        # Gate first, then the norm, over each group's channels.
+        g = y.reshape(B_, T, d_inner) * nn.silu(z.astype(f32))
+        g = g.reshape(B_, T, G, d_inner // G)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                              + cfg.rms_norm_eps)
+        g = (g.reshape(B_, T, d_inner) * norm_w.astype(f32)).astype(cfg.dtype)
+        return _proj(cfg, cfg.d_model, "out_proj")(g)
+
+
+def slot_leaves(cfg: TransformerConfig) -> tuple:
+    """Names of the ``cache`` leaves this model keeps per SLOT of the
+    serving engine (``[max_slots, ...]``, gathered and scattered by slot
+    id) beside the paged pool: () for a model whose every layer attends."""
+    return MambaMixer.SLOT_LEAVES if "mamba" in cfg.layer_types else ()
+
+
 class Block(nn.Module):
     cfg: TransformerConfig
+    kind: str = "attention"   # the mixer: "attention" | "mamba"
 
     @nn.compact
     def __call__(self, x, *, mask=None, positions=None, decode=False,
                  prefill=False, extend=False, seq_lengths=None):
         cfg = self.cfg
-        norm = (nn.RMSNorm if cfg.norm == "rms" else nn.LayerNorm)
-        mk_norm = lambda name: norm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                                    name=name)
-        x = x + Attention(cfg, name="attn")(
-            mk_norm("norm_attn")(x), mask=mask, positions=positions,
-            decode=decode, prefill=prefill, extend=extend,
-            seq_lengths=seq_lengths)
+        res = cfg.residual_multiplier
+        h = _norm(cfg, "norm_attn")(x)
+        if self.kind == "mamba":
+            mixed = MambaMixer(cfg, name="mamba")(
+                h, decode=decode, prefill=prefill, extend=extend,
+                seq_lengths=seq_lengths)
+        else:
+            mixed = Attention(cfg, name="attn")(
+                h, mask=mask, positions=positions, decode=decode,
+                prefill=prefill, extend=extend, seq_lengths=seq_lengths)
+        x = x + _times(mixed, res)
         if cfg.n_experts > 0:
             moe_cfg = cfg
             if decode or prefill or extend:
@@ -553,10 +722,10 @@ class Block(nn.Module):
                 # give every token its full top-k experts, no drops, and
                 # identical routing between prefill and decode.
                 moe_cfg = dataclasses.replace(cfg, moe_group_size=1)
-            x = x + MoELayer(moe_cfg, name="moe")(mk_norm("norm_mlp")(x))
+            y = MoELayer(moe_cfg, name="moe")(_norm(cfg, "norm_mlp")(x))
         else:
-            x = x + MlpBlock(cfg, name="mlp")(mk_norm("norm_mlp")(x))
-        return x
+            y = MlpBlock(cfg, name="mlp")(_norm(cfg, "norm_mlp")(x))
+        return x + _times(y, res)
 
 
 class PipelinedBlocks(nn.Module):
@@ -818,13 +987,19 @@ class Transformer(nn.Module):
                 "CLIs do this automatically)")
         if infer and not cfg.causal:
             raise ValueError("decode requires a causal model")
-        if infer and not cfg.use_rope:
+        if infer and cfg.position == "learned":
             # Learned positions would need the cache index at this level.
-            raise NotImplementedError("decode requires use_rope=True")
+            raise NotImplementedError(
+                "decode requires position 'rope' or 'none', not 'learned'")
+        if cfg.pipeline and "mamba" in cfg.layer_types:
+            raise NotImplementedError(
+                "pipeline=True stacks identical blocks; layer_types mixes "
+                "two kinds")
         embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="embedder",
                          dtype=cfg.dtype, param_dtype=cfg.param_dtype)
-        x = constrain_residual(embed(tokens))
-        if not cfg.use_rope:
+        x = constrain_residual(_times(embed(tokens),
+                                      cfg.embedding_multiplier))
+        if cfg.position == "learned":
             pos = positions if positions is not None else (
                 jnp.arange(tokens.shape[1])[None, :])
             pos_emb = nn.Embed(cfg.max_seq_len, cfg.d_model, name="pos_embedder",
@@ -842,7 +1017,7 @@ class Transformer(nn.Module):
             use_remat = cfg.remat and not infer
             block = nn.remat(Block, static_argnums=()) if use_remat else Block
             for i in range(cfg.n_layers):
-                blk = block(cfg, name=f"layer_{i}")
+                blk = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")
                 if use_remat:
                     # remat traces every kwarg; the decode/prefill bools
                     # must stay Python-static, and here they are both False.
@@ -852,11 +1027,12 @@ class Transformer(nn.Module):
                             decode=decode, prefill=prefill, extend=extend,
                             seq_lengths=seq_lengths)
                 x = constrain_residual(y)
-        norm = (nn.RMSNorm if cfg.norm == "rms" else nn.LayerNorm)
-        x = norm(dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="norm_f")(x)
+        x = _norm(cfg, "norm_f")(x)
         if cfg.tie_embeddings:
             # Tied head reads the (unquantized) embedding table.
             logits = embed.attend(x.astype(cfg.param_dtype))
         else:
             logits = _proj(cfg, cfg.vocab_size, "lm_head")(x)
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
         return logits

@@ -92,11 +92,13 @@ def dot_product_attention(
     kv_lengths: Optional[jax.Array] = None,
     impl: str = "auto",
     axis_name: Optional[str] = None,  # sp axis for ring attention
+    softmax_scale: Optional[float] = None,
 ) -> jax.Array:
     """``kv_lengths`` [B]: declares the mask to be SUFFIX padding (keys at
     positions >= kv_lengths[b] invalid) — the flash kernel's near-free
     masking path. Callers that pass it should pass the equivalent ``mask``
-    too, for the impls that don't read lengths."""
+    too, for the impls that don't read lengths. ``softmax_scale``: in
+    place of 1/sqrt(head_dim)."""
     if impl == "auto":
         # On an sp>1 mesh the sequence dim is sharded and ring attention is
         # the only impl that keeps it that way. Otherwise flash above the
@@ -134,7 +136,11 @@ def dot_product_attention(
             S = k.shape[1]
             mask = (jnp.arange(S)[None, :] < kv_lengths[:, None])
             mask = mask[:, None, None, :]  # [B, 1, 1, S]
-        return xla_attention(q, k, v, causal=causal, mask=mask)
+        return xla_attention(q, k, v, causal=causal, mask=mask,
+                             softmax_scale=softmax_scale)
+    if softmax_scale is not None:
+        # The kernels scale by 1/sqrt(head_dim): fold the rest into q.
+        q = q * (softmax_scale * q.shape[-1] ** 0.5)
     if impl == "flash":
         from serverless_learn_tpu.ops.pallas.flash_attention import flash_attention
 

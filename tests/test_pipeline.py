@@ -239,7 +239,7 @@ def test_pp_sp_suffix_lengths_match_dp(devices):
 
     cfg = TransformerConfig(
         vocab_size=64, d_model=32, n_layers=4, n_heads=2, n_kv_heads=2,
-        d_ff=64, max_seq_len=32, causal=True, use_rope=True,
+        d_ff=64, max_seq_len=32, causal=True, position="rope",
         suffix_padding_mask=True, pipeline=True, pipeline_microbatches=2,
         dtype=jnp.float32, param_dtype=jnp.float32)
     module = Transformer(cfg)
@@ -275,7 +275,7 @@ def test_pp_sp_rejects_noncausal(devices):
 
     cfg = TransformerConfig(vocab_size=64, d_model=32, n_layers=4,
                             n_heads=2, d_ff=64, max_seq_len=64,
-                            causal=False, use_rope=True, pipeline=True,
+                            causal=False, position="rope", pipeline=True,
                             pipeline_microbatches=2)
     with pytest.raises(NotImplementedError, match="causal"):
         jax.eval_shape(

@@ -420,3 +420,51 @@ def test_generation_server_takes_a_sink(model, tmp_path):
         srv.stop()
     kinds = {r.get("event") for r in sink.records}
     assert {"span", "sched_iter"} <= kinds
+
+
+# ---- ``state_resets``: slots whose recurrent state started from zero ------
+
+def test_a_model_without_slot_state_resets_nothing(run):
+    assert _total(run, "state_resets") == 0
+    assert run["engine"].state_resets_total == 0
+
+
+@pytest.fixture(scope="module")
+def recurrent_model(devices):
+    """The registry's tiny hybrid: Mamba, Mamba, attention, Mamba."""
+    bundle = get_model("granite_hybrid_tiny", dtype=jnp.float32,
+                       param_dtype=jnp.float32, max_seq_len=64)
+    params = bundle.module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    return bundle.module, params
+
+
+@pytest.mark.parametrize("num_blocks", [0, 16],
+                         ids=["roomy_pool", "one_sequence_of_pool"])
+def test_state_resets_are_admissions_plus_readmissions(recurrent_model,
+                                                       num_blocks):
+    """A prefill row at position 0 starts its slot's state from zero: one
+    per admission and one per re-admission after a preemption, exactly.
+    The tight pool (one max-length sequence under long budgets) preempts;
+    the roomy one never does."""
+    module, params = recurrent_model
+    sink = ListSink()
+    eng = ContinuousBatchingEngine(
+        module, params, max_slots=MAX_SLOTS, chunk_size=CHUNK,
+        kv=KVCacheConfig(block_size=4, prefill_chunk=4,
+                         num_blocks=num_blocks),
+        registry=MetricsRegistry(), event_log=sink)
+    requests = [(p, 24) for p, _ in UNIQUE]
+    replies = _drive_by_hand(eng, requests)
+    assert [len(r["new_tokens"]) for r in replies] == [24] * len(requests)
+    iters = [r for r in sink.records if r.get("event") == "sched_iter"]
+    resets = sum(r["state_resets"] for r in iters)
+    assert (eng.preemptions > 0) == bool(num_blocks)
+    assert resets == eng.state_resets_total \
+        == len(requests) + eng.preemptions
+    for r in iters:
+        # One row a slot a program: never more resets than rows, never
+        # more rows than the slots mid-prefill times the programs.
+        assert r["state_resets"] <= r["prefill_row_chunks"] \
+            <= r["prefill_steps"] * MAX_SLOTS
+        assert r["prefill_rows"] <= r["slots_prefilling"]
