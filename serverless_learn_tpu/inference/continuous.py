@@ -9,8 +9,8 @@ The slots' keys and values live in a **paged KV pool**
 (``inference/kvcache.py``, ``KVCacheConfig``; ``kv=None`` means
 ``KVCacheConfig()``):
 
-* Each layer owns a block pool ``pages_k/v [num_blocks, block_size, K,
-  D]``; a host-side free-list allocator hands pages to slots through
+* Each layer owns a block pool ``pages_k/v [num_blocks, block_size,
+  K * D]``; a host-side free-list allocator hands pages to slots through
   per-slot block tables, so a slot only holds pages for tokens it has
   actually produced and retirement returns them immediately. Decode runs
   over a COMPACTED live batch with a bucketed table window ``W`` —

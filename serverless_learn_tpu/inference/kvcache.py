@@ -8,10 +8,11 @@ of the serving engine's paged pool instead:
 
 * :class:`BlockPool` — a free-list allocator over ``num_blocks`` page
   ids with per-block refcounts. The device arrays it indexes into live
-  per attention layer (``pages_k/v [num_blocks, block_size, K, D]``,
-  ``models/transformer.py``); the SAME id addresses every layer's pool,
-  so one host-side table drives all layers. Exhaustion raises the typed
-  :class:`KVBlocksExhausted` — admission backpressure, never a crash.
+  per attention layer (``pages_k/v [num_blocks, block_size, K * D]``: a
+  token's heads are one row, ``models/transformer.py``); the SAME id
+  addresses every layer's pool, so one host-side table drives all
+  layers. Exhaustion raises the typed :class:`KVBlocksExhausted` —
+  admission backpressure, never a crash.
 * :class:`PrefixTrie` — hash-consed shared-prefix reuse. Nodes sit at
   block granularity (one node per ``block_size``-token chunk, keyed by
   the chunk's token tuple); a registered node holds its own pool

@@ -130,7 +130,7 @@ class TransformerConfig:
     head_dim_override: Optional[int] = None  # local-slice cfgs must pin it
     # Paged KV cache for INFERENCE (round 13): > 0 replaces the per-row
     # monolithic ``cached_k/v [B, max_seq_len, K, D]`` with one shared
-    # block pool per layer (``pages_k/v [kv_pages, kv_page_size, K, D]``)
+    # block pool per layer (``pages_k/v [kv_pages, kv_page_size, K * D]``)
     # plus a per-row block table (``page_tbl [B, W]`` of page ids, the
     # sentinel id == kv_pages marking unallocated entries) and the same
     # ``cache_index`` vector. The table is HOST-OWNED: the engine
@@ -375,10 +375,14 @@ class Attention(nn.Module):
                         "kv_page_size > 0 requires kv_pages > 0")
                 max_pages = -(-cfg.max_seq_len // ps)
                 is_init = not self.has_variable("cache", "pages_k")
+                # A token's K heads are ONE row of K * D: the leaves' minor
+                # dimension fills whole lane tiles whatever the head's
+                # width, so the scatter and the gather below want the same
+                # layout and the compiler never re-lays the pool out.
                 pk = self.variable("cache", "pages_k", jnp.zeros,
-                                   (P, ps, K, D), k.dtype)
+                                   (P, ps, K * D), k.dtype)
                 pv = self.variable("cache", "pages_v", jnp.zeros,
-                                   (P, ps, K, D), v.dtype)
+                                   (P, ps, K * D), v.dtype)
                 tbl = self.variable(
                     "cache", "page_tbl",
                     lambda: jnp.full((B, max_pages), P, jnp.int32))
@@ -417,10 +421,10 @@ class Attention(nn.Module):
                     offs = positions_bt % ps
                     pk.value = pk.value.at[
                         ids.reshape(-1), offs.reshape(-1)].set(
-                        k.reshape(B * T, K, D), mode="drop")
+                        k.reshape(B * T, K * D), mode="drop")
                     pv.value = pv.value.at[
                         ids.reshape(-1), offs.reshape(-1)].set(
-                        v.reshape(B * T, K, D), mode="drop")
+                        v.reshape(B * T, K * D), mode="drop")
                     ci.value = pos0 + new_len
                     # Attention reads the gathered window; sentinel table
                     # entries clip to a real page whose garbage the
