@@ -143,6 +143,23 @@ for _site in ("_paged_prefill_jit", "_paged_chunk_jit"):
 del _site
 
 
+def program_name(kind: str, key: tuple) -> str:
+    """The compiled module's name of a serving program: ``"pre"`` with
+    its ``(nb, T, W)`` bucket is ``jit_pre_n8t32w64``, ``"chunk"`` with
+    its ``(nb, W)`` bucket ``jit_chunk_n8w64``. A device trace names each
+    launch by it, and the ``sched_iter`` record's ``programs`` entries
+    carry it."""
+    return f"jit_{kind}_" + "".join(
+        f"{c}{v}" for c, v in zip("ntw" if kind == "pre" else "nw", key))
+
+
+def _named(fn, kind: str, key: tuple):
+    """``fn`` renamed so that ``jax.jit`` calls its module
+    ``program_name(kind, key)``; the traced body is unchanged."""
+    fn.__name__ = fn.__qualname__ = program_name(kind, key)[len("jit_"):]
+    return fn
+
+
 def _fold_keys(seeds: jax.Array, positions: jax.Array) -> jax.Array:
     """Per-slot PRNG keys: fold_in(PRNGKey(seed_b), pos_b)."""
     return jax.vmap(
@@ -307,6 +324,10 @@ class ContinuousBatchingEngine:
         self.preemptions = 0
         self._admit_counter = 0
         self.event_log = event_log
+        # The programs the current iteration launched, for its
+        # ``sched_iter`` record: a list while an event sink is set, else
+        # None (``_iterate``).
+        self._launches: Optional[list] = None
         # ---- per-request waterfall ledger (round 21) ----
         self.waterfall = waterfall if waterfall is not None \
             else WaterfallConfig()
@@ -523,7 +544,7 @@ class ContinuousBatchingEngine:
             )
             return pages, out, tok0
 
-        fn = jax.jit(pre, donate_argnums=(1, 2))
+        fn = jax.jit(_named(pre, "pre", key), donate_argnums=(1, 2))
         self._prefill_jits[key] = fn
         return fn
 
@@ -586,7 +607,7 @@ class ContinuousBatchingEngine:
 
         # Donate the state: the pool is the engine's dominant allocation
         # and each chunk consumes its predecessor's.
-        fn = jax.jit(chunk, donate_argnums=(1, 2))
+        fn = jax.jit(_named(chunk, "chunk", key), donate_argnums=(1, 2))
         self._chunk_jits[key] = fn
         return fn
 
@@ -1052,6 +1073,9 @@ class ContinuousBatchingEngine:
                 jnp.asarray(seed), jnp.asarray(cow_src),
                 jnp.asarray(cow_dst))
         t_j1 = time.perf_counter()
+        if self._launches is not None:
+            self._launches.append(("pre", key, len(batch),
+                                   int(lens.sum()), t_j0, new_bucket))
         # Boundary events: in-flight decode traces see this window as a
         # prefill-budget steal (or a new-bucket compile, which dominates
         # whatever prefill rode along in it). Compile is an INTERVAL —
@@ -1158,6 +1182,9 @@ class ContinuousBatchingEngine:
                 jnp.asarray(tbl_rows), jnp.asarray(live_arr))
         if new_bucket:
             self._wf_events.note("compile", t_j0, time.perf_counter())
+        if self._launches is not None:
+            self._launches.append(("chunk", key, len(rows), len(rows) * C,
+                                   t_j0, new_bucket))
         self.chunks_run += 1
         self._m_chunks.inc()
         self.decoded_rows_total += len(rows)
@@ -1310,7 +1337,8 @@ class ContinuousBatchingEngine:
                 self.harvest_wait_s_total, self.state_resets_total)
 
     def _sched_record(self, seq: int, ts: list, idle: bool, c0: tuple,
-                      census: tuple, queued: int, sent: list) -> dict:
+                      census: tuple, queued: int, sent: list,
+                      launches: list) -> dict:
         """The ``sched_iter`` record of one working iteration of the
         dispatch loop: what it found in the slots, what it sent to the
         device, what came back, and where the host's time went. Built
@@ -1321,19 +1349,22 @@ class ContinuousBatchingEngine:
         the harvests (the clock of the request spans' marks). ``c0``:
         ``_sched_counts()`` at its start. ``census``/``queued``: taken
         after admission, before the prefill programs. ``sent``: the futures
-        it dispatched. No ``marks_s`` or ``waterfall`` key: readers pick
-        request spans out of the same sink by those."""
+        it dispatched; ``launches``: the same programs as ``_prefill_step``
+        and ``_decode_step_paged`` noted them, in dispatch order. No
+        ``marks_s`` or ``waterfall`` key: readers pick request spans out
+        of the same sink by those."""
         (pre_toks, hit_toks, dec_rows, chunks, toks_out,
          finished, released, wait_s, resets) = (
             b - a for a, b in zip(c0, self._sched_counts()))
         dec, pre, free, other = census
-        # ``prefill_steps``: the prefill programs it dispatched;
-        # ``prefill_row_chunks``: the rows they carried (a snapshot has
-        # one entry a row); ``prefill_rows``: the distinct slots they fed
-        # (a slot that got eight rows of one program, or a row in each
-        # of three, counts once).
-        pre_futs = [f for f in sent if f[0] == "prefill"]
-        pre_rows = len({e[0] for f in pre_futs for e in f[2]})
+        # ``prefill_rows``: the distinct slots the prefill programs fed (a
+        # slot that got eight rows of one program, or a row in each of
+        # three, counts once). ``programs``: every program launched, with
+        # its module name, the row-chunks (prefill) or live rows (decode)
+        # it carried, its prompt tokens or row-steps, the launch's
+        # instant and whether the launch compiled its bucket.
+        pre_rows = len({e[0] for f in sent if f[0] == "prefill"
+                        for e in f[2]})
         ids = dict.fromkeys(
             e[1].span.span_id for f in sent for e in f[2]
             if e[1].span is not None)
@@ -1348,9 +1379,12 @@ class ContinuousBatchingEngine:
             "max_slots": self.max_slots, "slots_decoding": dec,
             "slots_prefilling": pre, "slots_free": free,
             "slots_other": other, "queued": queued,
-            "prefill_steps": len(pre_futs), "prefill_rows": pre_rows,
-            "prefill_row_chunks": sum(len(f[2]) for f in pre_futs),
-            "prefill_tokens": pre_toks, "state_resets": resets,
+            "programs": [
+                {"prog": program_name(kind, key), "rows": rows,
+                 "tokens": toks, "t_s": t_s, "compiled": compiled}
+                for kind, key, rows, toks, t_s, compiled in launches],
+            "prefill_rows": pre_rows, "prefill_tokens": pre_toks,
+            "state_resets": resets,
             "prefill_hit_tokens": hit_toks, "decode_rows": dec_rows,
             "decode_steps": chunks * self.chunk_size,
             "tokens_out": toks_out, "requests_finished": finished,
@@ -1361,7 +1395,7 @@ class ContinuousBatchingEngine:
         while not self._stop.is_set():
             seq += 1
             # ``sched.*``: the iteration and its phases on the profiler's
-            # clock, beside the jit_pre / jit_chunk programs they launch
+            # clock, beside the jit_pre_* / jit_chunk_* programs they launch
             # in any captured trace.
             with annotate("sched.iter"):
                 self._iterate(seq)
@@ -1386,6 +1420,7 @@ class ContinuousBatchingEngine:
         futures, staged = self._futures, self._staged
         sink = self.event_log   # read once: its owner may swap it
         on = sink is not None
+        self._launches = [] if on else None
         if on:
             ts = [time.perf_counter()]
             c0 = self._sched_counts()
@@ -1459,7 +1494,8 @@ class ContinuousBatchingEngine:
                 # of crash forensics are for request spans and lifecycle
                 # events, not for several scheduler records a second.
                 sink.emit(self._sched_record(
-                    seq, ts, idle, c0, census, queued, sent))
+                    seq, ts, idle, c0, census, queued, sent,
+                    self._launches))
         except Exception as ex:
             # Fail every in-flight and staged request; a poisoned
             # device state must not wedge the dispatcher silently.

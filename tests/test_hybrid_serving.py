@@ -197,6 +197,13 @@ def _by_hand(module, params, requests, **kw):
     return eng, sent, keys, records
 
 
+def _prefill_entries(records) -> list:
+    """The records' prefill programs (``programs`` entries), in dispatch
+    order."""
+    return [p for r in records for p in r["programs"]
+            if p["prog"].startswith("jit_pre_")]
+
+
 def test_a_preempted_request_restarts_token_identical(hybrid):
     """A pool of one max-length sequence under four long requests: the
     youngest slots are preempted and re-admitted from their first token,
@@ -228,9 +235,11 @@ def test_one_row_chunk_a_slot_a_program_and_no_trie(hybrid):
     # Both slots prefill side by side, a row each; the longer goes on
     # alone. Never more rows than slots mid-prefill.
     assert keys == [(2, 4, 1), (2, 4, 4), (1, 4, 4), (1, 4, 4), (1, 4, 16)]
-    assert sum(r["prefill_row_chunks"] for r in records) == 5 + 2
-    assert all(r["prefill_row_chunks"] <= r["prefill_steps"] * 2
-               for r in records)
+    pre = _prefill_entries(records)
+    assert [p["prog"] for p in pre] == [
+        "jit_pre_n2t4w1", "jit_pre_n2t4w4", "jit_pre_n1t4w4",
+        "jit_pre_n1t4w4", "jit_pre_n1t4w16"]
+    assert [p["rows"] for p in pre] == [2, 2, 1, 1, 1]
     assert eng._trie is None
     st = eng.kv_stats()
     assert st["prefix_cache"].startswith("off: a page hit cannot restore")
@@ -260,7 +269,11 @@ def test_an_attention_only_model_packs_and_keeps_its_trie(devices):
     # (two slots), its last beside the short prompt's first. The keys are
     # those the parent commit (2675f4e) dispatches for these requests.
     assert keys == [(2, 4, 4), (2, 4, 4), (2, 4, 16), (1, 4, 4)]
-    assert sum(r["prefill_row_chunks"] for r in records) == 7
+    pre = _prefill_entries(records)
+    assert [p["prog"] for p in pre] == [
+        "jit_pre_n2t4w4", "jit_pre_n2t4w4", "jit_pre_n2t4w16",
+        "jit_pre_n1t4w4"]
+    assert sum(p["rows"] for p in pre) == 7
     assert all(r["state_resets"] == 0 for r in records)
     assert eng._trie is not None and eng._trie.blocks_held > 0
     st = eng.kv_stats()

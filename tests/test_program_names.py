@@ -4,10 +4,15 @@ anything else.
 
 * ``jit_step_fn`` — ``chipbench/metrics/step_ms_p50.py`` takes the train
   step's ``XLA Modules`` events by ``"step_fn" in name``.
-* ``jit_chunk`` — ``chipbench/metrics/decode_roofline.py`` takes the
-  paged decode chunk's by ``"chunk" in name``.
-* ``jit_pre`` — the paged prefill program, as the result line's
-  ``breakdown`` prints it beside each idle gap.
+* ``jit_chunk_n{nb}w{W}`` — ``chipbench/metrics/decode_roofline.py``
+  takes the paged decode chunk's by ``"chunk" in name``.
+* ``jit_pre_n{nb}t{T}w{W}`` — the paged prefill program, as the result
+  line's ``breakdown`` prints it beside each idle gap. Both carry their
+  bucket (PR 38): ``chipbench/program_records.py`` lines the events whose
+  names start ``jit_pre_`` / ``jit_chunk_`` up with the ``sched_iter``
+  records' ``programs`` entries, which carry the same names
+  (``continuous.program_name``), for ``prefill_ms_per_token`` and
+  ``prefill_device_share``.
 * ``flash_fwd`` / ``flash_bwd_dq`` / ``flash_bwd_dkv`` — the Pallas
   kernels' HLO instructions in ``device_ops`` (``flash_attn_roofline``
   still finds them by their ``tpu_custom_call`` target).
@@ -23,7 +28,7 @@ from serverless_learn_tpu.config import (ExperimentConfig, KVCacheConfig,
                                          MeshConfig, TrainConfig)
 from serverless_learn_tpu.data.datasets import SyntheticSource
 from serverless_learn_tpu.inference.continuous import (
-    ContinuousBatchingEngine)
+    ContinuousBatchingEngine, program_name)
 from serverless_learn_tpu.models.registry import get_model
 from serverless_learn_tpu.ops.pallas import flash_attention as fa
 from serverless_learn_tpu.parallel.mesh import make_mesh
@@ -58,8 +63,7 @@ def _lower_train_step():
     return tr.step_fn.lower(tr.abstract_state(), batch)
 
 
-def _lower_paged_prefill(eng):
-    nb, T, W = 1, 8, 4
+def _lower_paged_prefill(eng, nb=1, T=8, W=4):
     st, i32 = eng._state, jnp.int32
     z = lambda dt: jnp.zeros((nb,), dt)
     return eng._paged_prefill_jit(nb, T, W).lower(
@@ -68,8 +72,7 @@ def _lower_paged_prefill(eng):
         z(jnp.float32), z(i32), z(i32), z(jnp.uint32), z(i32), z(i32))
 
 
-def _lower_paged_chunk(eng):
-    nb, W = 1, 4
+def _lower_paged_chunk(eng, nb=1, W=4):
     st = eng._state
     return eng._paged_chunk_jit(nb, W).lower(
         eng.params, st["pages"], st["vecs"],
@@ -77,13 +80,29 @@ def _lower_paged_chunk(eng):
 
 
 @pytest.mark.parametrize("program,name", [
-    ("train_step", "jit_step_fn"), ("paged_prefill", "jit_pre"),
-    ("paged_chunk", "jit_chunk")])
+    ("train_step", "jit_step_fn"), ("paged_prefill", "jit_pre_n1t8w4"),
+    ("paged_chunk", "jit_chunk_n1w4")])
 def test_program_module_names(engine, program, name):
     lowered = {"train_step": _lower_train_step,
                "paged_prefill": lambda: _lower_paged_prefill(engine),
                "paged_chunk": lambda: _lower_paged_chunk(engine)}[program]()
     assert _module_name(lowered) == name
+
+
+@pytest.mark.parametrize("kind,key,name", [
+    ("pre", (2, 4, 16), "jit_pre_n2t4w16"),
+    ("pre", (1, 8, 1), "jit_pre_n1t8w1"),
+    ("chunk", (2, 16), "jit_chunk_n2w16"),
+    ("chunk", (1, 1), "jit_chunk_n1w1")])
+def test_each_bucket_names_its_module(engine, kind, key, name):
+    """The module's name is the bucket's, ``program_name`` gives the same
+    string, and the readers' substrings and prefixes still find it."""
+    lowered = (_lower_paged_prefill(engine, *key) if kind == "pre"
+               else _lower_paged_chunk(engine, *key))
+    assert _module_name(lowered) == program_name(kind, key) == name
+    assert name.startswith(f"jit_{kind}_")
+    assert ("chunk" in name) == (kind == "chunk")
+    assert "step_fn" not in name
 
 
 def test_flash_kernels_are_named_apart():

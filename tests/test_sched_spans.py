@@ -14,6 +14,7 @@ chunks of the mid-prefill prompts, oldest admitted first,
 is stepped by hand, so that it is the same on every run.
 """
 
+import re
 import threading
 
 import jax
@@ -23,7 +24,7 @@ import pytest
 
 from serverless_learn_tpu.config import KVCacheConfig
 from serverless_learn_tpu.inference.continuous import (
-    ContinuousBatchingEngine, _Request)
+    ContinuousBatchingEngine, _Request, program_name)
 from serverless_learn_tpu.models.registry import get_model
 from serverless_learn_tpu.telemetry import MetricsRegistry, Span, flight
 
@@ -74,18 +75,34 @@ def _engine(module, params, budget: int = BUDGET,
         registry=MetricsRegistry(), **kw)
 
 
-def _count_prefill_programs(eng) -> list:
-    """Every prefill program the engine dispatches fetches its jit by
-    ``_paged_prefill_jit`` once: count the fetches."""
-    calls = []
-    real = eng._paged_prefill_jit
+def _count_programs(eng) -> tuple:
+    """Every program the engine dispatches fetches its jit by
+    ``_paged_prefill_jit`` or ``_paged_chunk_jit`` once: count the
+    fetches, as (prefill keys, chunk keys) in dispatch order."""
+    pre, chunk = [], []
+    real_pre, real_chunk = eng._paged_prefill_jit, eng._paged_chunk_jit
 
-    def counting(nb, T, W):
-        calls.append((nb, T, W))
-        return real(nb, T, W)
+    def counting_pre(nb, T, W):
+        pre.append((nb, T, W))
+        return real_pre(nb, T, W)
 
-    eng._paged_prefill_jit = counting
-    return calls
+    def counting_chunk(nb, W):
+        chunk.append((nb, W))
+        return real_chunk(nb, W)
+
+    eng._paged_prefill_jit = counting_pre
+    eng._paged_chunk_jit = counting_chunk
+    return pre, chunk
+
+
+def _prefill(rec) -> list:
+    """The prefill programs among a record's ``programs`` entries."""
+    return [p for p in rec["programs"] if p["prog"].startswith("jit_pre_")]
+
+
+def _chunks(rec) -> list:
+    return [p for p in rec["programs"]
+            if p["prog"].startswith("jit_chunk_")]
 
 
 def _drive(eng, requests, first_alone: bool = False) -> list:
@@ -146,7 +163,7 @@ def _run_scenario(model, name: str) -> dict:
     eng = _engine(module, params,
                   budget=0 if name == "derived" else BUDGET,
                   prefix_cache=name != "no_prefix_cache", event_log=sink)
-    programs = _count_prefill_programs(eng)
+    programs, chunk_keys = _count_programs(eng)
     ring0 = len(flight.events())
     try:
         if name == "derived":
@@ -158,6 +175,7 @@ def _run_scenario(model, name: str) -> dict:
         eng.stop()
     return {"scenario": name, "requests": requests,
             "replies": replies, "engine": eng, "programs": programs,
+            "chunk_keys": chunk_keys,
             "iters": [r for r in sink.records
                       if r.get("event") == "sched_iter"],
             "spans": [r for r in sink.records if r.get("event") == "span"],
@@ -245,26 +263,99 @@ def _row_chunks(run) -> int:
     return n
 
 
-def test_prefill_steps_are_the_programs_dispatched(run):
-    """``prefill_steps`` counts programs, ``prefill_row_chunks`` the rows
-    they carried (the engine's ``prefill_chunks_run``, the requests' own
-    waterfall chunks), ``prefill_rows`` the distinct slots they fed."""
+def test_programs_are_the_programs_dispatched(run):
+    """``programs`` lists every program the engine launched, in dispatch
+    order: its prefill entries are the programs fetched at
+    ``_paged_prefill_jit``, their rows the row-chunks (the engine's
+    ``prefill_chunks_run``, the requests' own waterfall chunks) and their
+    tokens the prompt tokens sent; its chunk entries are the decode
+    chunks, their rows the live rows. ``prefill_rows`` counts the
+    distinct slots the prefill programs fed."""
     eng = run["engine"]
-    assert _total(run, "prefill_steps") == len(run["programs"])
-    assert len(run["programs"]) > 0
-    assert _total(run, "prefill_row_chunks") == eng.prefill_chunks_run \
+    entries = [p for r in run["iters"] for p in r["programs"]]
+    pre = [p for r in run["iters"] for p in _prefill(r)]
+    chunks = [p for r in run["iters"] for p in _chunks(r)]
+    assert len(pre) + len(chunks) == len(entries)
+    assert [p["prog"] for p in pre] \
+        == [program_name("pre", k) for k in run["programs"]]
+    assert [p["prog"] for p in chunks] \
+        == [program_name("chunk", k) for k in run["chunk_keys"]]
+    assert len(pre) > 0 and len(chunks) == eng.chunks_run
+    assert sum(p["rows"] for p in pre) == eng.prefill_chunks_run \
         == _row_chunks(run)
+    assert sum(p["tokens"] for p in pre) == eng.prefill_tokens_total \
+        == _total(run, "prefill_tokens")
     assert _total(run, "prefill_rows") <= eng.prefill_chunks_run
     for r in run["iters"]:
-        assert bool(r["prefill_steps"]) == bool(r["prefill_rows"]) \
-            == bool(r["prefill_tokens"]) == bool(r["prefill_row_chunks"])
+        rows = sum(p["rows"] for p in _prefill(r))
+        assert sum(p["tokens"] for p in _prefill(r)) == r["prefill_tokens"]
+        assert bool(_prefill(r)) == bool(r["prefill_rows"]) \
+            == bool(r["prefill_tokens"])
         # No program is wider than the programs the engine has.
-        assert (r["prefill_steps"] <= r["prefill_row_chunks"]
-                <= r["prefill_steps"] * MAX_SLOTS)
-        assert r["prefill_rows"] <= min(r["slots_prefilling"],
-                                        r["prefill_row_chunks"])
-        assert r["prefill_tokens"] <= r["prefill_row_chunks"] * 4
+        assert all(1 <= p["rows"] <= MAX_SLOTS for p in _prefill(r))
+        assert r["prefill_rows"] <= min(r["slots_prefilling"], rows)
+        assert r["prefill_tokens"] <= rows * 4
+        # A chunk's rows are the record's decode rows, its tokens the
+        # row-steps it ran.
+        assert sum(p["rows"] for p in _chunks(r)) == r["decode_rows"]
+        assert len(_chunks(r)) * CHUNK == r["decode_steps"]
+        assert all(p["tokens"] == p["rows"] * CHUNK for p in _chunks(r))
     assert all(nb <= MAX_SLOTS and T <= 8 for nb, T, _ in run["programs"])
+
+
+def _lowered_name(eng, kind: str, key: tuple) -> str:
+    st, i32 = eng._state, jnp.int32
+    if kind == "chunk":
+        nb, W = key
+        lowered = eng._paged_chunk_jit(nb, W).lower(
+            eng.params, st["pages"], st["vecs"], jnp.zeros((nb, W), i32),
+            jnp.zeros((nb,), i32))
+    else:
+        nb, T, W = key
+        z = lambda dt: jnp.zeros((nb,), dt)
+        lowered = eng._paged_prefill_jit(nb, T, W).lower(
+            eng.params, st["pages"], st["vecs"], jnp.zeros((nb, W), i32),
+            z(i32), jnp.zeros((nb, T), i32), z(i32), z(i32), z(jnp.bool_),
+            z(jnp.float32), z(i32), z(i32), z(jnp.uint32), z(i32), z(i32))
+    return re.search(r"module @(\w+)", lowered.as_text()).group(1)
+
+
+def test_each_entry_names_its_compiled_module(scenario):
+    """An entry's ``prog`` is the name of the module its bucket lowers
+    to: the name a device trace gives the launch."""
+    run = scenario("unique")
+    eng = run["engine"]
+    names = {p["prog"] for r in run["iters"] for p in r["programs"]}
+    keys = [("pre", k) for k in dict.fromkeys(run["programs"])] \
+        + [("chunk", k) for k in dict.fromkeys(run["chunk_keys"])]
+    assert names == {program_name(kind, k) for kind, k in keys}
+    for kind, k in keys:
+        assert _lowered_name(eng, kind, k) == program_name(kind, k)
+
+
+def test_compiled_is_each_buckets_first_launch(run):
+    """The engine starts with no program: an entry is ``compiled``
+    exactly where its bucket is launched for the first time."""
+    seen = set()
+    for r in run["iters"]:
+        for p in r["programs"]:
+            assert p["compiled"] is (p["prog"] not in seen), p
+            seen.add(p["prog"])
+    assert seen
+
+
+def test_launch_instants_lie_in_their_iteration_in_order(run):
+    """``t_s`` is on the clock of ``t0_s`` (``perf_counter``): inside its
+    record's iteration, and never earlier than the launch before it."""
+    last = -float("inf")
+    for r in run["iters"]:
+        for p in r["programs"]:
+            assert r["t0_s"] <= p["t_s"] <= r["t0_s"] + r["dur_s"], (r, p)
+            assert p["t_s"] >= last
+            last = p["t_s"]
+        # Prefill programs go first, then the one decode chunk.
+        kinds = [p["prog"].startswith("jit_chunk_") for p in r["programs"]]
+        assert kinds == sorted(kinds) and sum(kinds) <= 1
 
 
 def test_derived_quota_feeds_every_prefilling_slot(scenario):
@@ -280,17 +371,18 @@ def test_derived_quota_feeds_every_prefilling_slot(scenario):
     assert int(run["engine"]._m_kv_blocked.value) == 0
     stalled = unbounded = capped = unfed = 0
     for r in run["iters"]:
+        pre = _prefill(r)
         if not r["slots_prefilling"]:
-            assert r["prefill_steps"] == 0
+            assert not pre
             continue
         if r["slots_decoding"]:
             stalled += 1
-            assert 1 <= r["prefill_steps"] <= CHUNK // 2, r
-            capped += r["prefill_steps"] == CHUNK // 2
+            assert 1 <= len(pre) <= CHUNK // 2, r
+            capped += len(pre) == CHUNK // 2
             if r["prefill_rows"] < r["slots_prefilling"]:
                 unfed += 1
-                assert r["prefill_row_chunks"] \
-                    == (CHUNK // 2) * MAX_SLOTS, r
+                assert [p["rows"] for p in pre] \
+                    == [MAX_SLOTS] * (CHUNK // 2), r
         else:
             unbounded += 1
             assert r["prefill_rows"] == r["slots_prefilling"], r
@@ -300,8 +392,8 @@ def test_derived_quota_feeds_every_prefilling_slot(scenario):
     # iteration, in ceil(8 / max_slots) programs.
     assert stalled and unbounded and capped and unfed
     first = run["iters"][0]
-    assert first["prefill_steps"] == -(-8 // MAX_SLOTS)
-    assert first["prefill_row_chunks"] == 8
+    assert len(_prefill(first)) == -(-8 // MAX_SLOTS)
+    assert sum(p["rows"] for p in _prefill(first)) == 8
     assert first["prefill_tokens"] == len(LONG[0][0])
 
 
@@ -465,6 +557,7 @@ def test_state_resets_are_admissions_plus_readmissions(recurrent_model,
     for r in iters:
         # One row a slot a program: never more resets than rows, never
         # more rows than the slots mid-prefill times the programs.
-        assert r["state_resets"] <= r["prefill_row_chunks"] \
-            <= r["prefill_steps"] * MAX_SLOTS
+        pre = _prefill(r)
+        assert r["state_resets"] <= sum(p["rows"] for p in pre) \
+            <= len(pre) * MAX_SLOTS
         assert r["prefill_rows"] <= r["slots_prefilling"]
