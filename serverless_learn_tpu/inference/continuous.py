@@ -109,7 +109,7 @@ from serverless_learn_tpu.inference.batching import PROMPT_BUCKETS, _bucket
 from serverless_learn_tpu.inference.generate import init_cache
 from serverless_learn_tpu.inference.kvcache import (BlockPool, PrefixTrie,
                                                     pages_for)
-from serverless_learn_tpu.models.transformer import slot_leaves
+from serverless_learn_tpu.models.transformer import moe_layers, slot_leaves
 from serverless_learn_tpu.telemetry import (RATE_BUCKETS, SIZE_BUCKETS,
                                             Span, TraceContext, get_registry)
 from serverless_learn_tpu.telemetry import flight, goodput
@@ -264,6 +264,10 @@ class ContinuousBatchingEngine:
         # continues the row before it starts from that row's state
         # (``_paged_prefill_jit``).
         self._slot_leaves = slot_leaves(module.cfg)
+        # A model with experts: each decode chunk also returns, per step
+        # and expert layer, how many distinct experts its rows were
+        # routed to (``_paged_chunk_jit``), harvested with the tokens.
+        self._moe_layers = moe_layers(module.cfg)
         prefix_cache = kv.prefix_cache and not self._slot_leaves
         num_blocks = kv.num_blocks or (
             max_slots * self._max_pages
@@ -317,6 +321,9 @@ class ContinuousBatchingEngine:
         # plus re-admissions after preemption.
         self.state_resets_total = 0
         self.tokens_out_total = 0
+        # Distinct experts the harvested decode steps read, summed over
+        # steps and expert layers (0 for a model without experts).
+        self.experts_read_total = 0
         self.harvest_wait_s_total = 0.0
         # Decode row accounting: ``decoded_rows_total`` counts rows that
         # still owed tokens at dispatch; ``dispatched_rows_total`` counts
@@ -573,7 +580,9 @@ class ContinuousBatchingEngine:
         if key in self._chunk_jits:
             return self._chunk_jits[key]
         module, C, ktop = self._pmod, self.chunk_size, self.max_top_k
-        per_slot = self._slot_leaves
+        per_slot, M = self._slot_leaves, self.max_slots
+        n_experts = module.cfg.n_experts if self._moe_layers else 0
+        routed = ["cache", "moe_route"] if n_experts else ["cache"]
 
         def chunk(params, pages, vecs, tbl, live):
             def take(x):
@@ -585,12 +594,19 @@ class ContinuousBatchingEngine:
             temp, topk = take(vecs["temp"]), take(vecs["topk"])
             eos, seed = take(vecs["eos"]), take(vecs["seed"])
 
+            def experts_read(ids):   # [nb, 1, K] -> distinct experts
+                real = (live < M)[:, None]   # not padding
+                hit = jnp.zeros((n_experts,), jnp.bool_).at[
+                    jnp.where(real, ids[:, 0], n_experts)].set(
+                    True, mode="drop")
+                return hit.sum(dtype=jnp.int32)
+
             def step(carry, _):
                 pages, tok, pos, done, ci = carry
                 cache = kvcache.with_tables(pages, tbl, ci)
                 logits, upd = module.apply(
                     {"params": params, "cache": cache}, tok[:, None],
-                    decode=True, mutable=["cache"])
+                    decode=True, mutable=routed)
                 pages, ci1 = kvcache.split_cache(upd["cache"])
                 ci = ci + 1 if ci1 is None else ci1
                 nxt = _sample_slots(logits[:, 0], temp, topk, seed, pos,
@@ -600,13 +616,20 @@ class ContinuousBatchingEngine:
                 keep = jnp.maximum(eos, 0)
                 nxt = jnp.where(done, keep, nxt)
                 done = done | ((eos >= 0) & (nxt == eos))
-                return (pages, nxt, pos + 1, done, ci), nxt
+                out = nxt
+                if n_experts:
+                    out = (nxt, jnp.stack([
+                        experts_read(ids) for ids in
+                        jax.tree_util.tree_leaves(upd["moe_route"])]))
+                return (pages, nxt, pos + 1, done, ci), out
 
             # The live slots' rows of the slot leaves ride through the
             # steps as a compact batch and go back at the chunk's end.
             rows, _ = kvcache.take_slots(pages, per_slot, live)
             (rows, tok, pos, done, ci), toks = jax.lax.scan(
                 step, (rows, tok, pos, done, ci), None, length=C)
+            if n_experts:   # [C, nb] tokens, [C, expert layers] counts
+                toks, experts = toks
             pages = kvcache.put_slots(pages, rows, per_slot, live)
 
             def put(big, new):
@@ -617,7 +640,8 @@ class ContinuousBatchingEngine:
                        pos=put(vecs["pos"], pos),
                        done=put(vecs["done"], done),
                        ci=put(vecs["ci"], ci))
-            return pages, out, jnp.swapaxes(toks, 0, 1)  # [nb, C]
+            toks = jnp.swapaxes(toks, 0, 1)  # [nb, C]
+            return pages, out, (toks, experts) if n_experts else toks
 
         # Donate the state: the pool is the engine's dominant allocation
         # and each chunk consumes its predecessor's.
@@ -1213,7 +1237,8 @@ class ContinuousBatchingEngine:
         # the copy launched at dispatch, harvest finds the bytes already
         # en route and the transfer overlaps the in-flight chunks.
         try:
-            toks.copy_to_host_async()
+            for a in jax.tree_util.tree_leaves(toks):
+                a.copy_to_host_async()
         except (AttributeError, RuntimeError):
             pass  # platform without async D2H: harvest blocks
         return ("pchunk", toks, snapshot)
@@ -1223,7 +1248,11 @@ class ContinuousBatchingEngine:
     def _harvest(self, fut) -> None:
         kind, toks, snapshot = fut
         t_h0 = time.perf_counter()
-        arr = np.asarray(jax.device_get(toks))  # blocks; overlaps in-flight
+        got = jax.device_get(toks)  # blocks; overlaps in-flight
+        if isinstance(got, tuple):   # a decode chunk of a model with experts
+            got, experts = got
+            self.experts_read_total += int(np.sum(experts))
+        arr = np.asarray(got)
         t_now = time.perf_counter()
         self.harvest_wait_s_total += t_now - t_h0
         if t_now - t_h0 > 1e-4:
@@ -1348,7 +1377,8 @@ class ContinuousBatchingEngine:
                 int(self._m_kv_hit_tokens.value), self.decoded_rows_total,
                 self.chunks_run, self.tokens_out_total,
                 self.requests_finished, self.slots_released_total,
-                self.harvest_wait_s_total, self.state_resets_total)
+                self.harvest_wait_s_total, self.state_resets_total,
+                self.experts_read_total)
 
     def _sched_record(self, seq: int, ts: list, idle: bool, c0: tuple,
                       census: tuple, queued: int, sent: list,
@@ -1368,7 +1398,7 @@ class ContinuousBatchingEngine:
         ``marks_s`` or ``waterfall`` key: readers pick request spans out
         of the same sink by those."""
         (pre_toks, hit_toks, dec_rows, chunks, toks_out,
-         finished, released, wait_s, resets) = (
+         finished, released, wait_s, resets, experts) = (
             b - a for a, b in zip(c0, self._sched_counts()))
         dec, pre, free, other = census
         # ``prefill_rows``: the distinct slots the prefill programs fed (a
@@ -1402,7 +1432,8 @@ class ContinuousBatchingEngine:
             "prefill_hit_tokens": hit_toks, "decode_rows": dec_rows,
             "decode_steps": chunks * self.chunk_size,
             "tokens_out": toks_out, "requests_finished": finished,
-            "slots_released": released, "requests": list(ids)}
+            "slots_released": released, "experts_read": experts,
+            "requests": list(ids)}
 
     def _dispatch_loop(self):
         seq = 0
@@ -1558,6 +1589,8 @@ class ContinuousBatchingEngine:
         per_slot = kvcache.slot_bytes(self._state.get("pages", {}),
                                       self._slot_leaves)
         out.update(
+            moe_layers=self._moe_layers,
+            n_experts=self.module.cfg.n_experts if self._moe_layers else 0,
             state_slots=self.max_slots if self._slot_leaves else 0,
             state_bytes_per_slot=per_slot,
             state_bytes=per_slot * self.max_slots,

@@ -43,6 +43,8 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
+import heapq
+import itertools
 import math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -341,37 +343,46 @@ class PrefixTrie:
         return {"block_size": self.block_size, "blocks": self._count,
                 "hashes": hashes, "top": top}
 
-    def _leaves(self) -> List[Tuple[_Node, _Node, Tuple[int, ...]]]:
-        out = []
-
-        def walk(node):
-            for key, child in node.children.items():
-                if child.children:
-                    walk(child)
-                else:
-                    out.append((node, child, key))
-
-        walk(self._root)
-        return out
-
     def release(self, n: int) -> int:
         """Evict up to ``n`` LRU leaf nodes, preferring those whose block
         would actually free (refcount 1 = trie-only). Returns the number
-        of pool blocks freed."""
+        of pool blocks freed.
+
+        One walk of the trie a call: its leaves go on a heap, and a node
+        whose last child is evicted joins the heap then, so each eviction
+        takes the leaf a fresh walk of the whole trie would pick."""
+        if n <= 0:
+            return 0
+        up: Dict[int, Tuple[_Node, Tuple[int, ...]]] = {}
+        heap: list = []
+        seq = itertools.count()
+
+        def push(node: _Node):
+            # Trie-only leaves first (they free real memory), then LRU;
+            # the sequence number keeps the heap off the nodes themselves.
+            heapq.heappush(heap, (self.pool.refcount(node.block) > 1,
+                                  node.stamp, next(seq), node))
+
+        stack = [self._root]
+        while stack:
+            node = stack.pop()
+            for key, child in node.children.items():
+                up[id(child)] = (node, key)
+                if child.children:
+                    stack.append(child)
+                else:
+                    push(child)
         freed = 0
         evicted = 0
-        while evicted < n:
-            leaves = self._leaves()
-            if not leaves:
-                break
-            # Trie-only leaves first (they free real memory), then LRU.
-            leaves.sort(key=lambda pcn: (
-                self.pool.refcount(pcn[1].block) > 1, pcn[1].stamp))
-            parent, child, key = leaves[0]
+        while evicted < n and heap:
+            child = heapq.heappop(heap)[-1]
+            parent, key = up[id(child)]
             del parent.children[key]
             freed += self.pool.decref([child.block])
             self._count -= 1
             evicted += 1
+            if parent is not self._root and not parent.children:
+                push(parent)
         return freed
 
     def clear(self) -> int:

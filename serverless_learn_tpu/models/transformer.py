@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from serverless_learn_tpu.ops.attention import dot_product_attention
 from serverless_learn_tpu.ops.moe import MoELayer
 
+LAYER_KINDS = ("attention", "mamba", "window_rope", "full_nope")
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -42,11 +44,23 @@ class TransformerConfig:
     rope_theta: float = 10000.0
     norm: str = "rms"  # "rms" | "layer"
     rms_norm_eps: float = 1e-6
-    # The kind of each layer's mixer: a tuple of "attention" / "mamba",
-    # one entry a layer; () => every layer attends. A "mamba" layer is a
-    # Mamba-2 mixer (``MambaMixer``) of ``ssm_heads`` heads of
-    # ``ssm_head_dim`` channels over a state of ``ssm_state`` per channel.
+    # The kind of each layer's mixer, one entry a layer; () => every layer
+    # attends. "attention" sees every earlier key, its positions as
+    # ``position`` says; the two other attention kinds carry their own:
+    # "window_rope" sees the last ``sliding_window`` keys and rotates,
+    # "full_nope" sees every earlier key with no positional term. A
+    # "mamba" layer is a Mamba-2 mixer (``MambaMixer``) of ``ssm_heads``
+    # heads of ``ssm_head_dim`` channels over a state of ``ssm_state``
+    # per channel.
     layer_types: tuple = ()
+    sliding_window: int = 0
+    # Attention terms some families publish (all off = absent): an
+    # RMSNorm of q and of k over head_dim before rotation; a gate on the
+    # attention's output, o_proj(attn * sigmoid(gate_proj(x))); and an
+    # RMSNorm on each sublayer's output as well as on its input.
+    qk_norm: bool = False
+    attn_output_gate: bool = False
+    post_norms: bool = False
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
@@ -68,11 +82,27 @@ class TransformerConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # Routing-subgroup token count (0 => full row). Bounds slot competition
-    # and dispatch memory; ALSO sets the granularity of the load-balance aux
-    # loss (a mean of per-group Switch terms, ops/moe.py), so changing it
-    # perturbs the aux value/gradient, not just memory.
+    # Routing-subgroup token count (0 => full row) of the TRAINING path's
+    # capacity dispatch. Bounds slot competition and dispatch memory; ALSO
+    # sets the granularity of the load-balance aux loss (a mean of
+    # per-group Switch terms, ops/moe.py), so changing it perturbs the aux
+    # value/gradient, not just memory. Serving routes dropless.
     moe_group_size: int = 1024
+    # An expert's width (0 => d_ff) and a shared expert's, which every
+    # token passes through beside its routed ones (0 => none); the first
+    # ``moe_dense_layers`` layers keep the dense MLP of width d_ff.
+    moe_d_ff: int = 0
+    moe_shared_d_ff: int = 0
+    moe_dense_layers: int = 0
+    # Routing (``ops/moe.route``): scores "softmax" or "sigmoid" of the
+    # router's float32 logits; experts chosen by the top-k of the scores
+    # plus a learned ``expert_bias`` where ``moe_select_bias``; gate
+    # weights the chosen scores, renormalised over the k where
+    # ``moe_route_norm``, times ``moe_route_scale``.
+    moe_score: str = "softmax"
+    moe_route_norm: bool = True
+    moe_route_scale: float = 1.0
+    moe_select_bias: bool = False
     tie_embeddings: bool = False
     dtype: jnp.dtype = jnp.bfloat16
     param_dtype: jnp.dtype = jnp.float32
@@ -150,16 +180,29 @@ class TransformerConfig:
         if self.position not in ("rope", "learned", "none"):
             raise ValueError(f"unknown position kind {self.position!r} "
                              "(rope | learned | none)")
-        bad = set(self.layer_types) - {"attention", "mamba"}
+        bad = set(self.layer_types) - set(LAYER_KINDS)
         if bad or (self.layer_types
                    and len(self.layer_types) != self.n_layers):
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers "
                 f"(unknown kinds: {sorted(bad)}) for n_layers="
-                f"{self.n_layers}; kinds are attention | mamba")
+                f"{self.n_layers}; kinds are {' | '.join(LAYER_KINDS)}")
+        if "window_rope" in self.layer_types and self.sliding_window <= 0:
+            raise ValueError("a window_rope layer needs sliding_window > 0")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown moe_score {self.moe_score!r} "
+                             "(softmax | sigmoid)")
 
     def layer_kind(self, i: int) -> str:
         return self.layer_types[i] if self.layer_types else "attention"
+
+    def window(self, kind: str) -> int:
+        """Keys a layer of ``kind`` sees behind a query (0: all)."""
+        return self.sliding_window if kind == "window_rope" else 0
+
+    def rotates(self, kind: str) -> bool:
+        return kind == "window_rope" or (kind == "attention"
+                                         and self.position == "rope")
 
     @property
     def kv_heads(self) -> int:
@@ -331,14 +374,26 @@ def _norm(cfg: TransformerConfig, name: str):
                         name=name)
 
 
+def _band(q_pos, k_pos, window: int):
+    """Key j is visible to query i: j <= i, and j > i - ``window`` where
+    a window is set. ``q_pos`` [..., T, 1] and ``k_pos`` [..., 1, S]
+    broadcast to the mask."""
+    seen = k_pos <= q_pos
+    return seen & (k_pos > q_pos - window) if window else seen
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
+    # The layer's kind (``TransformerConfig.layer_types``): whether it has
+    # a window, and whether it rotates.
+    kind: str = "attention"
 
     @nn.compact
     def __call__(self, x, *, mask=None, positions=None, decode=False,
                  prefill=False, extend=False, seq_lengths=None):
         cfg = self.cfg
         H, K, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        window, rotates = cfg.window(self.kind), cfg.rotates(self.kind)
         dense = lambda feats, name: _proj(cfg, feats, name)
         q = dense((H, D), "q_proj")(x)
         k = dense((K, D), "k_proj")(x)
@@ -348,6 +403,9 @@ class Attention(nn.Module):
                                 cfg.dtype, cfg.param_dtype, name="q_lora")(x)
             v = v + LoRAAdapter(cfg.lora_rank, cfg.lora_alpha, (K, D),
                                 cfg.dtype, cfg.param_dtype, name="v_lora")(x)
+        if cfg.qk_norm:
+            q = _norm(cfg, "q_norm")(q)
+            k = _norm(cfg, "k_norm")(k)
         causal = cfg.causal
         if decode or prefill or extend:
             # Autoregressive KV cache. decode: x is the single newest token
@@ -399,7 +457,7 @@ class Attention(nn.Module):
                     pos0 = ci.value  # [B]
                     positions_bt = (pos0[:, None]
                                     + jnp.arange(T, dtype=jnp.int32))
-                    if cfg.position == "rope":
+                    if rotates:
                         sin, cos = rope_angles(positions_bt, D,
                                                cfg.rope_theta)
                         q = apply_rope(q, sin, cos)
@@ -429,13 +487,34 @@ class Attention(nn.Module):
                     # Attention reads the gathered window; sentinel table
                     # entries clip to a real page whose garbage the
                     # per-position mask below never admits.
-                    safe_tbl = jnp.clip(tbl.value, 0, P - 1)
+                    rows_tbl, first_key = tbl.value, None
+                    span = -(-(window + T - 1) // ps) + 1 if window else W
+                    if span < W:
+                        # A window layer gathers only the pages its
+                        # window can touch, from a per-row start page;
+                        # the pages behind it stay in the table.
+                        with jax.named_scope("attn.window"):
+                            lo = jnp.maximum(pos0 - window + 1, 0) // ps
+                            start = jnp.minimum(lo, W - span)
+                            rows_tbl = jnp.take_along_axis(
+                                tbl.value,
+                                start[:, None] + jnp.arange(span), axis=1)
+                            first_key = start * ps
+                            S = span * ps
+                    safe_tbl = jnp.clip(rows_tbl, 0, P - 1)
                     k = jnp.take(pk.value, safe_tbl, axis=0).reshape(
-                        B, S, K, D)
+                        B, -1, K, D)
                     v = jnp.take(pv.value, safe_tbl, axis=0).reshape(
-                        B, S, K, D)
-                    mask = (jnp.arange(S)[None, None, :]
-                            <= positions_bt[:, :, None])[:, None]
+                        B, -1, K, D)
+                    if window:
+                        k_pos = jnp.arange(S)[None, None, :]
+                        if first_key is not None:
+                            k_pos = k_pos + first_key[:, None, None]
+                        mask = _band(positions_bt[:, :, None], k_pos,
+                                     window)[:, None]
+                    else:
+                        mask = (jnp.arange(S)[None, None, :]
+                                <= positions_bt[:, :, None])[:, None]
                     causal = False
             else:
                 is_init = not self.has_variable("cache", "cached_k")
@@ -449,7 +528,7 @@ class Attention(nn.Module):
                 pass  # the paged branch above handled everything
             elif not is_init and prefill:
                 T = x.shape[1]
-                if cfg.position == "rope":
+                if rotates:
                     p = jnp.broadcast_to(
                         jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
                     sin, cos = rope_angles(p, D, cfg.rope_theta)
@@ -466,6 +545,10 @@ class Attention(nn.Module):
                 # Attention runs causally over the padded prompt: real
                 # token i attends only [0, i] — all real under right-
                 # padding; pad rows produce garbage nobody reads.
+                if window:
+                    at = jnp.arange(T)
+                    mask = _band(at[:, None], at[None, :], window)[None, None]
+                    causal = False
             elif not is_init and extend:
                 # Append T tokens at each row's current index (the
                 # speculative-verify primitive): RoPE at absolute
@@ -477,7 +560,7 @@ class Attention(nn.Module):
                 pos0 = ci.value  # [B]
                 positions_bt = pos0[:, None] + jnp.arange(T,
                                                           dtype=jnp.int32)
-                if cfg.position == "rope":
+                if rotates:
                     sin, cos = rope_angles(positions_bt, D, cfg.rope_theta)
                     q = apply_rope(q, sin, cos)
                     k = apply_rope(k, sin, cos)
@@ -490,15 +573,16 @@ class Attention(nn.Module):
                 cv.value = jax.vmap(write_span)(cv.value, v, pos0)
                 ci.value = pos0 + T
                 k, v = ck.value, cv.value
-                mask = (jnp.arange(cfg.max_seq_len)[None, None, :]
-                        <= positions_bt[:, :, None])[:, None]  # [B,1,T,S]
+                mask = _band(positions_bt[:, :, None],
+                             jnp.arange(cfg.max_seq_len)[None, None, :],
+                             window)[:, None]  # [B,1,T,S]
                 causal = False
             elif not is_init:
                 if x.shape[1] != 1:
                     raise ValueError(
                         f"decode feeds one token at a time, got T={x.shape[1]}")
                 pos = ci.value  # [B]
-                if cfg.position == "rope":
+                if rotates:
                     sin, cos = rope_angles(pos[:, None], D, cfg.rope_theta)
                     q = apply_rope(q, sin, cos)
                     k = apply_rope(k, sin, cos)
@@ -511,15 +595,23 @@ class Attention(nn.Module):
                 cv.value = jax.vmap(write_at)(cv.value, v, pos)
                 ci.value = pos + 1
                 k, v = ck.value, cv.value
-                mask = (jnp.arange(cfg.max_seq_len)[None, :]
-                        <= pos[:, None])[:, None, None, :]
+                mask = _band(pos[:, None], jnp.arange(cfg.max_seq_len)[None, :],
+                             window)[:, None, None, :]
                 causal = False  # the index mask already encodes causality
-        elif cfg.position == "rope":
-            if positions is None:
-                positions = jnp.arange(x.shape[1])[None, :]
-            sin, cos = rope_angles(positions, D, cfg.rope_theta)
-            q = apply_rope(q, sin, cos)
-            k = apply_rope(k, sin, cos)
+        else:
+            if rotates:
+                if positions is None:
+                    positions = jnp.arange(x.shape[1])[None, :]
+                sin, cos = rope_angles(positions, D, cfg.rope_theta)
+                q = apply_rope(q, sin, cos)
+                k = apply_rope(k, sin, cos)
+            if window:
+                # A band inside the causal triangle, by index (packed rows'
+                # ``positions`` restart; a window does not reach across).
+                at = jnp.arange(x.shape[1])
+                band = _band(at[:, None], at[None, :], window)[None, None]
+                mask = band if mask is None else mask & band
+                causal = False
         kv_lengths = None
         if (cfg.suffix_padding_mask and mask is not None
                 and not (decode or prefill or extend) and mask.ndim == 4
@@ -561,6 +653,8 @@ class Attention(nn.Module):
                 else cfg.attention_impl,
                 axis_name=cfg.sp_axis or "sp",
                 softmax_scale=cfg.attention_multiplier)
+        if cfg.attn_output_gate:
+            out = out * nn.sigmoid(dense((H, D), "gate_proj")(x))
         y = _proj(cfg, cfg.d_model, "o_proj", n_contract=2)(out)
         if cfg.manual_tp_axis:
             # Row-parallel output projection: each tp member contracted its
@@ -694,6 +788,11 @@ class MambaMixer(nn.Module):
         return _proj(cfg, cfg.d_model, "out_proj")(g)
 
 
+def moe_layers(cfg: TransformerConfig) -> int:
+    """How many of the model's layers route to experts."""
+    return max(cfg.n_layers - cfg.moe_dense_layers, 0) if cfg.n_experts else 0
+
+
 def slot_leaves(cfg: TransformerConfig) -> tuple:
     """Names of the ``cache`` leaves this model keeps per SLOT of the
     serving engine (``[max_slots, ...]``, gathered and scattered by slot
@@ -703,7 +802,8 @@ def slot_leaves(cfg: TransformerConfig) -> tuple:
 
 class Block(nn.Module):
     cfg: TransformerConfig
-    kind: str = "attention"   # the mixer: "attention" | "mamba"
+    kind: str = "attention"   # the mixer: an entry of LAYER_KINDS
+    dense_mlp: bool = False   # the dense MLP even where the model has experts
 
     @nn.compact
     def __call__(self, x, *, mask=None, positions=None, decode=False,
@@ -716,25 +816,25 @@ class Block(nn.Module):
                 h, decode=decode, prefill=prefill, extend=extend,
                 seq_lengths=seq_lengths, link=link)
         else:
-            mixed = Attention(cfg, name="attn")(
+            mixed = Attention(cfg, self.kind, name="attn")(
                 h, mask=mask, positions=positions, decode=decode,
                 prefill=prefill, extend=extend, seq_lengths=seq_lengths)
+        if cfg.post_norms:
+            mixed = _norm(cfg, "norm_attn_out")(mixed)
         x = x + _times(mixed, res)
-        if cfg.n_experts > 0:
-            moe_cfg = cfg
-            if decode or prefill or extend:
-                # Inference routes PER TOKEN (group size 1): capacity is
-                # a training-efficiency construct, and grouped drops make
-                # routing depend on the other tokens in the group — under
-                # prefill that includes FUTURE positions, which would
-                # break the cached-decode == full-forward equivalence
-                # (tests/test_moe_generate.py pins it). Per-token groups
-                # give every token its full top-k experts, no drops, and
-                # identical routing between prefill and decode.
-                moe_cfg = dataclasses.replace(cfg, moe_group_size=1)
-            y = MoELayer(moe_cfg, name="moe")(_norm(cfg, "norm_mlp")(x))
+        h = _norm(cfg, "norm_mlp")(x)
+        if cfg.n_experts > 0 and not self.dense_mlp:
+            # Serving routes dropless: capacity is a training-efficiency
+            # construct, and grouped drops would make a token's routing
+            # depend on the other tokens of its group, FUTURE positions
+            # among them under prefill (the cached-decode == full-forward
+            # equivalence, tests/test_moe_generate.py).
+            y = MoELayer(cfg, name="moe")(
+                h, dropless=decode or prefill or extend)
         else:
-            y = MlpBlock(cfg, name="mlp")(_norm(cfg, "norm_mlp")(x))
+            y = MlpBlock(cfg, name="mlp")(h)
+        if cfg.post_norms:
+            y = _norm(cfg, "norm_mlp_out")(y)
         return x + _times(y, res)
 
 
@@ -1005,10 +1105,11 @@ class Transformer(nn.Module):
             # Learned positions would need the cache index at this level.
             raise NotImplementedError(
                 "decode requires position 'rope' or 'none', not 'learned'")
-        if cfg.pipeline and "mamba" in cfg.layer_types:
+        if cfg.pipeline and (set(cfg.layer_types) - {"attention"}
+                             or cfg.moe_dense_layers):
             raise NotImplementedError(
-                "pipeline=True stacks identical blocks; layer_types mixes "
-                "two kinds")
+                "pipeline=True stacks identical blocks; layer_types or "
+                "moe_dense_layers mixes two kinds")
         embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="embedder",
                          dtype=cfg.dtype, param_dtype=cfg.param_dtype)
         x = constrain_residual(_times(embed(tokens),
@@ -1031,7 +1132,9 @@ class Transformer(nn.Module):
             use_remat = cfg.remat and not infer
             block = nn.remat(Block, static_argnums=()) if use_remat else Block
             for i in range(cfg.n_layers):
-                blk = block(cfg, cfg.layer_kind(i), name=f"layer_{i}")
+                blk = block(cfg, cfg.layer_kind(i),
+                            dense_mlp=i < cfg.moe_dense_layers,
+                            name=f"layer_{i}")
                 if use_remat:
                     # remat traces every kwarg; the decode/prefill bools
                     # must stay Python-static, and here they are both False.

@@ -20,24 +20,86 @@ dispatch intermediates is therefore ``O(tokens × group_size)`` — independent
 of sequence length and of the global token count — and the routing cumsum
 never crosses the dp-sharded batch axis (each dp shard routes its own rows:
 no cross-replica slot competition, no all-reduce on dispatch), so per-device
-expert compute scales down with dp.
+expert compute scales down with dp. That is the TRAINING path.
+
+Serving (``dropless``: decode, prefill, extend) drops no token and computes
+no expert for a token that was not routed to it: the (token, expert) pairs
+are sorted by expert, the held experts run as one grouped product over
+their groups (``jax.lax.ragged_dot``), and the results go back to token
+order, weighted (``dropless_experts``). Both paths route alike
+(``route``), and a shared expert, where the model has one, adds its
+output once.
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 
+def route(logits: jax.Array, top_k: int, score: str = "softmax",
+          bias=None, norm: bool = True, scale: float = 1.0):
+    """Router logits [..., E] (float32) -> (scores [..., E], gate weights
+    [..., K], expert ids [..., K]).
+
+    Scores are ``softmax`` or ``sigmoid`` of the logits. The experts are
+    the top ``top_k`` of the scores plus ``bias`` [E] where one is given:
+    the bias steers the choice only. The gate weights are the chosen
+    experts' scores, renormalised over the k where ``norm`` holds, times
+    ``scale``."""
+    logits = logits.astype(jnp.float32)
+    scores = (jax.nn.softmax(logits, -1) if score == "softmax"
+              else jax.nn.sigmoid(logits))
+    picked = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(picked, top_k)
+    gate_w = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm:
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gate_w = gate_w * scale
+    return scores, gate_w, idx
+
+
+def dropless_experts(x: jax.Array, gate_w: jax.Array, idx: jax.Array,
+                     w_gate: jax.Array, w_up: jax.Array,
+                     w_down: jax.Array) -> jax.Array:
+    """Every token through exactly its routed SwiGLU experts, none
+    dropped. x [N, D], gate_w / idx [N, K], expert weights [E, D, F] and
+    [E, F, D] -> [N, D] float32.
+
+    The N·K (token, expert) pairs are sorted by expert, each expert's
+    rows are one group of a grouped product over the E held experts
+    (``jax.lax.ragged_dot``, group sizes from the sort), and the rows go
+    back to token order and are summed under their gate weights."""
+    N, D = x.shape
+    K, E = idx.shape[1], w_gate.shape[0]
+    with jax.named_scope("moe.dispatch"):
+        flat = idx.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        rows = jnp.take(x, order // K, axis=0)               # [N*K, D]
+        sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    with jax.named_scope("moe.experts"):
+        h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
+             * jax.lax.ragged_dot(rows, w_up, sizes))
+        out = jax.lax.ragged_dot(h, w_down, sizes)            # [N*K, D]
+    with jax.named_scope("moe.combine"):
+        back = jnp.take(out, jnp.argsort(order), axis=0).reshape(N, K, D)
+        return jnp.einsum("nk,nkd->nd", gate_w,
+                          back.astype(jnp.float32))
+
+
 def top_k_routing(router_logits: jax.Array, n_experts: int, top_k: int,
-                  capacity: int):
+                  capacity: int, **routing):
     """Static-shape grouped top-k routing with per-group expert capacity.
 
     Args:
       router_logits: [G, S, E] float32 — G independent routing groups of S
         tokens each (callers use one group per batch row).
       capacity: slots per expert per group (C).
+      routing: ``route``'s keywords (score, bias, norm, scale).
 
     Returns:
       dispatch: [G, S, E, C] {0,1} — token (g, s) occupies slot c of expert e.
@@ -53,9 +115,7 @@ def top_k_routing(router_logits: jax.Array, n_experts: int, top_k: int,
         semantics bit-for-bit instead of approximately.
     """
     G, S, E = router_logits.shape
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), -1)  # [G, S, E]
-    gate_w, gate_idx = jax.lax.top_k(probs, top_k)  # [G, S, K]
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    probs, gate_w, gate_idx = route(router_logits, top_k, **routing)
     onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # [G, S, K, E]
 
     # Slot assignment within each group: all 1st choices take priority over
@@ -100,21 +160,29 @@ def apply_with_losses(module, params, *args, **kwargs):
 
 
 class MoELayer(nn.Module):
-    """Drop-in MLP replacement: top-k routed SwiGLU experts.
+    """Drop-in MLP replacement: top-k routed SwiGLU experts of width
+    ``moe_d_ff`` (or ``d_ff``), and a shared expert of width
+    ``moe_shared_d_ff`` where the model has one.
 
     Expert weights are stacked on a leading ``[n_experts, ...]`` dim that the
     sharding rule table maps to ``ep`` (``parallel/sharding.py``); the two
     dispatch/combine einsums then induce ICI all-to-alls under GSPMD. The aux
     load-balance loss is sown into the ``"losses"`` collection — model
     bundles apply through ``apply_with_losses`` to add it to the task loss.
+    ``dropless`` (serving) takes ``dropless_experts`` instead of the
+    capacity dispatch and sows each token's expert ids into the
+    ``"moe_route"`` collection, which a caller that makes it mutable
+    reads (the serving engine counts the experts a decode step read).
     """
 
     cfg: "TransformerConfig"  # noqa: F821 — transformer.py's config
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:  # [B, T, D] -> [B, T, D]
+    def __call__(self, x: jax.Array, dropless: bool = False
+                 ) -> jax.Array:  # [B, T, D] -> [B, T, D]
         cfg = self.cfg
-        E, K, F = cfg.n_experts, cfg.moe_top_k, cfg.d_ff
+        E, K = cfg.n_experts, cfg.moe_top_k
+        F = cfg.moe_d_ff or cfg.d_ff
         # Manual expert parallelism (inside a pipeline's shard_map, where
         # GSPMD can't partition for us — round-4 pp x ep): cfg.n_experts is
         # this member's LOCAL expert count, routing runs over the GLOBAL
@@ -127,21 +195,15 @@ class MoELayer(nn.Module):
         ep = cfg.manual_ep_axis
         E_route = cfg.moe_global_experts if ep else E
         B, T, D = x.shape
-        # Split each row into routing subgroups of <= moe_group_size tokens
-        # (largest divisor of T that fits) so the one-hot dispatch
-        # intermediates stay bounded at long sequence length.
-        limit = min(cfg.moe_group_size or T, T)
-        gs = max(d for d in range(1, limit + 1) if T % d == 0)
-        x = x.reshape(B * (T // gs), gs, D)  # [G, S, D]
-        capacity = max(1, int(cfg.moe_capacity_factor * K * gs / E_route))
 
         router = self.param(
             "router", nn.initializers.normal(0.02), (D, E_route),
             cfg.param_dtype)
-        logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
-                            router.astype(jnp.float32))
-        dispatch, combine, aux = top_k_routing(logits, E_route, K, capacity)
-        self.sow("losses", "moe_aux", cfg.moe_aux_weight * aux)
+        bias = (self.param("expert_bias", nn.initializers.zeros,
+                           (E_route,), cfg.param_dtype)
+                if cfg.moe_select_bias else None)
+        routing = dict(score=cfg.moe_score, bias=bias,
+                       norm=cfg.moe_route_norm, scale=cfg.moe_route_scale)
 
         if cfg.quant == "int8":
             # Weight-only int8 experts (round 5): expert tensors are the
@@ -169,6 +231,55 @@ class MoELayer(nn.Module):
             w_up = self.param("expert_up", init, (E, D, F), cfg.param_dtype)
             w_down = self.param("expert_down", init, (E, F, D),
                                 cfg.param_dtype)
+        shared = None
+        if cfg.moe_shared_d_ff:
+            from serverless_learn_tpu.models.transformer import MlpBlock
+
+            shared = MlpBlock(dataclasses.replace(
+                cfg, d_ff=cfg.moe_shared_d_ff), name="shared")
+
+        if dropless:
+            if ep:
+                raise NotImplementedError(
+                    "dropless routing holds every expert: serving runs no "
+                    "expert-parallel pipeline")
+            x2 = x.reshape(B * T, D)
+            with jax.named_scope("moe.route"):
+                logits = jnp.einsum("nd,de->ne", x2.astype(jnp.float32),
+                                    router.astype(jnp.float32))
+                _, gate_w, idx = route(logits, K, **routing)
+            self.sow("moe_route", "experts", idx.reshape(B, T, K))
+            y = dropless_experts(
+                x2.astype(cfg.dtype), gate_w, idx, w_gate.astype(cfg.dtype),
+                w_up.astype(cfg.dtype), w_down.astype(cfg.dtype))
+            y = y.reshape(B, T, D)
+        else:
+            y = self._capacity_dispatch(x, router, routing, w_gate, w_up,
+                                        w_down)
+        if shared is not None:
+            y = y + shared(x).astype(jnp.float32)
+        return y.astype(cfg.dtype)
+
+    def _capacity_dispatch(self, x, router, routing, w_gate, w_up, w_down):
+        """The training path: static-shape grouped top-k dispatch with a
+        capacity per expert and group; over-capacity tokens pass through on
+        the residual. [B, T, D] -> [B, T, D] float32."""
+        cfg = self.cfg
+        K, ep = cfg.moe_top_k, cfg.manual_ep_axis
+        E_route = cfg.moe_global_experts if ep else cfg.n_experts
+        B, T, D = x.shape
+        # Split each row into routing subgroups of <= moe_group_size tokens
+        # (largest divisor of T that fits) so the one-hot dispatch
+        # intermediates stay bounded at long sequence length.
+        limit = min(cfg.moe_group_size or T, T)
+        gs = max(d for d in range(1, limit + 1) if T % d == 0)
+        x = x.reshape(B * (T // gs), gs, D)  # [G, S, D]
+        capacity = max(1, int(cfg.moe_capacity_factor * K * gs / E_route))
+        logits = jnp.einsum("btd,de->bte", x.astype(jnp.float32),
+                            router.astype(jnp.float32))
+        dispatch, combine, aux = top_k_routing(logits, E_route, K, capacity,
+                                               **routing)
+        self.sow("losses", "moe_aux", cfg.moe_aux_weight * aux)
 
         # Dispatch tokens to expert slots; with batch over dp and experts
         # over ep, GSPMD lowers the e-contraction to an ICI all-to-all (or
@@ -193,4 +304,4 @@ class MoELayer(nn.Module):
             # Row-parallel expert down-projection: each tp member holds
             # d_ff/tp of every (local) expert; partial sums combine here.
             y = jax.lax.psum(y, cfg.manual_tp_axis)
-        return y.reshape(B, T, D).astype(cfg.dtype)
+        return y.reshape(B, T, D)

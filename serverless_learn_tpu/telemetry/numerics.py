@@ -146,24 +146,48 @@ def fingerprint(tree, depth: int = DEFAULT_DEPTH,
     to one weight block moves one chunk sum, so two digests disagreeing
     localizes *where* in the subtree, not just *that*. Cheap enough
     (a handful of f32 reductions) to compute inside the jitted step
-    every step and record every cadence."""
+    every step and record every cadence.
+
+    Reduced leaf by leaf (``_leaf_digest``): no float32 copy of a whole
+    subtree is made, which for one expert layer of a served MoE model
+    would be gigabytes beside its weights."""
+    k = max(chunks, 1)
     out: Dict[str, Dict[str, jnp.ndarray]] = {}
     for name, leaves in subtrees(tree, depth).items():
-        flatv = jnp.concatenate(
-            [jnp.ravel(jnp.asarray(l, jnp.float32)) for l in leaves])
-        flatv = jnp.where(jnp.isfinite(flatv), flatv, 0.0)
-        n = flatv.shape[0]
-        digest = {"l2": jnp.sqrt(jnp.sum(jnp.square(flatv))),
-                  "sum": jnp.sum(flatv),
-                  "absmax": jnp.max(jnp.abs(flatv)) if n else jnp.float32(0)}
-        pad = (-n) % max(chunks, 1)
-        if pad:
-            flatv = jnp.concatenate([flatv, jnp.zeros((pad,), jnp.float32)])
-        parts = flatv.reshape(max(chunks, 1), -1).sum(axis=1)
-        for i in range(max(chunks, 1)):
+        sizes = [int(np.prod(np.shape(l))) for l in leaves]
+        n = sum(sizes)
+        width = (n + (-n) % k) // k     # elements a chunk covers
+        sq, total, amax = jnp.float32(0), jnp.float32(0), jnp.float32(0)
+        parts = jnp.zeros((k,), jnp.float32)
+        start = 0
+        for leaf, m in zip(leaves, sizes):
+            # The chunks' ranges in this leaf's own element indices.
+            bounds = np.clip(np.arange(k + 1) * width - start, 0, m)
+            s2, s1, mx, p = _leaf_digest(leaf, bounds.astype(np.int32))
+            sq, total, amax = sq + s2, total + s1, jnp.maximum(amax, mx)
+            parts = parts + p
+            start += m
+        digest = {"l2": jnp.sqrt(sq), "sum": total, "absmax": amax}
+        for i in range(k):
             digest[f"c{i}"] = parts[i]
         out[name] = digest
     return out
+
+
+@jax.jit
+def _leaf_digest(leaf, bounds):
+    """One leaf's share of a subtree's digest: its sum of squares, sum,
+    absmax and, per chunk, the sum over its elements ``bounds[c]`` to
+    ``bounds[c + 1]``; non-finite elements count as 0. One fused pass,
+    compiled once a leaf shape."""
+    v = jnp.ravel(leaf).astype(jnp.float32)
+    v = jnp.where(jnp.isfinite(v), v, 0.0)
+    i = jnp.arange(v.shape[0])
+    parts = jnp.stack([
+        jnp.sum(jnp.where((i >= bounds[c]) & (i < bounds[c + 1]), v, 0.0))
+        for c in range(bounds.shape[0] - 1)])
+    return (jnp.sum(jnp.square(v)), jnp.sum(v),
+            jnp.max(jnp.abs(v), initial=0.0), parts)
 
 
 def weight_version(tree, depth: int = DEFAULT_DEPTH,

@@ -9,6 +9,7 @@ The exactness bar: the engine must be byte-identical to solo ``generate``
 WHERE K/V live, never what attention reads.
 """
 
+import copy
 import json
 import threading
 import time
@@ -96,6 +97,61 @@ def test_trie_eviction_respects_live_refs():
     pool.decref(b1)
     pool.decref(b2)
     assert pool.refcount(b2[0]) == 1  # trie still holds it
+
+
+def _evict_by_fresh_walks(trie, n):
+    """Eviction as a fresh walk of the whole trie picks each leaf: the
+    order ``PrefixTrie.release`` has to keep."""
+    order = []
+    for _ in range(n):
+        leaves = []
+        stack = [trie._root]
+        while stack:
+            node = stack.pop()
+            for key, child in node.children.items():
+                if child.children:
+                    stack.append(child)
+                else:
+                    leaves.append((node, child, key))
+        if not leaves:
+            break
+        parent, child, key = min(leaves, key=lambda pck: (
+            trie.pool.refcount(pck[1].block) > 1, pck[1].stamp))
+        del parent.children[key]
+        trie.pool.decref([child.block])
+        trie._count -= 1
+        order.append(child.block)
+    return order
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_trie_release_evicts_in_the_order_of_fresh_walks(seed):
+    rng = np.random.default_rng(seed)
+    pool = BlockPool(512, block_size=2)
+    trie = PrefixTrie(pool)
+    for _ in range(40):
+        # Few token values: prompts share prefixes, so evicting a leaf
+        # turns its parent into one.
+        prompt = rng.integers(0, 3, size=int(rng.integers(2, 13))).tolist()
+        blocks = pool.alloc(len(prompt) // 2)
+        trie.register(prompt, blocks)
+        if rng.random() < 0.5:
+            pool.decref(blocks)  # owner retired: some leaves trie-only
+        if rng.random() < 0.3:
+            trie.lookup(rng.integers(0, 3, size=8).tolist())
+    held = trie.blocks_held
+    want_trie = copy.deepcopy(trie)
+    for n in (3, held // 2, held):
+        got = []
+        decref = trie.pool.decref
+        trie.pool.decref = lambda bs: (got.extend(bs), decref(bs))[1]
+        freed = trie.release(n)
+        del trie.pool.decref
+        assert got == _evict_by_fresh_walks(want_trie, n)
+        assert freed == sum(1 for b in got if pool.refcount(b) == 0)
+        assert trie.blocks_held == want_trie.blocks_held
+    assert trie.blocks_held == 0
+    assert pool.free_blocks == want_trie.pool.free_blocks
 
 
 def test_kv_config_roundtrip():

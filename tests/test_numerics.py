@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from serverless_learn_tpu.config import (
     DataConfig, ExperimentConfig, HealthConfig, MeshConfig, NumericsConfig,
@@ -103,6 +104,36 @@ def test_fingerprint_chunks_localize_perturbation():
         {k: {f: float(x) for f, x in d.items()} for k, d in fa.items()},
         {k: {f: float(x) for f, x in d.items()} for k, d in fb.items()})
     assert worst is not None and worst["subtree"] == "dense_0"
+
+
+def _pinned_tree():
+    k = jax.random.split(jax.random.PRNGKey(7), 6)
+    return {"a": {"w": jax.random.normal(k[0], (37, 11), jnp.float32),
+                  "b": jax.random.normal(k[1], (5,), jnp.bfloat16)},
+            "b": {"x": {"y": jax.random.normal(k[2], (3, 4, 5)) * 3,
+                        "z": jnp.array([1.0, jnp.inf, -2.0], jnp.float32)}},
+            "c": jax.random.normal(k[3], (2,), jnp.float32)}
+
+
+def test_fingerprint_reduced_leaf_by_leaf_keeps_the_digest():
+    """The digest is reduced leaf by leaf, with no float32 copy of a whole
+    subtree; what it renders is the digest the concatenating version gave
+    (these strings are that version's, over mixed dtypes, a non-finite
+    element, chunk ranges that cross leaves and a subtree shorter than
+    its chunks)."""
+    tree = _pinned_tree()
+    assert numerics.weight_version(tree) == "263e4513bf0b"
+    assert numerics.weight_version(tree, depth=2, chunks=3) == "6a73a63a48e1"
+    fp = jax.device_get(numerics.fingerprint(tree))
+    assert float(fp["c"]["c1"]) == pytest.approx(-0.4773978590965271)
+    assert float(fp["c"]["c2"]) == float(fp["c"]["c3"]) == 0.0
+    assert float(fp["b"]["absmax"]) == pytest.approx(7.170856475830078)
+    # Inside a jitted step it reads the same.
+    jitted = jax.device_get(jax.jit(numerics.fingerprint)(tree))
+    for name, digest in fp.items():
+        for key, value in digest.items():
+            assert float(jitted[name][key]) == pytest.approx(float(value),
+                                                             rel=1e-6)
 
 
 # -- NaN/Inf provenance -------------------------------------------------------
