@@ -25,10 +25,10 @@ expert compute scales down with dp. That is the TRAINING path.
 Serving (``dropless``: decode, prefill, extend) drops no token and computes
 no expert for a token that was not routed to it: the (token, expert) pairs
 are sorted by expert, the held experts run as one grouped product over
-their groups (``jax.lax.ragged_dot``), and the results go back to token
-order, weighted (``dropless_experts``). Both paths route alike
-(``route``), and a shared expert, where the model has one, adds its
-output once.
+their groups (the ``moe_gmm`` Pallas kernel where the shapes tile, else
+``jax.lax.ragged_dot``), and the results go back to token order, weighted
+(``dropless_experts``). Both paths route alike (``route``), and a shared
+expert, where the model has one, adds its output once.
 """
 
 from __future__ import annotations
@@ -71,9 +71,9 @@ def dropless_experts(x: jax.Array, gate_w: jax.Array, idx: jax.Array,
     [E, F, D] -> [N, D] float32.
 
     The N·K (token, expert) pairs are sorted by expert, each expert's
-    rows are one group of a grouped product over the E held experts
-    (``jax.lax.ragged_dot``, group sizes from the sort), and the rows go
-    back to token order and are summed under their gate weights."""
+    rows are one group of a grouped product over the E held experts (the
+    ``moe_gmm`` kernel where it tiles, else ``jax.lax.ragged_dot``), and
+    the rows go back to token order, summed under their gate weights."""
     N, D = x.shape
     K, E = idx.shape[1], w_gate.shape[0]
     with jax.named_scope("moe.dispatch"):
@@ -82,9 +82,9 @@ def dropless_experts(x: jax.Array, gate_w: jax.Array, idx: jax.Array,
         rows = jnp.take(x, order // K, axis=0)               # [N*K, D]
         sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     with jax.named_scope("moe.experts"):
-        h = (jax.nn.silu(jax.lax.ragged_dot(rows, w_gate, sizes))
-             * jax.lax.ragged_dot(rows, w_up, sizes))
-        out = jax.lax.ragged_dot(h, w_down, sizes)            # [N*K, D]
+        h = (jax.nn.silu(_grouped(rows, w_gate, sizes))
+             * _grouped(rows, w_up, sizes))
+        out = _grouped(h, w_down, sizes)                      # [N*K, D]
     with jax.named_scope("moe.combine"):
         back = jnp.take(out, jnp.argsort(order), axis=0).reshape(N, K, D)
         return jnp.einsum("nk,nkd->nd", gate_w,
@@ -157,6 +157,16 @@ def apply_with_losses(module, params, *args, **kwargs):
     leaves = jax.tree_util.tree_leaves(mutables.get("losses", {}))
     aux = sum(jnp.sum(leaf) for leaf in leaves) if leaves else jnp.float32(0.0)
     return out, aux
+
+
+def _grouped(lhs: jax.Array, rhs: jax.Array, sizes: jax.Array) -> jax.Array:
+    """``lhs [M, k]`` by ``rhs [E, k, n]`` over the contiguous row groups
+    ``sizes [E]`` -> ``[M, n]``: the Pallas kernel where it tiles."""
+    from serverless_learn_tpu.ops.pallas import moe_gmm
+
+    if moe_gmm.untileable_reason(lhs, rhs) is None:
+        return moe_gmm.gmm(lhs, rhs, sizes)
+    return jax.lax.ragged_dot(lhs, rhs, sizes)
 
 
 class MoELayer(nn.Module):

@@ -16,6 +16,9 @@ anything else.
 * ``flash_fwd`` / ``flash_bwd_dq`` / ``flash_bwd_dkv`` — the Pallas
   kernels' HLO instructions in ``device_ops`` (``flash_attn_roofline``
   still finds them by their ``tpu_custom_call`` target).
+* ``moe_gmm`` — the expert layers' grouped-product kernel
+  (``ops/pallas/moe_gmm.py``), as ``device_ops`` sums it and
+  ``chipbench/metrics/moe_gmm_roofline.py`` finds it.
 """
 
 import re
@@ -119,3 +122,15 @@ def test_flash_kernels_are_named_apart():
     for name in (fa.FWD_KERNEL, fa.BWD_DQ_KERNEL, fa.BWD_DKV_KERNEL):
         assert re.search(rf"\b{name}\b", text), name
     assert len({fa.FWD_KERNEL, fa.BWD_DQ_KERNEL, fa.BWD_DKV_KERNEL}) == 3
+
+
+def test_the_grouped_product_kernel_is_named():
+    from serverless_learn_tpu.ops.pallas import moe_gmm
+
+    lhs = jnp.zeros((16, 128), jnp.float32)
+    rhs = jnp.zeros((2, 128, 128), jnp.float32)
+    text = jax.jit(moe_gmm.gmm).lower(
+        lhs, rhs, jnp.array([10, 6], jnp.int32)).as_text(debug_info=True)
+    assert moe_gmm.KERNEL == "moe_gmm"
+    # The kernel's scope, not the module's file name (moe_gmm.py).
+    assert re.search(r'loc\("moe_gmm[/"]', text)
