@@ -257,11 +257,11 @@ class ContinuousBatchingEngine:
         ps = kv.block_size
         self._ps = ps
         self._max_pages = pages_for(max_seq, ps)
-        # A model with a recurrent layer keeps state per SLOT beside the
-        # pool (``kvcache.take_slots``). A page hit cannot restore that
-        # state, so such a model runs without the prefix trie. Its
-        # prefill rows pack as an attention model's do: a row that
-        # continues the row before it starts from that row's state
+        # A model with a recurrent or a convolution layer keeps state per
+        # SLOT beside the pool (``kvcache.take_slots``). A page hit cannot
+        # restore that state, so such a model runs without the prefix
+        # trie. Its prefill rows pack as an attention model's do: a row
+        # that continues the row before it starts from that row's state
         # (``_paged_prefill_jit``).
         self._slot_leaves = slot_leaves(module.cfg)
         # A model with experts: each decode chunk also returns, per step
@@ -286,12 +286,12 @@ class ContinuousBatchingEngine:
         self._pmod = kvcache.paged_module(module, ps, num_blocks)
         self.prefill_chunk = kv.prefill_chunk or max_seq
         if self._slot_leaves and self.prefill_chunk < module.cfg.ssm_conv - 1:
-            # A linked row's convolution reads the last ssm_conv - 1
-            # inputs of the full chunk before it (``ssm.causal_conv``).
+            # A linked row's convolution, a Mamba or a gated one, reads the
+            # last ssm_conv - 1 inputs of the chunk before (``causal_conv``).
             raise ValueError(
                 f"kv.prefill_chunk ({self.prefill_chunk}) is shorter than "
                 f"the {module.cfg.ssm_conv - 1} convolution inputs a "
-                "prefill row passes on to the next chunk")
+                "prefill row passes on to the next chunk's convolution")
         # 0 = derived per iteration (``_prefill_steps``). An explicit
         # cap is at least one chunk: the oldest row always advances.
         self.prefill_budget = (max(kv.prefill_budget, self.prefill_chunk)
@@ -1596,7 +1596,7 @@ class ContinuousBatchingEngine:
             state_bytes=per_slot * self.max_slots,
             prefix_cache=(
                 "on" if self._trie is not None else
-                "off: a page hit cannot restore a recurrent layer's state"
+                "off: a page hit cannot restore a layer's slot state"
                 if self._slot_leaves else "off: kv.prefix_cache is false"))
         if self._trie is not None:
             out["prefix_digest"] = self._trie.digest(

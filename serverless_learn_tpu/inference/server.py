@@ -111,7 +111,10 @@ class GenerationServer:
         self._thread: Optional[threading.Thread] = None
         self._conns = {}  # live connection thread -> socket, for stop()
         self._conns_lock = threading.Lock()
-        self.max_connections = 64  # bounds threads and total line buffers
+        # Bounds threads and total line buffers. Twice the engine's
+        # slots, and at least 64: a client per slot and one queued
+        # behind it keep every slot busy while replies turn round.
+        self.max_connections = max(64, 2 * max_batch)
         self.requests_served = 0
         # handle() now runs concurrently (the engine queue serializes the
         # device, not the handlers), so the counter needs its own lock.

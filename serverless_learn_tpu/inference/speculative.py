@@ -41,6 +41,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from serverless_learn_tpu.models.transformer import slot_leaves
+
 
 def prefix_draft(module, params, n_layers: int):
     """(draft_module, draft_params): the target's own first ``n_layers``
@@ -207,11 +209,13 @@ def speculative_generate(
     if max_new_tokens <= 0:
         return prompt.astype(jnp.int32), {"acceptance": 0.0, "rounds": 0}
     for m, who in ((target, "target"), (draft, "draft")):
-        if "mamba" in m.cfg.layer_types:
-            # A rejected draft rolls the cache index back; a recurrent
-            # state has consumed the rejected tokens and cannot follow.
+        if slot_leaves(m.cfg):
+            # A rejected draft rolls the cache index back; state kept per
+            # slot (a recurrent state, a convolution's carried inputs) has
+            # consumed the rejected tokens and cannot follow.
             raise NotImplementedError(
-                f"speculative decoding over a {who} with recurrent layers")
+                f"speculative decoding over a {who} with state per slot "
+                f"(layers {sorted(set(m.cfg.layer_types))})")
         if P + max_new_tokens + K > m.cfg.max_seq_len:
             raise ValueError(
                 f"prompt + max_new + K ({P}+{max_new_tokens}+{K}) exceeds "

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from serverless_learn_tpu.ops.attention import dot_product_attention
 from serverless_learn_tpu.ops.moe import MoELayer
 
-LAYER_KINDS = ("attention", "mamba", "window_rope", "full_nope")
+LAYER_KINDS = ("attention", "mamba", "conv", "window_rope", "full_nope")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,7 +51,8 @@ class TransformerConfig:
     # "full_nope" sees every earlier key with no positional term. A
     # "mamba" layer is a Mamba-2 mixer (``MambaMixer``) of ``ssm_heads``
     # heads of ``ssm_head_dim`` channels over a state of ``ssm_state``
-    # per channel.
+    # per channel; a "conv" layer a gated short convolution of
+    # ``ssm_conv`` taps over ``d_model`` channels (``ShortConvMixer``).
     layer_types: tuple = ()
     sliding_window: int = 0
     # Attention terms some families publish (all off = absent): an
@@ -788,16 +789,74 @@ class MambaMixer(nn.Module):
         return _proj(cfg, cfg.d_model, "out_proj")(g)
 
 
+class ShortConvMixer(nn.Module):
+    """The gated short convolution of the LFM2 family: one input
+    projection to ``[B | C | u]`` of ``d_model`` channels each, the input
+    gate ``v = B * u``, a causal depthwise convolution of ``ssm_conv``
+    taps over ``v`` with no bias (zeros before a sequence starts), the
+    output gate ``C * conv(v)``, and the output projection. No
+    activation, no bias.
+
+    Under ``decode`` / ``prefill`` / ``extend`` it carries one ``cache``
+    leaf per row, indexed by the serving engine's SLOT
+    (``SLOT_LEAVES``): the convolution's last ``ssm_conv - 1`` inputs,
+    with ``MambaMixer``'s contract for a sequence's first call,
+    ``seq_lengths`` and ``link``."""
+
+    cfg: TransformerConfig
+    SLOT_LEAVES = ("conv_state",)
+
+    @nn.compact
+    def __call__(self, h, *, decode=False, prefill=False, extend=False,
+                 seq_lengths=None, link=None):
+        from serverless_learn_tpu.ops import ssm
+
+        cfg = self.cfg
+        d, K = cfg.d_model, cfg.ssm_conv
+        B_ = h.shape[0]
+        gate_b, gate_c, u = jnp.split(_proj(cfg, 3 * d, "in_proj")(h), 3,
+                                      axis=-1)
+        taps = self.param("conv_kernel", nn.initializers.lecun_normal(),
+                          (K, d), cfg.param_dtype)
+        carried = False
+        if decode or prefill or extend:
+            carried = self.has_variable("cache", "conv_state")
+            cs = self.variable("cache", "conv_state", jnp.zeros,
+                               (B_, K - 1, d), cfg.dtype)
+        with jax.named_scope("conv.gate_in"):
+            v = gate_b * u
+        with jax.named_scope("conv.taps"):
+            conv_in = cs.value if carried else jnp.zeros(
+                (B_, K - 1, d), v.dtype)
+            w, conv_out = ssm.causal_conv(
+                v, conv_in, taps, None, seq_lengths if carried else None,
+                link if carried and extend else None)
+        if carried:
+            cs.value = conv_out
+        with jax.named_scope("conv.gate_out"):
+            y = (gate_c.astype(jnp.float32) * w).astype(cfg.dtype)
+        return _proj(cfg, d, "out_proj")(y)
+
+
 def moe_layers(cfg: TransformerConfig) -> int:
     """How many of the model's layers route to experts."""
     return max(cfg.n_layers - cfg.moe_dense_layers, 0) if cfg.n_experts else 0
 
 
+# The mixers that keep state per slot, by their kind in ``layer_types``.
+_SLOT_MIXERS = (("mamba", MambaMixer), ("conv", ShortConvMixer))
+
+
 def slot_leaves(cfg: TransformerConfig) -> tuple:
     """Names of the ``cache`` leaves this model keeps per SLOT of the
     serving engine (``[max_slots, ...]``, gathered and scattered by slot
-    id) beside the paged pool: () for a model whose every layer attends."""
-    return MambaMixer.SLOT_LEAVES if "mamba" in cfg.layer_types else ()
+    id) beside the paged pool: those of every mixer kind its layers hold,
+    () for a model whose every layer attends."""
+    names = []
+    for kind, mixer in _SLOT_MIXERS:
+        if kind in cfg.layer_types:
+            names += [n for n in mixer.SLOT_LEAVES if n not in names]
+    return tuple(names)
 
 
 class Block(nn.Module):
@@ -813,6 +872,10 @@ class Block(nn.Module):
         h = _norm(cfg, "norm_attn")(x)
         if self.kind == "mamba":
             mixed = MambaMixer(cfg, name="mamba")(
+                h, decode=decode, prefill=prefill, extend=extend,
+                seq_lengths=seq_lengths, link=link)
+        elif self.kind == "conv":
+            mixed = ShortConvMixer(cfg, name="conv")(
                 h, decode=decode, prefill=prefill, extend=extend,
                 seq_lengths=seq_lengths, link=link)
         else:
@@ -1084,9 +1147,10 @@ class Transformer(nn.Module):
         cached prefix) — the speculative-verify primitive: one forward
         scores K drafted tokens (``inference/speculative.py``).
         ``link`` [B] bool (``extend`` only): row i holds the tokens that
-        follow row i - 1's in the same sequence; a recurrent layer starts
-        it from row i - 1's state (``MambaMixer``). An attention layer
-        needs no link: rows of one sequence name one block table.
+        follow row i - 1's in the same sequence; a recurrent or
+        convolution layer starts it from row i - 1's state
+        (``MambaMixer``, ``ShortConvMixer``). An attention layer needs no
+        link: rows of one sequence name one block table.
         """
         cfg = self.cfg
         if decode + prefill + extend > 1:
